@@ -31,6 +31,7 @@ from repro.core.evaluator import evaluate_many, exact_combination_count
 from repro.core.jobs import generate_workload
 from repro.core.simulator import simulate
 from repro.core.trace import synthesize_trace
+from repro.runtime import init_compile_cache
 
 ART = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
                    "artifacts", "bench")
@@ -262,9 +263,8 @@ def table_eval_perf(full: bool = False):
     cap); ``--full`` adds a fused-only row at K = 2**26, beyond what the
     seed could represent in memory.
     """
-    import jax
-
     from repro.core import evaluator, policies
+    from repro.runtime import x64
 
     def fused_time(jobs, orders, repeats):
         ts = []
@@ -281,10 +281,10 @@ def table_eval_perf(full: bool = False):
             # per-call work in the seed design: materialize + gather + jit
             outcomes, weights = evaluator.enumerate_outcomes(jobs)
             durations, success = evaluator._realized_arrays(jobs, outcomes)
-            with jax.experimental.enable_x64(True):
+            with x64():
                 vals = np.asarray(evaluator._static_batch(
-                    np.float64(durations), success, np.float64(weights),
-                    orders,
+                    np.asarray(durations, np.float64), success,
+                    np.asarray(weights, np.float64), orders,
                 ))
             ts.append(time.perf_counter() - t0)
         return float(np.median(ts[1:])), vals
@@ -333,9 +333,8 @@ def table_eval_dynamic(full: bool = False):
     (the seed's materialization cap); ``--full`` adds SERPT and a
     fused-only row at K = 2**26, beyond what the seed could represent.
     """
-    import jax
-
     from repro.core import evaluator, policies
+    from repro.runtime import x64
 
     def fused_time(jobs, policy, repeats):
         ts = []
@@ -355,10 +354,11 @@ def table_eval_dynamic(full: bool = False):
             # per-call work in the seed design: materialize + gather + jit
             outcomes, weights = evaluator.enumerate_outcomes(jobs)
             _, success = evaluator._realized_arrays(jobs, outcomes)
-            with jax.experimental.enable_x64(True):
+            with x64():
                 val = float(evaluator._dynamic_batch(
-                    np.float64(idx_table), np.float64(stage_durs), outcomes,
-                    success, np.float64(weights), int(num_stages.sum()),
+                    np.asarray(idx_table, np.float64),
+                    np.asarray(stage_durs, np.float64), outcomes, success,
+                    np.asarray(weights, np.float64), int(num_stages.sum()),
                 ))
             ts.append(time.perf_counter() - t0)
         return float(np.median(ts[1:])), val
@@ -609,4 +609,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    init_compile_cache()
     main()

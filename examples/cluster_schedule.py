@@ -23,6 +23,7 @@ from repro.core.jobs import JobSpec
 from repro.data.pipeline import DataConfig, SyntheticLM
 from repro.launch.train import Trainer, default_plan
 from repro.obs import MetricsRegistry, TraceRecorder, format_snapshot
+from repro.runtime import init_compile_cache
 
 ARCH_POOL = ["qwen3-1.7b", "mamba2-1.3b", "mixtral-8x22b", "granite-3-8b",
              "llama3-8b", "jamba-v0.1-52b"]
@@ -104,4 +105,5 @@ def main():
 
 
 if __name__ == "__main__":
+    init_compile_cache()
     main()

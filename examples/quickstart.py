@@ -19,6 +19,7 @@ from repro.core.policies import (
     sr_rank_values,
 )
 from repro.obs import format_snapshot, get_registry, profiling
+from repro.runtime import init_compile_cache
 
 
 def worked_example():
@@ -49,6 +50,7 @@ def random_workload():
 
 
 if __name__ == "__main__":
+    init_compile_cache()
     ensure_cache_dir()  # persist workload tables across invocations
     profiling.enable()  # time the fused evaluator ops + cache tiers
     worked_example()

@@ -19,6 +19,7 @@ from repro.configs.registry import get_smoke
 from repro.launch.serve import ServePlan, make_decode_fn, make_prefill_fn
 from repro.models import transformer as T
 from repro.parallel.sharding import DEFAULT_RULES
+from repro.runtime import init_compile_cache
 
 
 def main():
@@ -77,4 +78,5 @@ def main():
 
 
 if __name__ == "__main__":
+    init_compile_cache()
     main()

@@ -24,6 +24,7 @@ from repro.ckpt.checkpoint import CheckpointManager
 from repro.configs.registry import get_config, get_smoke
 from repro.data.pipeline import DataConfig, SyntheticLM
 from repro.launch.train import Trainer, default_plan
+from repro.runtime import init_compile_cache
 
 
 def make_cfg(preset: str):
@@ -80,4 +81,5 @@ def main():
 
 
 if __name__ == "__main__":
+    init_compile_cache()
     main()

@@ -32,8 +32,10 @@ fused and vectorized:
   :func:`sample_outcomes` + explicit tables remain as the legacy
   materialized tier.
 
-Static-order evaluation runs under ``jax.experimental.enable_x64`` so the
-fused op accumulates in float64 (<=1e-9 agreement with the seed path).
+Evaluation runs under :func:`repro.runtime.x64`, so host-side tables and
+the XLA path are float64 (<=1e-9 agreement with the seed path).  On a
+TPU, ``impl="auto"`` runs the Pallas kernels, which compute in float32
+within ``ops.CHIP_RTOL`` of that.
 Enumeration metadata (mixed-radix strides, combination counts) and padded
 workload arrays are cached per workload via
 :func:`repro.core.policies.workload_cached`, so the DES and cluster
@@ -57,6 +59,7 @@ from repro.core.jobs import Workload
 from repro.kernels.sojourn_eval import rng as kernel_rng
 from repro.kernels.sojourn_eval import sojourn_eval, sojourn_eval_dynamic
 from repro.kernels.sojourn_eval.ref import mixed_radix_strides
+from repro.runtime import x64
 
 __all__ = [
     "enumerate_outcomes",
@@ -76,11 +79,6 @@ MAX_EXACT_COMBOS = 1 << 26
 #: Above this many combinations, a (K, N) outcome table is too large to
 #: materialize (dynamic-policy lockstep simulation and shared exact tables).
 MAX_MATERIALIZED_COMBOS = 1 << 21
-
-
-def _x64():
-    """Static-order evaluation runs in float64 end to end."""
-    return jax.experimental.enable_x64(True)
 
 
 # ---------------------------------------------------------------------------
@@ -224,7 +222,7 @@ def expected_sojourn_static(
                 f"{k_total} combinations exceed MAX_EXACT_COMBOS; use "
                 "samples=(seed, n_samples) or sample_outcomes"
             )
-    with _x64():
+    with x64():
         e_succ, e_all = sojourn_eval(
             sizes,
             probs,
@@ -321,7 +319,7 @@ def expected_sojourn_dynamic(
     idx_table = policies.index_table(jobs, policy)
     stage_durs = policies.stage_durations(jobs)
     if samples is not None:
-        with _x64():
+        with x64():
             e_succ, _ = sojourn_eval_dynamic(
                 probs, stage_durs, num_stages, idx_table,
                 samples=samples, n_servers=n_servers, impl=impl,
@@ -334,7 +332,7 @@ def expected_sojourn_dynamic(
                 f"{k_total} combinations exceed MAX_EXACT_COMBOS; use "
                 "samples=(seed, n_samples) or sample_outcomes"
             )
-        with _x64():
+        with x64():
             e_succ, _ = sojourn_eval_dynamic(
                 probs, stage_durs, num_stages, idx_table,
                 n_servers=n_servers, impl=impl,
@@ -347,13 +345,13 @@ def expected_sojourn_dynamic(
         )
     _, success = _realized_arrays(jobs, outcomes)
     total_stages = int(num_stages.sum())
-    with _x64():
+    with x64():
         val = _dynamic_batch(
-            jnp.asarray(np.float64(idx_table)),
-            jnp.asarray(np.float64(stage_durs)),
+            jnp.asarray(np.asarray(idx_table, np.float64)),
+            jnp.asarray(np.asarray(stage_durs, np.float64)),
             jnp.asarray(outcomes),
             jnp.asarray(success),
-            jnp.asarray(np.float64(weights)),
+            jnp.asarray(np.asarray(weights, np.float64)),
             total_stages,
         )
         return float(val)

@@ -59,17 +59,19 @@ static ``sojourn_enum`` op — no ``(K, N)`` table anywhere, exact to
      ``clock = busy``).
 
 * **Reduction** — after the step loop the lane holds Eq. (7)'s mean
-  sojourn of successful jobs for its combination; the tile accumulates
-  ``w * mean`` into a VMEM scratch accumulator that persists across the
-  sequential combination tiles and is flushed on the last one — the
-  same tiled reduction as the static kernel.
+  sojourn of successful jobs for its combination; the tile adds
+  ``w * mean`` into a Kahan-compensated VMEM scratch accumulator that
+  persists across the sequential combination tiles and is flushed on the
+  last one — the static kernels' ``accumulate``.
 
 The XLA fallback (`_dynamic_enum_xla`) is the identical algorithm as a
 ``lax.scan`` over combination tiles with the job axis vectorized
 (``(T, N)`` state, ``argmin`` selection); it is the default on CPU and
-the path the exact evaluator rides.  Both paths accumulate in the input
-dtype: float64 under ``jax.experimental.enable_x64`` (the <=1e-9 parity
-bar), float32 on real TPU grids.
+the path the exact evaluator rides on the CPU.  Precision is chosen as in
+``ops.py``: the compiled Pallas kernels run in float32 (within
+``ops.CHIP_RTOL`` of the float64 oracle); the XLA and interpret paths
+follow the ambient x64 mode, float64 inside :func:`repro.runtime.x64`
+(the <=1e-9 parity bar).
 """
 
 from __future__ import annotations
@@ -84,6 +86,11 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.sojourn_eval import kernel as K
 from repro.kernels.sojourn_eval import rng
+from repro.kernels.sojourn_eval.ops import (
+    compute_dtype,
+    precision_scope,
+    resolve_impl,
+)
 from repro.kernels.sojourn_eval.ref import mixed_radix_strides
 from repro.obs import profiling
 
@@ -203,11 +210,9 @@ def _dynamic_kernel(
     probs_ref,  # (1, N, M) VMEM stop probabilities (0 pad)
     durs_ref,  # (1, N, M) VMEM per-stage service increments (0 pad)
     idx_ref,  # (1, N, M) VMEM this policy's index table (+inf pad)
-    succ_ref,  # (1, 1) out: E[sojourn | successful jobs]
-    all_ref,  # (1, 1) out: E[sojourn | all jobs]
-    acc_succ,  # (SUBLANES, LANES) VMEM scratch
-    acc_all,
-    *,
+    succ_ref,  # (1, 1, 1) SMEM out: E[sojourn | successful jobs]
+    all_ref,  # (1, 1, 1) SMEM out: E[sojourn | all jobs]
+    *scratch,  # K.reduction_scratch
     n: int,
     m: int,
     total_stages: int,
@@ -216,13 +221,7 @@ def _dynamic_kernel(
     n_servers: int,
 ):
     kt = pl.program_id(1)
-
-    @pl.when(kt == 0)
-    def _init():
-        acc_succ[...] = jnp.zeros_like(acc_succ)
-        acc_all[...] = jnp.zeros_like(acc_all)
-
-    dtype = acc_succ.dtype
+    dtype = durs_ref.dtype
     shape = (K.SUBLANES, K.LANES)
     k = K._tile_combo_ids(kt)
     # Scalar tables, hoisted out of the step loop.
@@ -250,12 +249,7 @@ def _dynamic_kernel(
 
     # Eq. (7) mean over the successful jobs; Eq. (9) weighted reduction.
     mean = jnp.where(cnt > 0, tot / jnp.maximum(cnt, 1).astype(dtype), 0.0)
-    acc_succ[...] += w * mean
-    acc_all[...] += w * (tsum / n)
-
-    @pl.when(kt == nkt - 1)
-    def _finalize():
-        K._flush(succ_ref, all_ref, acc_succ, acc_all)
+    K.accumulate(kt, nkt, (succ_ref, all_ref), scratch, (w * mean, w * (tsum / n)))
 
 
 def _dynamic_mc_kernel(
@@ -264,11 +258,9 @@ def _dynamic_mc_kernel(
     cdf_ref,  # (1, N, M) VMEM stop-probability CDF (cumsum of probs)
     durs_ref,  # (1, N, M) VMEM per-stage service increments (0 pad)
     idx_ref,  # (1, N, M) VMEM this policy's index table (+inf pad)
-    succ_ref,  # (1, 1) out
-    all_ref,  # (1, 1) out
-    acc_succ,
-    acc_all,
-    *,
+    succ_ref,  # (1, 1, 1) SMEM out
+    all_ref,  # (1, 1, 1) SMEM out
+    *scratch,  # K.reduction_scratch
     n: int,
     m: int,
     total_stages: int,
@@ -280,13 +272,7 @@ def _dynamic_mc_kernel(
     job's stop stage from the Threefry counter stream instead of the
     mixed-radix rule; the lockstep simulation is shared."""
     kt = pl.program_id(1)
-
-    @pl.when(kt == 0)
-    def _init():
-        acc_succ[...] = jnp.zeros_like(acc_succ)
-        acc_all[...] = jnp.zeros_like(acc_all)
-
-    dtype = acc_succ.dtype
+    dtype = durs_ref.dtype
     shape = (K.SUBLANES, K.LANES)
     k = K._tile_combo_ids(kt)  # lanes own global sample indices
     key = (seed_ref[0, 0].astype(jnp.uint32), seed_ref[0, 1].astype(jnp.uint32))
@@ -315,14 +301,12 @@ def _dynamic_mc_kernel(
     )
 
     mean = jnp.where(cnt > 0, tot / jnp.maximum(cnt, 1).astype(dtype), 0.0)
-    acc_succ[...] += w * mean
-    acc_all[...] += w * (tsum / n)
-
-    @pl.when(kt == nkt - 1)
-    def _finalize():
-        K._flush(succ_ref, all_ref, acc_succ, acc_all)
+    K.accumulate(kt, nkt, (succ_ref, all_ref), scratch, (w * mean, w * (tsum / n)))
 
 
+@functools.partial(
+    jax.jit, static_argnames=("k_total", "total_stages", "n_servers", "interpret")
+)
 def dynamic_sojourn_enum(
     probs: jax.Array,  # (N, M) padded stop probabilities
     stage_durs: jax.Array,  # (N, M) padded per-stage increments
@@ -348,6 +332,7 @@ def dynamic_sojourn_enum(
         nkt=nkt,
         n_servers=n_servers,
     )
+    out_specs, out_shape = K.scalar_outputs(p_pols, dtype)
     out_succ, out_all = pl.pallas_call(
         kernel,
         grid=(p_pols, nkt),
@@ -358,18 +343,9 @@ def dynamic_sojourn_enum(
             pl.BlockSpec((1, n, m), lambda p, kt: (0, 0, 0)),
             pl.BlockSpec((1, n, m), lambda p, kt: (p, 0, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, 1), lambda p, kt: (p, 0)),
-            pl.BlockSpec((1, 1), lambda p, kt: (p, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((p_pols, 1), dtype),
-            jax.ShapeDtypeStruct((p_pols, 1), dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((K.SUBLANES, K.LANES), dtype),
-            pltpu.VMEM((K.SUBLANES, K.LANES), dtype),
-        ],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=K.reduction_scratch(dtype),
         interpret=interpret,
     )(
         strides.reshape(1, n),
@@ -378,15 +354,18 @@ def dynamic_sojourn_enum(
         stage_durs.reshape(1, n, m),
         idx_tables,
     )
-    return out_succ[:, 0], out_all[:, 0]
+    return out_succ[:, 0, 0], out_all[:, 0, 0]
 
 
+@functools.partial(
+    jax.jit, static_argnames=("n_samples", "total_stages", "n_servers", "interpret")
+)
 def dynamic_sojourn_mc(
     cdf: jax.Array,  # (N, M) stop-probability CDF
     stage_durs: jax.Array,  # (N, M) padded per-stage increments
     idx_tables: jax.Array,  # (P, N, M) per-policy index tables (+inf pad)
     radix: jax.Array,  # (N,) int32 stage counts
-    seed: int,
+    key: jax.Array,  # (2,) int32 Threefry key words, rng.split_seed(seed)
     n_samples: int,
     total_stages: int,
     *,
@@ -397,7 +376,6 @@ def dynamic_sojourn_mc(
     p_pols, n, m = idx_tables.shape
     nkt = max(1, pl.cdiv(n_samples, K.BLOCK_COMBOS))
     dtype = idx_tables.dtype
-    seed_arr = jnp.asarray([rng.split_seed(seed)], jnp.int32)  # (1, 2)
     kernel = functools.partial(
         _dynamic_mc_kernel,
         n=n,
@@ -407,6 +385,7 @@ def dynamic_sojourn_mc(
         nkt=nkt,
         n_servers=n_servers,
     )
+    out_specs, out_shape = K.scalar_outputs(p_pols, dtype)
     out_succ, out_all = pl.pallas_call(
         kernel,
         grid=(p_pols, nkt),
@@ -417,27 +396,18 @@ def dynamic_sojourn_mc(
             pl.BlockSpec((1, n, m), lambda p, kt: (0, 0, 0)),
             pl.BlockSpec((1, n, m), lambda p, kt: (p, 0, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, 1), lambda p, kt: (p, 0)),
-            pl.BlockSpec((1, 1), lambda p, kt: (p, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((p_pols, 1), dtype),
-            jax.ShapeDtypeStruct((p_pols, 1), dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((K.SUBLANES, K.LANES), dtype),
-            pltpu.VMEM((K.SUBLANES, K.LANES), dtype),
-        ],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=K.reduction_scratch(dtype),
         interpret=interpret,
     )(
-        seed_arr,
+        key.reshape(1, 2),
         radix.reshape(1, n),
         cdf.reshape(1, n, m),
         stage_durs.reshape(1, n, m),
         idx_tables,
     )
-    return out_succ[:, 0], out_all[:, 0]
+    return out_succ[:, 0, 0], out_all[:, 0, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -602,14 +572,6 @@ def _dynamic_mc_xla(
 # ---------------------------------------------------------------------------
 
 
-def _resolve(impl: str) -> str:
-    if impl == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "xla"
-    if impl not in ("xla", "pallas", "interpret"):
-        raise ValueError(f"unknown impl {impl!r}; options: auto/xla/pallas/interpret")
-    return impl
-
-
 def sojourn_eval_dynamic(
     probs: np.ndarray,  # (N, M) padded stop probabilities
     stage_durs: np.ndarray,  # (N, M) padded per-stage increments
@@ -639,9 +601,9 @@ def sojourn_eval_dynamic(
     When :mod:`repro.obs.profiling` is enabled, each call is timed into
     a ``prof.sojourn_eval.dynamic.<mode>.<impl>.seconds`` span.
     """
-    impl = _resolve(impl)
+    impl = resolve_impl(impl)
     mode = "mc" if samples is not None else "enum"
-    with profiling.span(f"sojourn_eval.dynamic.{mode}.{impl}"):
+    with profiling.span(f"sojourn_eval.dynamic.{mode}.{impl}"), precision_scope(impl):
         return _sojourn_eval_dynamic(
             probs, stage_durs, num_stages, idx_tables,
             samples=samples, n_servers=n_servers, impl=impl,
@@ -666,7 +628,7 @@ def _sojourn_eval_dynamic(
             f"idx_tables must be (P, {n}, {m}); got {idx_tables.shape}"
         )
     total_stages = int(num_stages.sum())
-    fdt = jnp.asarray(probs).dtype  # f64 under x64, else f32
+    fdt = compute_dtype(impl)
     if samples is not None:
         seed, n_samples = int(samples[0]), int(samples[1])
         if n_samples <= 0:
@@ -699,7 +661,7 @@ def _sojourn_eval_dynamic(
             jnp.asarray(stage_durs, fdt),
             jnp.asarray(idx_tables, fdt),
             jnp.asarray(num_stages, jnp.int32),
-            seed,
+            jnp.asarray(rng.split_seed(seed), jnp.int32),
             n_samples,
             total_stages,
             n_servers=n_servers,

@@ -14,8 +14,8 @@ in host NumPy (capping K at 2**21); these kernels never materialize it:
   axis — TPU-friendly: no vector gather, only ``(SUBLANES, LANES)``
   selects.  The per-order completion-time prefix sum runs in the same
   position loop, and the probability-weighted successful-job sojourn
-  accumulates into a VMEM scratch tile that persists across the
-  (sequential, innermost) combination-tile grid dimension.
+  accumulates, Kahan-compensated, into VMEM scratch tiles that persist
+  across the (sequential, innermost) combination-tile grid dimension.
 
 * ``sojourn_outcomes`` — the same fused gather + prefix sum + weighted
   reduction for an *explicit* outcome matrix (Monte-Carlo samples or a
@@ -38,10 +38,11 @@ pre-permuted by the caller (``ops.py``), so position ``pos`` in the
 kernel loop *is* service position: the running sum ``t`` after ``pos``
 steps is the completion time of the job served ``pos``-th.
 
-Accumulation happens in the input dtype: float64 under
-``jax.experimental.enable_x64`` (CPU interpret / XLA paths — this is
-what the exact evaluator uses and what the <=1e-9 parity tests check),
-float32 on real TPU grids.
+Accumulation happens in the input dtype.  ``ops.sojourn_eval`` passes
+float32 when the kernels are compiled for a TPU (Mosaic has no 64-bit
+types; ``ops.CHIP_RTOL`` bounds the error against the float64 oracle),
+and the ambient x64 mode's float in interpret mode, where the <=1e-9
+parity tests run them in float64.
 """
 
 from __future__ import annotations
@@ -77,9 +78,58 @@ def _tile_combo_ids(kt: jax.Array) -> jax.Array:
     return kt * BLOCK_COMBOS + row * LANES + col
 
 
-def _flush(succ_ref, all_ref, acc_succ, acc_all):
-    succ_ref[0, 0] = jnp.sum(acc_succ[...])
-    all_ref[0, 0] = jnp.sum(acc_all[...])
+def reduction_scratch(dtype) -> list:
+    """VMEM scratch of the tiled reduction: a running-sum tile and its
+    Kahan compensation tile for each of the two results."""
+    return [pltpu.VMEM((SUBLANES, LANES), dtype) for _ in range(4)]
+
+
+def accumulate(kt, nkt, outs, scratch, terms):
+    """Add one tile's per-lane terms into the scratch; flush on the last tile.
+
+    Each lane sums one term per combination tile, up to 2**16 of them at
+    K = 2**26.  A plain float32 running sum loses about 1e-4 relative
+    there; the Kahan compensation keeps it near float32 rounding.  The
+    combination axis is the innermost, sequential grid dimension, so
+    ``kt == 0`` starts each order's (or policy's) sum.
+    """
+
+    @pl.when(kt == 0)
+    def _init():
+        for ref in scratch:
+            ref[...] = jnp.zeros_like(ref)
+
+    for i, x in enumerate(terms):
+        acc, comp = scratch[2 * i], scratch[2 * i + 1]
+        y = x - comp[...]
+        t = acc[...] + y
+        comp[...] = (t - acc[...]) - y
+        acc[...] = t
+
+    @pl.when(kt == nkt - 1)
+    def _flush():
+        for i, out in enumerate(outs):
+            out[0, 0, 0] = jnp.sum(scratch[2 * i][...] - scratch[2 * i + 1][...])
+
+
+# TPU block layout.  Mosaic accepts a block only if its last two dims are
+# (8, 128)-aligned or span the whole array, and SMEM holds 1 MiB, so a
+# whole (P, N) table of per-order scalars does not fit at P = 4096.  Each
+# grid row p therefore gets its own (1, 1, N) slice of a (P, 1, N) table,
+# and writes its two scalars through a (1, 1, 1) SMEM block of a (P, 1, 1)
+# output.
+
+
+def per_row_smem(n: int) -> pl.BlockSpec:
+    """SMEM block holding row ``p`` of a ``(P, 1, n)`` scalar table."""
+    return pl.BlockSpec((1, 1, n), lambda p, kt: (p, 0, 0), memory_space=pltpu.SMEM)
+
+
+def scalar_outputs(rows: int, dtype):
+    """``(out_specs, out_shape)`` for the two per-row ``(rows, 1, 1)`` results."""
+    spec = pl.BlockSpec((1, 1, 1), lambda p, kt: (p, 0, 0), memory_space=pltpu.SMEM)
+    shape = jax.ShapeDtypeStruct((rows, 1, 1), dtype)
+    return [spec, spec], [shape, shape]
 
 
 # ---------------------------------------------------------------------------
@@ -88,28 +138,20 @@ def _flush(succ_ref, all_ref, acc_succ, acc_all):
 
 
 def _enum_kernel(
-    strides_ref,  # (1, N) int32 SMEM, per-order permuted mixed-radix strides
-    radix_ref,  # (1, N) int32 SMEM, per-order permuted stage counts M_i
+    strides_ref,  # (1, 1, N) int32 SMEM, per-order permuted mixed-radix strides
+    radix_ref,  # (1, 1, N) int32 SMEM, per-order permuted stage counts M_i
     sizes_ref,  # (1, N, M) VMEM, per-order permuted cumulative sizes
     probs_ref,  # (1, N, M) VMEM, per-order permuted stop probabilities
-    succ_ref,  # (1, 1) out: E[sojourn | successful jobs] accumulator
-    all_ref,  # (1, 1) out: E[sojourn | all jobs]
-    acc_succ,  # (SUBLANES, LANES) VMEM scratch
-    acc_all,
-    *,
+    succ_ref,  # (1, 1, 1) SMEM out: E[sojourn | successful jobs]
+    all_ref,  # (1, 1, 1) SMEM out: E[sojourn | all jobs]
+    *scratch,  # reduction_scratch
     n: int,
     m: int,
     k_total: int,
     nkt: int,
 ):
     kt = pl.program_id(1)
-
-    @pl.when(kt == 0)
-    def _init():
-        acc_succ[...] = jnp.zeros_like(acc_succ)
-        acc_all[...] = jnp.zeros_like(acc_all)
-
-    dtype = acc_succ.dtype
+    dtype = sizes_ref.dtype
     k = _tile_combo_ids(kt)
     # Eq. (8): combination probability = prod_i p_{i, stage_i(k)}; the tail
     # tile is masked by zeroing its weight (k >= K contributes nothing).
@@ -119,8 +161,8 @@ def _enum_kernel(
     tot = jnp.zeros((SUBLANES, LANES), dtype)  # sum over successful jobs
     cnt = jnp.zeros((SUBLANES, LANES), jnp.int32)  # successes l(k)
     for pos in range(n):
-        stride = strides_ref[0, pos]
-        radix = radix_ref[0, pos]
+        stride = strides_ref[0, 0, pos]
+        radix = radix_ref[0, 0, pos]
         s = (k // stride) % radix  # on-the-fly mixed-radix decode
         d = jnp.zeros((SUBLANES, LANES), dtype)
         p = jnp.zeros((SUBLANES, LANES), dtype)
@@ -137,14 +179,10 @@ def _enum_kernel(
     # Eq. (7): mean sojourn of the l(k) successful jobs (0 when l = 0);
     # Eq. (9): the probability-weighted sum, tiled into the scratch.
     mean = jnp.where(cnt > 0, tot / jnp.maximum(cnt, 1).astype(dtype), 0.0)
-    acc_succ[...] += w * mean
-    acc_all[...] += w * (tsum / n)
-
-    @pl.when(kt == nkt - 1)
-    def _finalize():
-        _flush(succ_ref, all_ref, acc_succ, acc_all)
+    accumulate(kt, nkt, (succ_ref, all_ref), scratch, (w * mean, w * (tsum / n)))
 
 
+@functools.partial(jax.jit, static_argnames=("k_total", "interpret"))
 def sojourn_enum(
     sizes_p: jax.Array,  # (P, N, M) per-order permuted cumulative sizes
     probs_p: jax.Array,  # (P, N, M) per-order permuted probabilities
@@ -159,30 +197,22 @@ def sojourn_enum(
     nkt = max(1, pl.cdiv(k_total, BLOCK_COMBOS))
     dtype = sizes_p.dtype
     kernel = functools.partial(_enum_kernel, n=n, m=m, k_total=k_total, nkt=nkt)
+    out_specs, out_shape = scalar_outputs(p_orders, dtype)
     out_succ, out_all = pl.pallas_call(
         kernel,
         grid=(p_orders, nkt),
         in_specs=[
-            pl.BlockSpec((1, n), lambda p, kt: (p, 0), memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, n), lambda p, kt: (p, 0), memory_space=pltpu.SMEM),
+            per_row_smem(n),
+            per_row_smem(n),
             pl.BlockSpec((1, n, m), lambda p, kt: (p, 0, 0)),
             pl.BlockSpec((1, n, m), lambda p, kt: (p, 0, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, 1), lambda p, kt: (p, 0)),
-            pl.BlockSpec((1, 1), lambda p, kt: (p, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((p_orders, 1), dtype),
-            jax.ShapeDtypeStruct((p_orders, 1), dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((SUBLANES, LANES), dtype),
-            pltpu.VMEM((SUBLANES, LANES), dtype),
-        ],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=reduction_scratch(dtype),
         interpret=interpret,
-    )(strides_p, radix_p, sizes_p, probs_p)
-    return out_succ[:, 0], out_all[:, 0]
+    )(strides_p[:, None], radix_p[:, None], sizes_p, probs_p)
+    return out_succ[:, 0, 0], out_all[:, 0, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -191,36 +221,28 @@ def sojourn_enum(
 
 
 def _outcomes_kernel(
-    order_ref,  # (1, N) int32 SMEM: original job id served at each position
-    radix_ref,  # (1, N) int32 SMEM, per-order permuted stage counts
+    order_ref,  # (1, 1, N) int32 SMEM: original job id served at each position
+    radix_ref,  # (1, 1, N) int32 SMEM, per-order permuted stage counts
     sizes_ref,  # (1, N, M) VMEM, per-order permuted cumulative sizes
     outcomes_ref,  # (N, 1, SUBLANES, LANES) int32 VMEM, original job indexing
     weights_ref,  # (1, SUBLANES, LANES) VMEM, zero-padded combination weights
-    succ_ref,  # (1, 1) out
-    all_ref,  # (1, 1) out
-    acc_succ,
-    acc_all,
-    *,
+    succ_ref,  # (1, 1, 1) SMEM out
+    all_ref,  # (1, 1, 1) SMEM out
+    *scratch,  # reduction_scratch
     n: int,
     m: int,
     nkt: int,
 ):
     kt = pl.program_id(1)
-
-    @pl.when(kt == 0)
-    def _init():
-        acc_succ[...] = jnp.zeros_like(acc_succ)
-        acc_all[...] = jnp.zeros_like(acc_all)
-
-    dtype = acc_succ.dtype
+    dtype = sizes_ref.dtype
     w = weights_ref[0]  # tail tiles are weight-padded with zeros
     t = jnp.zeros((SUBLANES, LANES), dtype)
     tsum = jnp.zeros((SUBLANES, LANES), dtype)
     tot = jnp.zeros((SUBLANES, LANES), dtype)
     cnt = jnp.zeros((SUBLANES, LANES), jnp.int32)
     for pos in range(n):
-        job = order_ref[0, pos]
-        radix = radix_ref[0, pos]
+        job = order_ref[0, 0, pos]
+        radix = radix_ref[0, 0, pos]
         s = outcomes_ref[job, 0]  # (SUBLANES, LANES) realized stop stages
         d = jnp.zeros((SUBLANES, LANES), dtype)
         for j in range(m):
@@ -231,14 +253,10 @@ def _outcomes_kernel(
         cnt = cnt + succ.astype(jnp.int32)
         tsum = tsum + t
     mean = jnp.where(cnt > 0, tot / jnp.maximum(cnt, 1).astype(dtype), 0.0)
-    acc_succ[...] += w * mean
-    acc_all[...] += w * (tsum / n)
-
-    @pl.when(kt == nkt - 1)
-    def _finalize():
-        _flush(succ_ref, all_ref, acc_succ, acc_all)
+    accumulate(kt, nkt, (succ_ref, all_ref), scratch, (w * mean, w * (tsum / n)))
 
 
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def sojourn_outcomes(
     sizes_p: jax.Array,  # (P, N, M) per-order permuted cumulative sizes
     radix_p: jax.Array,  # (P, N) int32 permuted stage counts
@@ -253,31 +271,23 @@ def sojourn_outcomes(
     nkt = weights_t.shape[0]
     dtype = sizes_p.dtype
     kernel = functools.partial(_outcomes_kernel, n=n, m=m, nkt=nkt)
+    out_specs, out_shape = scalar_outputs(p_orders, dtype)
     out_succ, out_all = pl.pallas_call(
         kernel,
         grid=(p_orders, nkt),
         in_specs=[
-            pl.BlockSpec((1, n), lambda p, kt: (p, 0), memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, n), lambda p, kt: (p, 0), memory_space=pltpu.SMEM),
+            per_row_smem(n),
+            per_row_smem(n),
             pl.BlockSpec((1, n, m), lambda p, kt: (p, 0, 0)),
             pl.BlockSpec((n, 1, SUBLANES, LANES), lambda p, kt: (0, kt, 0, 0)),
             pl.BlockSpec((1, SUBLANES, LANES), lambda p, kt: (kt, 0, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, 1), lambda p, kt: (p, 0)),
-            pl.BlockSpec((1, 1), lambda p, kt: (p, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((p_orders, 1), dtype),
-            jax.ShapeDtypeStruct((p_orders, 1), dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((SUBLANES, LANES), dtype),
-            pltpu.VMEM((SUBLANES, LANES), dtype),
-        ],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=reduction_scratch(dtype),
         interpret=interpret,
-    )(orders, radix_p, sizes_p, outcomes_t, weights_t)
-    return out_succ[:, 0], out_all[:, 0]
+    )(orders[:, None], radix_p[:, None], sizes_p, outcomes_t, weights_t)
+    return out_succ[:, 0, 0], out_all[:, 0, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -287,28 +297,20 @@ def sojourn_outcomes(
 
 def _mc_kernel(
     seed_ref,  # (1, 2) int32 SMEM: the two 31-bit Threefry key words
-    order_ref,  # (1, N) int32 SMEM: original job id served at each position
-    radix_ref,  # (1, N) int32 SMEM, per-order permuted stage counts
+    order_ref,  # (1, 1, N) int32 SMEM: original job id served at each position
+    radix_ref,  # (1, 1, N) int32 SMEM, per-order permuted stage counts
     sizes_ref,  # (1, N, M) VMEM, per-order permuted cumulative sizes
     cdf_ref,  # (1, N, M) VMEM, per-order permuted stop-probability CDF
-    succ_ref,  # (1, 1) out
-    all_ref,  # (1, 1) out
-    acc_succ,
-    acc_all,
-    *,
+    succ_ref,  # (1, 1, 1) SMEM out
+    all_ref,  # (1, 1, 1) SMEM out
+    *scratch,  # reduction_scratch
     n: int,
     m: int,
     n_samples: int,
     nkt: int,
 ):
     kt = pl.program_id(1)
-
-    @pl.when(kt == 0)
-    def _init():
-        acc_succ[...] = jnp.zeros_like(acc_succ)
-        acc_all[...] = jnp.zeros_like(acc_all)
-
-    dtype = acc_succ.dtype
+    dtype = sizes_ref.dtype
     k = _tile_combo_ids(kt)  # lanes own global sample indices
     key = (seed_ref[0, 0].astype(jnp.uint32), seed_ref[0, 1].astype(jnp.uint32))
     x0 = k.astype(jnp.uint32)
@@ -319,8 +321,8 @@ def _mc_kernel(
     tot = jnp.zeros((SUBLANES, LANES), dtype)
     cnt = jnp.zeros((SUBLANES, LANES), jnp.int32)
     for pos in range(n):
-        job = order_ref[0, pos]  # RNG counter keyed by ORIGINAL job id
-        radix = radix_ref[0, pos]
+        job = order_ref[0, 0, pos]  # RNG counter keyed by ORIGINAL job id
+        radix = radix_ref[0, 0, pos]
         x1 = (jnp.zeros((SUBLANES, LANES), jnp.int32) + job).astype(jnp.uint32)
         bits, _ = rng.threefry2x32(jnp, key, x0, x1)
         u = rng.uniform_from_bits(bits, dtype)
@@ -338,20 +340,16 @@ def _mc_kernel(
         cnt = cnt + succ.astype(jnp.int32)
         tsum = tsum + t
     mean = jnp.where(cnt > 0, tot / jnp.maximum(cnt, 1).astype(dtype), 0.0)
-    acc_succ[...] += w * mean
-    acc_all[...] += w * (tsum / n)
-
-    @pl.when(kt == nkt - 1)
-    def _finalize():
-        _flush(succ_ref, all_ref, acc_succ, acc_all)
+    accumulate(kt, nkt, (succ_ref, all_ref), scratch, (w * mean, w * (tsum / n)))
 
 
+@functools.partial(jax.jit, static_argnames=("n_samples", "interpret"))
 def sojourn_mc(
     sizes_p: jax.Array,  # (P, N, M) per-order permuted cumulative sizes
     cdf_p: jax.Array,  # (P, N, M) per-order permuted stop-probability CDF
     radix_p: jax.Array,  # (P, N) int32 permuted stage counts
     orders: jax.Array,  # (P, N) int32 original job ids by position
-    seed: int,
+    key: jax.Array,  # (2,) int32 Threefry key words, rng.split_seed(seed)
     n_samples: int,
     *,
     interpret: bool = False,
@@ -360,32 +358,23 @@ def sojourn_mc(
     p_orders, n, m = sizes_p.shape
     nkt = max(1, pl.cdiv(n_samples, BLOCK_COMBOS))
     dtype = sizes_p.dtype
-    seed_arr = jnp.asarray([rng.split_seed(seed)], jnp.int32)  # (1, 2)
     kernel = functools.partial(
         _mc_kernel, n=n, m=m, n_samples=n_samples, nkt=nkt
     )
+    out_specs, out_shape = scalar_outputs(p_orders, dtype)
     out_succ, out_all = pl.pallas_call(
         kernel,
         grid=(p_orders, nkt),
         in_specs=[
             pl.BlockSpec((1, 2), lambda p, kt: (0, 0), memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, n), lambda p, kt: (p, 0), memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, n), lambda p, kt: (p, 0), memory_space=pltpu.SMEM),
+            per_row_smem(n),
+            per_row_smem(n),
             pl.BlockSpec((1, n, m), lambda p, kt: (p, 0, 0)),
             pl.BlockSpec((1, n, m), lambda p, kt: (p, 0, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, 1), lambda p, kt: (p, 0)),
-            pl.BlockSpec((1, 1), lambda p, kt: (p, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((p_orders, 1), dtype),
-            jax.ShapeDtypeStruct((p_orders, 1), dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((SUBLANES, LANES), dtype),
-            pltpu.VMEM((SUBLANES, LANES), dtype),
-        ],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=reduction_scratch(dtype),
         interpret=interpret,
-    )(seed_arr, orders, radix_p, sizes_p, cdf_p)
-    return out_succ[:, 0], out_all[:, 0]
+    )(key.reshape(1, 2), orders[:, None], radix_p[:, None], sizes_p, cdf_p)
+    return out_succ[:, 0, 0], out_all[:, 0, 0]

@@ -30,14 +30,18 @@ outcome combinations:
   (common random numbers), and ``ref.ref_mc_outcomes`` replays the
   stream host-side bitwise for parity.
 
-Precision follows the ambient JAX x64 mode: the evaluator calls this op
-under ``jax.experimental.enable_x64`` so everything accumulates in
-float64 (<=1e-9 parity with the seed path); on TPU the compiled kernels
-run in float32.
+Precision: ``impl="pallas"`` always runs the kernels in float32, since
+Mosaic has no 64-bit types; its error against the float64 oracle is
+bounded per entry mode by :data:`CHIP_RTOL`.  ``"xla"`` and
+``"interpret"`` follow the ambient JAX x64 mode: the evaluator calls
+this op under :func:`repro.runtime.x64`, so on the CPU everything
+accumulates in float64 (<=1e-9 parity with the dense oracle).  The
+host-side table preparation is float64 in every mode.
 """
 
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Literal
 
@@ -50,9 +54,18 @@ from repro.kernels.sojourn_eval import rng
 from repro.kernels.sojourn_eval.ref import mixed_radix_strides
 from repro.obs import profiling
 
-__all__ = ["sojourn_eval"]
+__all__ = ["sojourn_eval", "CHIP_RTOL"]
 
 Impl = Literal["auto", "xla", "pallas", "interpret"]
+
+#: Largest relative error of the float32 Pallas path against the float64
+#: oracle, per entry mode, at the sizes ``chip_smoke.py`` runs.  Shared by
+#: that script and the float32 parity tests.  On a TPU v5e the largest
+#: errors seen were 4.1e-7 (enum, 9! orders and K = 2**26), 2.3e-8
+#: (outcomes) and 3.8e-8 (mc, 2**20 samples).  "mc" has room for a few
+#: samples whose float32 uniform lands on the other side of a float32 CDF
+#: entry than in float64: each moves the mean by about 1/n_samples.
+CHIP_RTOL = {"enum": 1e-6, "outcomes": 1e-6, "mc": 1e-5}
 
 #: Combination indices per XLA scan tile (bounded-memory streaming).
 XLA_TILE = 1 << 15
@@ -60,12 +73,29 @@ XLA_TILE = 1 << 15
 _TILE_BYTES_BUDGET = 256 << 20
 
 
-def _resolve(impl: str) -> str:
+def resolve_impl(impl: str) -> str:
+    """``"auto"`` is the Pallas kernels on a TPU and the XLA path elsewhere."""
     if impl == "auto":
         return "pallas" if jax.default_backend() == "tpu" else "xla"
     if impl not in ("xla", "pallas", "interpret"):
         raise ValueError(f"unknown impl {impl!r}; options: auto/xla/pallas/interpret")
     return impl
+
+
+def compute_dtype(impl: str):
+    """float32 for the compiled kernels, else the ambient x64 mode's float."""
+    return jnp.float32 if impl == "pallas" else jnp.result_type(float)
+
+
+def precision_scope(impl: str):
+    """Scope an op call runs in: x64 off for the compiled kernels.
+
+    Under x64 the kernels' index maps and literals trace as 64-bit
+    integers, which Mosaic cannot lower, so ``"pallas"`` traces with x64
+    off even when called from inside :func:`repro.runtime.x64`.  The
+    other impls keep the ambient mode.
+    """
+    return jax.enable_x64(False) if impl == "pallas" else contextlib.nullcontext()
 
 
 def _order_batch(n_orders: int, tile: int, n: int) -> int:
@@ -233,11 +263,11 @@ def sojourn_eval(
     numpy conversions inside synchronize the device work, so the span
     is end-to-end wall clock).
     """
-    impl = _resolve(impl)
+    impl = resolve_impl(impl)
     mode = "mc" if samples is not None else (
         "enum" if outcomes is None else "outcomes"
     )
-    with profiling.span(f"sojourn_eval.static.{mode}.{impl}"):
+    with profiling.span(f"sojourn_eval.static.{mode}.{impl}"), precision_scope(impl):
         return _sojourn_eval(
             sizes, probs, num_stages, orders,
             outcomes=outcomes, weights=weights, samples=samples, impl=impl,
@@ -258,7 +288,7 @@ def _sojourn_eval(
     if orders.ndim != 2 or orders.shape[1] != n:
         raise ValueError(f"orders must be (P, {n}); got {orders.shape}")
     strides = mixed_radix_strides(num_stages)
-    fdt = jnp.asarray(sizes).dtype  # f64 under x64, else f32
+    fdt = compute_dtype(impl)
     sizes_j = jnp.asarray(sizes, fdt)
     probs_j = jnp.asarray(probs, fdt)
 
@@ -295,7 +325,7 @@ def _sojourn_eval(
                     jnp.asarray(cdf_p, fdt),
                     jnp.asarray(rx_p),
                     jnp.asarray(ob),
-                    seed,
+                    jnp.asarray(rng.split_seed(seed), jnp.int32),
                     n_samples,
                     interpret=interpret,
                 )

@@ -25,7 +25,6 @@ bitwise.
 
 from __future__ import annotations
 
-import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.sojourn_eval import rng
@@ -80,29 +79,44 @@ def ref_sojourn(
     outcomes=None,  # optional (K, N) explicit outcome matrix
     weights=None,  # optional (K,) combination weights
 ):
-    """(E[sojourn successful], E[sojourn all]) per order, dense."""
-    sizes = jnp.asarray(sizes)
+    """(E[sojourn successful], E[sojourn all]) per order, dense, float64.
+
+    A job completes when every job served up to and including it has run,
+    so with ``d[k, j]`` job j's realized duration in combination k,
+    ``l_k`` the combination's number of successes and ``pos(j)`` job j's
+    service position, Eqs. (7)-(9) are bilinear in the ``(K, N)`` tables::
+
+        E_succ = sum over pos(j) <= pos(i) of A[i, j],
+                 A[i, j] = sum_k w_k succ[k, i] d[k, j] / l_k  (0 if l_k = 0)
+        E_all  = sum_j (N - pos(j)) D[j] / N,  D[j] = sum_k w_k d[k, j]
+
+    Only the ``(N, N)`` and ``(N,)`` contractions touch the K axis, so a
+    whole batch of orders (all 9! at N = 9) is scored at once, by a
+    formula the fused ops' per-combination prefix sums do not share.
+    """
+    sizes = np.asarray(sizes, np.float64)
     num_stages = np.asarray(num_stages)
     n = sizes.shape[0]
     if outcomes is None:
-        k_total = int(np.prod(num_stages, dtype=np.int64))
-        outcomes = ref_decode(num_stages, k_total)
+        outcomes = ref_decode(num_stages, int(np.prod(num_stages, dtype=np.int64)))
         weights = np.prod(
-            np.asarray(probs)[np.arange(n)[None, :], outcomes], axis=1
+            np.asarray(probs, np.float64)[np.arange(n)[None, :], outcomes], axis=1
         )
-    outcomes = jnp.asarray(outcomes)
-    weights = jnp.asarray(weights)
-    d = sizes[jnp.arange(n)[None, :], outcomes]  # (K, N)
-    succ = outcomes == jnp.asarray(num_stages)[None, :] - 1
-    cnt = jnp.sum(succ, axis=1)
-    e_succ, e_all = [], []
-    for order in np.asarray(orders):
-        t = jnp.cumsum(jnp.take(d, order, axis=1), axis=1)
-        tot = jnp.sum(t * jnp.take(succ, order, axis=1), axis=1)
-        mean = jnp.where(cnt > 0, tot / jnp.maximum(cnt, 1), 0.0)
-        e_succ.append(jnp.dot(weights, mean))
-        e_all.append(jnp.dot(weights, jnp.mean(t, axis=1)))
-    return jnp.stack(e_succ), jnp.stack(e_all)
+    outcomes = np.asarray(outcomes)
+    weights = np.asarray(weights, np.float64)
+    d = sizes[np.arange(n)[None, :], outcomes]  # (K, N)
+    succ = outcomes == num_stages[None, :] - 1
+    cnt = succ.sum(axis=1)
+    w_mean = np.where(cnt > 0, weights / np.maximum(cnt, 1), 0.0)
+    a = (succ * w_mean[:, None]).T @ d  # (N, N)
+    dsum = weights @ d  # (N,)
+    pos = np.argsort(np.asarray(orders), axis=1)  # pos[p, j]: position of job j
+    e_succ = np.concatenate([
+        np.einsum("pij,ij->p", (c[:, None, :] <= c[:, :, None]).astype(np.float64), a)
+        for c in np.array_split(pos, max(1, len(pos) >> 14))
+    ])
+    e_all = (n - pos) @ dsum / n
+    return e_succ, e_all
 
 
 def ref_sojourn_dynamic(

@@ -84,8 +84,16 @@ def threefry2x32(xp, key: tuple, x0, x1):
 def uniform_from_bits(bits, dtype):
     """uint32 bits -> uniform in [0, 1).  Exact in float64 (bits < 2**32
     times a power of two), so comparisons against a shared CDF are
-    reproducible bit-for-bit across NumPy / XLA / Pallas."""
-    return bits.astype(dtype) * 2.0**-32
+    reproducible bit-for-bit across NumPy / XLA / Pallas.
+
+    The word is converted as two 16-bit halves because Mosaic has no
+    uint32 -> float cast.  Both halves are exact in any float dtype and
+    the sum rounds once, so the result equals ``bits * 2**-32`` rounded
+    to ``dtype``, as a direct cast would give.
+    """
+    hi = (bits >> 16).astype(np.int32)
+    lo = (bits & 0xFFFF).astype(np.int32)
+    return hi.astype(dtype) * 2.0**-16 + lo.astype(dtype) * 2.0**-32
 
 
 # ---------------------------------------------------------------------------

@@ -6,7 +6,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from repro.parallel.sharding import shard_map  # version-compat shim
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels.flash_attention.ops import flash_attention
@@ -209,7 +208,7 @@ def sp_decode_attention(
         o = num / jnp.maximum(den, 1e-30).transpose(0, 2, 1)[..., None]
         return o.astype(q.dtype)
 
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(qspec, dspec, dspec, P()),

@@ -15,6 +15,11 @@ Three pieces, wired through every scheduling layer:
 
 ``python -m repro.obs.report`` replays a synthetic Philly-trace
 workload and writes the trace + metrics artifacts.
+
+The recorder is imported on first use: it depends on ``repro.core``,
+whose evaluator imports the kernels, which import
+:mod:`repro.obs.profiling`.  Loading it eagerly here would make
+``import repro.kernels.sojourn_eval`` circular.
 """
 
 from repro.obs.metrics import (  # noqa: F401
@@ -23,4 +28,13 @@ from repro.obs.metrics import (  # noqa: F401
     get_registry,
     record_run_metrics,
 )
-from repro.obs.recorder import TraceRecorder, validate_chrome_trace  # noqa: F401
+
+_RECORDER_NAMES = ("TraceRecorder", "validate_chrome_trace")
+
+
+def __getattr__(name):
+    if name in _RECORDER_NAMES:
+        from repro.obs import recorder
+
+        return getattr(recorder, name)
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

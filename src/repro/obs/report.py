@@ -34,6 +34,7 @@ from repro.core import policies, trace
 from repro.obs import metrics as obs_metrics
 from repro.obs import profiling
 from repro.obs.recorder import TraceRecorder, validate_chrome_trace
+from repro.runtime import init_compile_cache
 
 __all__ = ["main", "replay", "bench_overhead"]
 
@@ -195,4 +196,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    init_compile_cache()
     raise SystemExit(main())

@@ -19,7 +19,6 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from repro.parallel.sharding import shard_map  # version-compat shim
 from jax.sharding import Mesh, PartitionSpec as P
 
 __all__ = ["quantize", "dequantize", "ef_compress", "compressed_psum"]
@@ -77,9 +76,9 @@ def compressed_psum(grads: Any, residuals: Any, mesh: Mesh, axes=("data",)) -> t
         outs = [reduce_one(g, r) for g, r in zip(flat[:k], flat[k:])]
         return tuple(o[0] for o in outs) + tuple(o[1] for o in outs)
 
-    outs = shard_map(
+    outs = jax.shard_map(
         body, mesh=mesh, in_specs=spec_in + spec_in,
-        out_specs=spec_in + spec_in, check_rep=False,
+        out_specs=spec_in + spec_in, check_vma=False,
     )(*flat_g, *flat_r)
     k = len(flat_g)
     return treedef.unflatten(outs[:k]), treedef.unflatten(outs[k:])

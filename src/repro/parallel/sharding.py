@@ -21,8 +21,6 @@ rule table serves both single-pod and multi-pod meshes.
 from __future__ import annotations
 
 import dataclasses
-import functools
-import inspect
 from typing import Any, Mapping, Sequence
 
 import jax
@@ -34,41 +32,11 @@ __all__ = [
     "DEFAULT_RULES",
     "LONG_CONTEXT_RULES",
     "logical_sharding",
-    "shard_map",
     "shard_pytree_spec",
     "with_logical_constraint",
     "mesh_axis_sizes",
 ]
 
-
-# ---------------------------------------------------------------------------
-# shard_map version compat
-# ---------------------------------------------------------------------------
-#
-# ``jax.shard_map`` only exists on newer JAX; older versions expose it as
-# ``jax.experimental.shard_map.shard_map``.  The replication-check kwarg
-# was also renamed (``check_rep`` -> ``check_vma``).  All repo call sites
-# import from here and may use either kwarg name; we translate to whatever
-# the installed JAX accepts.
-
-
-def _resolve_shard_map():
-    fn = getattr(jax, "shard_map", None)
-    if fn is None:
-        from jax.experimental.shard_map import shard_map as fn  # noqa: PLC0415
-    params = inspect.signature(fn).parameters
-    return fn, params
-
-
-def shard_map(f=None, /, **kwargs):
-    """Version-portable ``shard_map`` (accepts check_rep or check_vma)."""
-    fn, params = _resolve_shard_map()
-    for old, new in (("check_rep", "check_vma"), ("check_vma", "check_rep")):
-        if old in kwargs and old not in params and new in params:
-            kwargs[new] = kwargs.pop(old)
-    if f is None:
-        return functools.partial(shard_map, **kwargs)
-    return fn(f, **kwargs)
 
 MeshAxes = tuple[str, ...]
 

@@ -21,7 +21,6 @@ here unconditionally; the hypothesis property-based version lives in
 
 import dataclasses
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -30,6 +29,7 @@ from repro.core import evaluator, policies, simulator
 from repro.core.jobs import JobSpec, generate_workload
 from repro.kernels.sojourn_eval import sojourn_eval_dynamic
 from repro.kernels.sojourn_eval.ref import ref_sojourn_dynamic
+from repro.runtime import x64
 
 RTOL = 1e-9
 IMPLS = ("xla", "interpret")
@@ -49,7 +49,7 @@ def _tables(jobs, policy):
 
 def fused(jobs, policy, impl, n_servers=1):
     probs, durs, num_stages, idx = _tables(jobs, policy)
-    with jax.experimental.enable_x64(True):
+    with x64():
         es, ea = sojourn_eval_dynamic(
             probs, durs, num_stages, idx, n_servers=n_servers, impl=impl
         )
@@ -61,14 +61,14 @@ def seed_batch(jobs, policy):
     probs, durs, num_stages, idx = _tables(jobs, policy)
     outcomes, weights = evaluator.enumerate_outcomes(jobs)
     _, success = evaluator._realized_arrays(jobs, outcomes)
-    with jax.experimental.enable_x64(True):
+    with x64():
         return float(
             evaluator._dynamic_batch(
-                jnp.asarray(np.float64(idx)),
-                jnp.asarray(np.float64(durs)),
+                jnp.asarray(np.asarray(idx, np.float64)),
+                jnp.asarray(np.asarray(durs, np.float64)),
                 jnp.asarray(outcomes),
                 jnp.asarray(success),
-                jnp.asarray(np.float64(weights)),
+                jnp.asarray(np.asarray(weights, np.float64)),
                 int(num_stages.sum()),
             )
         )
@@ -190,7 +190,7 @@ def test_multi_server_streamed_mc_matches_host_replay(n_servers):
         probs, durs, num_stages, idx,
         outcomes=outcomes, weights=weights, n_servers=n_servers,
     )
-    with jax.experimental.enable_x64(True):
+    with x64():
         for impl in IMPLS:
             es, ea = sojourn_eval_dynamic(
                 probs, durs, num_stages, idx,
@@ -224,7 +224,7 @@ def test_policy_batch_matches_single(impl):
     tabs = np.stack(
         [np.asarray(policies.index_table(jobs, p)) for p in POLICIES]
     )
-    with jax.experimental.enable_x64(True):
+    with x64():
         es_b, ea_b = sojourn_eval_dynamic(probs, durs, num_stages, tabs, impl=impl)
         for i, p in enumerate(POLICIES):
             es, ea = sojourn_eval_dynamic(
@@ -246,7 +246,7 @@ def test_fixed_priority_table_matches_static_order(impl):
     for pos, i in enumerate(order):
         table[i, :] = pos
     probs, durs, num_stages, _ = _tables(jobs, "sr")
-    with jax.experimental.enable_x64(True):
+    with x64():
         es, ea = sojourn_eval_dynamic(probs, durs, num_stages, table, impl=impl)
     want = evaluator.expected_sojourn_static(jobs, order, also_all_jobs=True)
     np.testing.assert_allclose(float(es[0]), float(want[0]), rtol=RTOL)

@@ -9,14 +9,21 @@ the XLA streaming path compiled; both under x64.
 
 import itertools
 
-import jax
 import numpy as np
 import pytest
 
 from repro.core import evaluator, policies
 from repro.core.jobs import JobSpec, generate_workload
-from repro.kernels.sojourn_eval import sojourn_eval
-from repro.kernels.sojourn_eval.ref import mixed_radix_strides, ref_decode, ref_sojourn
+from repro.kernels.sojourn_eval import sojourn_eval, sojourn_eval_dynamic
+from repro.kernels.sojourn_eval.ops import CHIP_RTOL
+from repro.kernels.sojourn_eval.ref import (
+    mixed_radix_strides,
+    ref_decode,
+    ref_mc_outcomes,
+    ref_sojourn,
+    ref_sojourn_dynamic,
+)
+from repro.runtime import x64
 
 RTOL = 1e-9
 IMPLS = ("xla", "interpret")
@@ -30,12 +37,7 @@ def _orders(n, rng, p=6):
 
 def _ref(jobs, orders, outcomes=None, weights=None):
     sizes, probs, num_stages = policies.padded_arrays(jobs)
-    with jax.experimental.enable_x64(True):
-        es, ea = ref_sojourn(
-            np.float64(sizes), np.float64(probs), num_stages, orders,
-            outcomes, weights,
-        )
-    return np.asarray(es), np.asarray(ea)
+    return ref_sojourn(sizes, probs, num_stages, orders, outcomes, weights)
 
 
 def _relerr(a, b):
@@ -222,9 +224,9 @@ def test_parity_vs_seed_static_batch(n, m):
     orders = _orders(n, rng)
     outcomes, weights = evaluator.enumerate_outcomes(jobs)
     durations, success = evaluator._realized_arrays(jobs, outcomes)
-    with jax.experimental.enable_x64(True):
+    with x64():
         seed_es, seed_ea = evaluator._static_batch(
-            np.float64(durations), success, np.float64(weights), orders,
+            np.asarray(durations, np.float64), success, np.asarray(weights, np.float64), orders,
             also_all_jobs=True,
         )
     seed_es, seed_ea = np.asarray(seed_es), np.asarray(seed_ea)
@@ -302,9 +304,68 @@ def test_workload_cache_hits_and_readonly():
 
 def sojourn_eval_x64(jobs, orders, outcomes=None, weights=None, impl="xla"):
     sizes, probs, num_stages = policies.padded_arrays(jobs)
-    with jax.experimental.enable_x64(True):
+    with x64():
         es, ea = sojourn_eval(
             sizes, probs, num_stages, np.asarray(orders, np.int32),
             outcomes=outcomes, weights=weights, impl=impl,
         )
     return np.asarray(es), np.asarray(ea)
+
+
+# ---------------------------------------------------------------------------
+# Float32 kernels, as compiled for the chip, against the float64 oracle
+# ---------------------------------------------------------------------------
+
+F32_SEED = 0x5EED
+F32_SAMPLES = 5000  # five sample tiles, the last one partial
+
+
+def _f32_workload():
+    # K = 3**8 = 6561: seven combination tiles, the last one partial;
+    # Weibull stage sizes (workload set 5) give the widest spread of terms.
+    jobs = generate_workload(np.random.default_rng(23), 8, num_stages=3, workload_set=5)
+    sizes, probs, num_stages = policies.padded_arrays(jobs)
+    return jobs, sizes, probs, num_stages
+
+
+@pytest.mark.parametrize("mode", ("enum", "outcomes", "mc"))
+def test_static_float32_kernels_within_chip_tolerance(mode):
+    jobs, sizes, probs, num_stages = _f32_workload()
+    orders = _orders(8, np.random.default_rng(29))
+    outcomes = weights = None
+    if mode != "enum":
+        outcomes, weights = ref_mc_outcomes(probs, num_stages, F32_SEED, F32_SAMPLES)
+    # Outside the x64 scope the interpreted kernels compute in float32.
+    es, ea = sojourn_eval(
+        sizes, probs, num_stages, orders,
+        outcomes=outcomes if mode == "outcomes" else None,
+        weights=weights if mode == "outcomes" else None,
+        samples=(F32_SEED, F32_SAMPLES) if mode == "mc" else None,
+        impl="interpret",
+    )
+    assert es.dtype == np.float32
+    want_s, want_a = ref_sojourn(sizes, probs, num_stages, orders, outcomes, weights)
+    assert _relerr(es, want_s) <= CHIP_RTOL[mode]
+    assert _relerr(ea, want_a) <= CHIP_RTOL[mode]
+
+
+@pytest.mark.parametrize("n_servers", (1, 3))
+@pytest.mark.parametrize("mode", ("enum", "mc"))
+def test_dynamic_float32_kernels_within_chip_tolerance(mode, n_servers):
+    jobs, _, probs, num_stages = _f32_workload()
+    durs = policies.stage_durations(jobs)
+    tables = np.stack([policies.index_table(jobs, p) for p in ("sr", "serpt")])
+    outcomes = weights = None
+    if mode == "mc":
+        outcomes, weights = ref_mc_outcomes(probs, num_stages, F32_SEED, F32_SAMPLES)
+    es, ea = sojourn_eval_dynamic(
+        probs, durs, num_stages, tables, n_servers=n_servers, impl="interpret",
+        samples=(F32_SEED, F32_SAMPLES) if mode == "mc" else None,
+    )
+    assert es.dtype == np.float32
+    for p, table in enumerate(tables):
+        want_s, want_a = ref_sojourn_dynamic(
+            probs, durs, num_stages, table, outcomes, weights, n_servers
+        )
+        assert _relerr(es[p], want_s) <= CHIP_RTOL[mode]
+        assert _relerr(ea[p], want_a) <= CHIP_RTOL[mode]

@@ -29,6 +29,7 @@ from repro.core import evaluator, policies
 from repro.core.jobs import JobSpec, generate_workload
 from repro.kernels.sojourn_eval import rng, sojourn_eval
 from repro.kernels.sojourn_eval.ref import ref_mc_outcomes
+from repro.runtime import x64
 
 IMPLS = ("xla", "interpret")
 SEED = 0x5EED_CAFE
@@ -107,9 +108,7 @@ def test_streamed_static_matches_host_replay(impl):
     orders = np.stack([np.arange(5), np.argsort(-np.arange(5))]).astype(np.int32)
     n_samples = 2048
     outcomes, weights = ref_mc_outcomes(probs, num_stages, SEED, n_samples)
-    import jax
-
-    with jax.experimental.enable_x64(True):
+    with x64():
         want = sojourn_eval(
             sizes, probs, num_stages, orders,
             outcomes=outcomes, weights=weights, impl="xla",
@@ -148,9 +147,7 @@ def test_streamed_non_pow2_sample_count_tail_masked():
     order = np.arange(4, dtype=np.int32)[None]
     n_samples = 1000  # not a multiple of any tile shape
     outcomes, weights = ref_mc_outcomes(probs, num_stages, SEED, n_samples)
-    import jax
-
-    with jax.experimental.enable_x64(True):
+    with x64():
         want = sojourn_eval(
             sizes, probs, num_stages, order,
             outcomes=outcomes, weights=weights, impl="xla",
