@@ -279,7 +279,7 @@ def run_phase(name, build, log) -> dict:
     spans = sorted(
         k[len("prof."): -len(".seconds")]
         for k in registry.snapshot()["histograms"]
-        if k.startswith("prof.sojourn_eval.")
+        if k.startswith("prof.sojourn_eval.") and k.endswith(".seconds")
     )
     impls = sorted({s.rsplit(".", 1)[1] for s in spans})
     errs, verdicts = check(results)

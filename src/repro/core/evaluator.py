@@ -59,6 +59,7 @@ from repro.core.jobs import Workload
 from repro.kernels.sojourn_eval import rng as kernel_rng
 from repro.kernels.sojourn_eval import sojourn_eval, sojourn_eval_dynamic
 from repro.kernels.sojourn_eval.ref import mixed_radix_strides
+from repro.obs import profiling
 from repro.runtime import x64
 
 __all__ = [
@@ -363,11 +364,16 @@ def expected_sojourn_dynamic(
 
 
 def optimal_order(jobs: Workload, max_n: int = 9) -> tuple[np.ndarray, float]:
-    """Exhaustive search over all N! non-preemptive orders (Thm III.1)."""
+    """Exhaustive search over all N! non-preemptive orders (Thm III.1).
+
+    Building the ``(N!, N)`` order array is the ``prof.optimal.orders``
+    span of :mod:`repro.obs.profiling`.
+    """
     n = len(jobs)
     if n > max_n:
         raise ValueError(f"exhaustive search with N={n} > {max_n} is too expensive")
-    orders = np.array(list(itertools.permutations(range(n))), dtype=np.int32)
+    with profiling.span("optimal.orders"):
+        orders = np.array(list(itertools.permutations(range(n))), dtype=np.int32)
     vals = expected_sojourn_static(jobs, orders)
     best = int(np.argmin(vals))
     return orders[best], float(vals[best])
@@ -433,12 +439,15 @@ def evaluate_many(
         counter-based stream is keyed by original job id, so all
         policies see the identical outcome sequence without any (S, N)
         sample table ever existing.
+
+    The call is the ``prof.evaluate_many`` span of :mod:`repro.obs.profiling`.
     """
-    k_total = exact_combination_count(jobs)
-    if k_total <= MAX_EXACT_COMBOS:
-        return {alg: evaluate(jobs, alg, rng=rng) for alg in algs}
-    seed = int(rng.integers(0, kernel_rng.MAX_SEED))
-    return {
-        alg: evaluate(jobs, alg, rng=rng, samples=(seed, mc_samples))
-        for alg in algs
-    }
+    with profiling.span("evaluate_many"):
+        k_total = exact_combination_count(jobs)
+        if k_total <= MAX_EXACT_COMBOS:
+            return {alg: evaluate(jobs, alg, rng=rng) for alg in algs}
+        seed = int(rng.integers(0, kernel_rng.MAX_SEED))
+        return {
+            alg: evaluate(jobs, alg, rng=rng, samples=(seed, mc_samples))
+            for alg in algs
+        }

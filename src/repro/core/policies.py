@@ -170,37 +170,36 @@ def _disk_evict(root: str, keep: str) -> None:
     limit = _disk_limit_bytes()
     if limit is None:
         return
-    t_prof = _prof.tick()
-    entries = []
-    total = 0
-    try:
-        names = os.listdir(root)
-    except OSError:
-        return
-    for name in names:
-        if not name.endswith(".npz"):
-            continue
-        path = os.path.join(root, name)
+    with _prof.span("cache.disk_evict"):
+        entries = []
+        total = 0
         try:
-            st = os.stat(path)
+            names = os.listdir(root)
         except OSError:
-            continue
-        entries.append((st.st_mtime, st.st_size, path))
-        total += st.st_size
-    entries.sort()
-    for _, size, path in entries:
-        if total <= limit:
-            break
-        if path == keep:
-            continue
-        try:
-            os.unlink(path)
-        except OSError:
-            continue
-        total -= size
-        with _cache_lock:
-            _disk_evictions += 1
-    _prof.tock("cache.disk_evict", t_prof)
+            return
+        for name in names:
+            if not name.endswith(".npz"):
+                continue
+            path = os.path.join(root, name)
+            try:
+                st = os.stat(path)
+            except OSError:
+                continue
+            entries.append((st.st_mtime, st.st_size, path))
+            total += st.st_size
+        entries.sort()
+        for _, size, path in entries:
+            if total <= limit:
+                break
+            if path == keep:
+                continue
+            try:
+                os.unlink(path)
+            except OSError:
+                continue
+            total -= size
+            with _cache_lock:
+                _disk_evictions += 1
 
 
 def _disk_load(path: str):
@@ -251,47 +250,49 @@ def workload_cached(kind: str, jobs: Workload, compute):
 
     Two tiers: the in-process LRU, then (when ``REPRO_CACHE_DIR`` is
     set) a cross-process disk memo of one ``.npz`` per entry.  With
-    :mod:`repro.obs.profiling` enabled, per-tier access latency is
-    recorded (``prof.cache.mem_hit`` / ``disk_load`` / ``miss_compute``
-    / ``disk_store`` / ``disk_evict`` histograms in the default
-    metrics registry).
+    :mod:`repro.obs.profiling` enabled, the whole call is the
+    ``prof.cache.lookup`` span (key hashing, the LRU, a memory hit), with
+    ``cache.disk_load``, ``cache.miss_compute``, ``cache.disk_store`` and
+    ``cache.disk_evict`` spans nested inside it.  ``compute()`` may look
+    up other cached values, so lookups nest too: their ``self_s``
+    histograms count each moment once.  Memory hits are counted by
+    :func:`cache_stats`.
     """
-    t_prof = _prof.tick()
-    digest = workload_key(jobs)
-    key = (kind, digest)
-    with _cache_lock:
-        counters = _cache_stats.setdefault(kind, [0, 0, 0, 0])
-        if key in _cache:
-            counters[0] += 1
-            _cache.move_to_end(key)
-            value = _cache[key]
-            _prof.tock("cache.mem_hit", t_prof)
-            return value
-        counters[1] += 1
-    path = _disk_path(kind, digest)
-    value = _disk_load(path) if path else None
-    if value is not None:
+    with _prof.span("cache.lookup"):
+        digest = workload_key(jobs)
+        key = (kind, digest)
         with _cache_lock:
-            counters[2] += 1
-        value = _freeze(value)
-        _prof.tock("cache.disk_load", t_prof)
-    else:
+            counters = _cache_stats.setdefault(kind, [0, 0, 0, 0])
+            if key in _cache:
+                counters[0] += 1
+                _cache.move_to_end(key)
+                return _cache[key]
+            counters[1] += 1
+        path = _disk_path(kind, digest)
         if path:
+            with _prof.span("cache.disk_load"):
+                value = _disk_load(path)
+        else:
+            value = None
+        if value is not None:
             with _cache_lock:
-                counters[3] += 1
-        t_compute = _prof.tick()
-        value = _freeze(compute())
-        _prof.tock("cache.miss_compute", t_compute)
-        if path:
-            t_store = _prof.tick()
-            _disk_store(path, value)
-            _prof.tock("cache.disk_store", t_store)
-    with _cache_lock:
-        _cache[key] = value
-        _cache.move_to_end(key)
-        while len(_cache) > _CACHE_CAPACITY:
-            _cache.popitem(last=False)
-    return value
+                counters[2] += 1
+            value = _freeze(value)
+        else:
+            if path:
+                with _cache_lock:
+                    counters[3] += 1
+            with _prof.span("cache.miss_compute"):
+                value = _freeze(compute())
+            if path:
+                with _prof.span("cache.disk_store"):
+                    _disk_store(path, value)
+        with _cache_lock:
+            _cache[key] = value
+            _cache.move_to_end(key)
+            while len(_cache) > _CACHE_CAPACITY:
+                _cache.popitem(last=False)
+        return value
 
 
 def clear_workload_cache() -> None:

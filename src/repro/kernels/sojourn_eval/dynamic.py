@@ -90,6 +90,7 @@ from repro.kernels.sojourn_eval.ops import (
     compute_dtype,
     precision_scope,
     resolve_impl,
+    run_batches,
 )
 from repro.kernels.sojourn_eval.ref import mixed_radix_strides
 from repro.obs import profiling
@@ -598,21 +599,28 @@ def sojourn_eval_dynamic(
     all arrivals at t=0.  Returns ``(P,)`` arrays (pass a single
     ``(N, M)`` table for ``P = 1``).
 
-    When :mod:`repro.obs.profiling` is enabled, each call is timed into
-    a ``prof.sojourn_eval.dynamic.<mode>.<impl>.seconds`` span.
+    When :mod:`repro.obs.profiling` is enabled, each call is timed as a
+    ``prof.sojourn_eval.dynamic.<mode>.<impl>`` span, tiled by the phase
+    spans ``prof.op_phase.dynamic.<impl>.{prep,put,call,sync}`` of
+    :func:`repro.kernels.sojourn_eval.ops.run_batches` (one batch of all
+    tables on the kernels, one table a batch on the XLA path).
     """
     impl = resolve_impl(impl)
     mode = "mc" if samples is not None else "enum"
-    with profiling.span(f"sojourn_eval.dynamic.{mode}.{impl}"), precision_scope(impl):
+    with (
+        profiling.span(f"sojourn_eval.dynamic.{mode}.{impl}"),
+        profiling.phases(f"op_phase.dynamic.{impl}", "prep") as phase,
+        precision_scope(impl),
+    ):
         return _sojourn_eval_dynamic(
             probs, stage_durs, num_stages, idx_tables,
-            samples=samples, n_servers=n_servers, impl=impl,
+            samples=samples, n_servers=n_servers, impl=impl, phase=phase,
         )
 
 
 def _sojourn_eval_dynamic(
     probs, stage_durs, num_stages, idx_tables, *,
-    samples=None, n_servers=1, impl="xla",
+    samples=None, n_servers=1, impl="xla", phase,
 ) -> tuple[np.ndarray, np.ndarray]:
     if n_servers < 1:
         raise ValueError(f"n_servers must be >= 1; got {n_servers}")
@@ -628,76 +636,73 @@ def _sojourn_eval_dynamic(
             f"idx_tables must be (P, {n}, {m}); got {idx_tables.shape}"
         )
     total_stages = int(num_stages.sum())
+    radix = num_stages.astype(np.int32)
     fdt = compute_dtype(impl)
+    interpret = impl == "interpret"
+    xla = impl == "xla"
     if samples is not None:
         seed, n_samples = int(samples[0]), int(samples[1])
         if n_samples <= 0:
             raise ValueError(f"n_samples must be positive; got {n_samples}")
-        cdf = np.cumsum(probs, axis=1)  # padded stages add 0 mass
-        if impl == "xla":
+        shared = [np.cumsum(probs, axis=1), stage_durs]  # padded stages add 0 mass
+        if xla:
             tile = min(
                 XLA_TILE, max(K.BLOCK_COMBOS, 1 << (n_samples - 1).bit_length())
             )
-            key2 = jnp.asarray(rng.split_seed(seed), jnp.uint32)
-            parts = [
-                _dynamic_mc_xla(
-                    jnp.asarray(cdf, fdt),
-                    jnp.asarray(stage_durs, fdt),
-                    jnp.asarray(table, fdt),
-                    key2,
+            shared.append(np.asarray(rng.split_seed(seed), np.uint32))
+
+            def per_batch(tables):
+                return [tables[0]]
+
+            def call(cdf, durs, key2, table):
+                return _dynamic_mc_xla(
+                    cdf, durs, table, key2,
                     radix=tuple(int(r) for r in num_stages),
                     n_samples=n_samples,
                     tile=tile,
                     total_stages=total_stages,
                     n_servers=n_servers,
                 )
-                for table in idx_tables
-            ]
-            e_succ = np.array([float(p[0]) for p in parts])
-            e_all = np.array([float(p[1]) for p in parts])
-            return e_succ, e_all
-        es, ea = dynamic_sojourn_mc(
-            jnp.asarray(cdf, fdt),
-            jnp.asarray(stage_durs, fdt),
-            jnp.asarray(idx_tables, fdt),
-            jnp.asarray(num_stages, jnp.int32),
-            jnp.asarray(rng.split_seed(seed), jnp.int32),
-            n_samples,
-            total_stages,
-            n_servers=n_servers,
-            interpret=impl == "interpret",
-        )
-        return np.asarray(es), np.asarray(ea)
-    strides = mixed_radix_strides(num_stages)
-    k_total = int(np.prod(num_stages, dtype=np.int64))
-    if impl == "xla":
-        tile = min(XLA_TILE, max(K.BLOCK_COMBOS, 1 << (k_total - 1).bit_length()))
-        parts = [
-            _dynamic_enum_xla(
-                jnp.asarray(probs, fdt),
-                jnp.asarray(stage_durs, fdt),
-                jnp.asarray(table, fdt),
-                strides=tuple(int(s) for s in strides),
-                radix=tuple(int(r) for r in num_stages),
-                k_total=k_total,
-                tile=tile,
-                total_stages=total_stages,
-                n_servers=n_servers,
-            )
-            for table in idx_tables
-        ]
-        e_succ = np.array([float(p[0]) for p in parts])
-        e_all = np.array([float(p[1]) for p in parts])
-        return e_succ, e_all
-    es, ea = dynamic_sojourn_enum(
-        jnp.asarray(probs, fdt),
-        jnp.asarray(stage_durs, fdt),
-        jnp.asarray(idx_tables, fdt),
-        jnp.asarray(strides, jnp.int32),
-        jnp.asarray(num_stages, jnp.int32),
-        k_total,
-        total_stages,
-        n_servers=n_servers,
-        interpret=impl == "interpret",
-    )
-    return np.asarray(es), np.asarray(ea)
+        else:
+            key = np.asarray(rng.split_seed(seed), np.int32)
+
+            def per_batch(tables):
+                return [tables, radix, key]
+
+            def call(cdf, durs, tables, rx, k):
+                return dynamic_sojourn_mc(
+                    cdf, durs, tables, rx, k, n_samples, total_stages,
+                    n_servers=n_servers, interpret=interpret,
+                )
+    else:
+        strides = mixed_radix_strides(num_stages)
+        k_total = int(np.prod(num_stages, dtype=np.int64))
+        shared = [probs, stage_durs]
+        if xla:
+            tile = min(XLA_TILE, max(K.BLOCK_COMBOS, 1 << (k_total - 1).bit_length()))
+
+            def per_batch(tables):
+                return [tables[0]]
+
+            def call(pr, durs, table):
+                return _dynamic_enum_xla(
+                    pr, durs, table,
+                    strides=tuple(int(s) for s in strides),
+                    radix=tuple(int(r) for r in num_stages),
+                    k_total=k_total,
+                    tile=tile,
+                    total_stages=total_stages,
+                    n_servers=n_servers,
+                )
+        else:
+
+            def per_batch(tables):
+                return [tables, strides.astype(np.int32), radix]
+
+            def call(pr, durs, tables, st, rx):
+                return dynamic_sojourn_enum(
+                    pr, durs, tables, st, rx, k_total, total_stages,
+                    n_servers=n_servers, interpret=interpret,
+                )
+    batch = 1 if xla else len(idx_tables)
+    return run_batches(phase, idx_tables, batch, fdt, shared, per_batch, call)

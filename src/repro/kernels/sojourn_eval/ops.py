@@ -258,25 +258,65 @@ def sojourn_eval(
 ) -> tuple[np.ndarray, np.ndarray]:
     """(E[sojourn successful], E[sojourn all]) per order; see module doc.
 
-    When :mod:`repro.obs.profiling` is enabled, each call is timed into
-    a ``prof.sojourn_eval.static.<mode>.<impl>.seconds`` span (the
-    numpy conversions inside synchronize the device work, so the span
-    is end-to-end wall clock).
+    When :mod:`repro.obs.profiling` is enabled, each call is timed as a
+    ``prof.sojourn_eval.static.<mode>.<impl>`` span (the numpy
+    conversions inside synchronize the device work, so the span is
+    end-to-end wall clock), tiled by the phase spans
+    ``prof.op_phase.static.<impl>.{prep,put,call,sync}`` of
+    :func:`run_batches`.
     """
     impl = resolve_impl(impl)
     mode = "mc" if samples is not None else (
         "enum" if outcomes is None else "outcomes"
     )
-    with profiling.span(f"sojourn_eval.static.{mode}.{impl}"), precision_scope(impl):
+    with (
+        profiling.span(f"sojourn_eval.static.{mode}.{impl}"),
+        profiling.phases(f"op_phase.static.{impl}", "prep") as phase,
+        precision_scope(impl),
+    ):
         return _sojourn_eval(
             sizes, probs, num_stages, orders,
             outcomes=outcomes, weights=weights, samples=samples, impl=impl,
+            phase=phase,
         )
+
+
+def run_batches(phase, items, batch, fdt, shared, per_batch, call):
+    """Answer ``items`` (orders or index tables) ``batch`` at a time.
+
+    Each batch runs four phases of ``phase`` (:func:`repro.obs.profiling.phases`):
+    ``prep``, the host work of ``per_batch(items_b)``, which returns the
+    batch's host arrays; ``put``, their copies to the device (with the
+    ``shared`` host arrays every batch uses, in the first batch); ``call``,
+    the dispatch of ``call(*shared, *batch arrays)``; ``sync``, the wait
+    for its two answers and their copy back.  Float arrays go to the
+    device as ``fdt``, the others keep their dtype.
+    """
+
+    def put(a):
+        return jnp.asarray(a, fdt) if a.dtype.kind == "f" else jnp.asarray(a)
+
+    shared_j = None
+    e_succ, e_all = [], []
+    for lo in range(0, len(items), batch):
+        phase.to("prep")
+        host = per_batch(items[lo : lo + batch])
+        phase.to("put")
+        if shared_j is None:
+            shared_j = [put(a) for a in shared]
+        args = [put(a) for a in host]
+        phase.to("call")
+        es, ea = call(*shared_j, *args)
+        del args  # free this batch's inputs before the next batch is put
+        phase.to("sync")
+        e_succ.append(np.asarray(es).reshape(-1))
+        e_all.append(np.asarray(ea).reshape(-1))
+    return np.concatenate(e_succ), np.concatenate(e_all)
 
 
 def _sojourn_eval(
     sizes, probs, num_stages, orders, *,
-    outcomes=None, weights=None, samples=None, impl="xla",
+    outcomes=None, weights=None, samples=None, impl="xla", phase,
 ) -> tuple[np.ndarray, np.ndarray]:
     if samples is not None and outcomes is not None:
         raise ValueError("samples= and outcomes= are mutually exclusive")
@@ -288,12 +328,9 @@ def _sojourn_eval(
     if orders.ndim != 2 or orders.shape[1] != n:
         raise ValueError(f"orders must be (P, {n}); got {orders.shape}")
     strides = mixed_radix_strides(num_stages)
+    radix = num_stages.astype(np.int32)
     fdt = compute_dtype(impl)
-    sizes_j = jnp.asarray(sizes, fdt)
-    probs_j = jnp.asarray(probs, fdt)
-
     interpret = impl == "interpret"
-    e_succ_parts, e_all_parts = [], []
     if samples is not None:
         seed, n_samples = int(samples[0]), int(samples[1])
         if n_samples <= 0:
@@ -303,99 +340,68 @@ def _sojourn_eval(
             XLA_TILE, max(K.BLOCK_COMBOS, 1 << (n_samples - 1).bit_length())
         )
         pb = _order_batch(orders.shape[0], tile, n)
-        key2 = jnp.asarray(rng.split_seed(seed), jnp.uint32)
-        for lo in range(0, orders.shape[0], pb):
-            ob = orders[lo : lo + pb]
-            if impl == "xla":
-                es, ea = _mc_xla(
-                    sizes_j,
-                    jnp.asarray(cdf, fdt),
-                    jnp.asarray(num_stages, jnp.int32),
-                    jnp.asarray(ob),
-                    key2,
-                    n_samples=n_samples,
-                    tile=tile,
-                )
-            else:
-                sz_p, cdf_p, rx_p = _permuted(
-                    [sizes, cdf, num_stages.astype(np.int32)], ob
-                )
-                es, ea = K.sojourn_mc(
-                    jnp.asarray(sz_p, fdt),
-                    jnp.asarray(cdf_p, fdt),
-                    jnp.asarray(rx_p),
-                    jnp.asarray(ob),
-                    jnp.asarray(rng.split_seed(seed), jnp.int32),
-                    n_samples,
-                    interpret=interpret,
-                )
-            e_succ_parts.append(np.asarray(es))
-            e_all_parts.append(np.asarray(ea))
+        if impl == "xla":
+            shared = [sizes, cdf, radix, np.asarray(rng.split_seed(seed), np.uint32)]
+
+            def per_batch(ob):
+                return [ob]
+
+            def call(sz, cd, rx, key2, ob):
+                return _mc_xla(sz, cd, rx, ob, key2, n_samples=n_samples, tile=tile)
+        else:
+            shared = []
+            key = np.asarray(rng.split_seed(seed), np.int32)
+
+            def per_batch(ob):
+                return [*_permuted([sizes, cdf, radix], ob), ob, key]
+
+            def call(sz, cd, rx, ob, k):
+                return K.sojourn_mc(sz, cd, rx, ob, k, n_samples, interpret=interpret)
     elif outcomes is None:
         k_total = int(np.prod(num_stages, dtype=np.int64))
         tile = min(XLA_TILE, max(K.BLOCK_COMBOS, 1 << (k_total - 1).bit_length()))
         pb = _order_batch(orders.shape[0], tile, n)
-        for lo in range(0, orders.shape[0], pb):
-            ob = orders[lo : lo + pb]
-            if impl == "xla":
-                es, ea = _enum_xla(
-                    sizes_j,
-                    probs_j,
-                    jnp.asarray(ob),
+        if impl == "xla":
+            shared = [sizes, probs]
+
+            def per_batch(ob):
+                return [ob]
+
+            def call(sz, pr, ob):
+                return _enum_xla(
+                    sz, pr, ob,
                     strides=tuple(int(s) for s in strides),
                     radix=tuple(int(r) for r in num_stages),
                     k_total=k_total,
                     tile=tile,
                 )
-            else:
-                sz_p, pr_p, st_p, rx_p = _permuted(
-                    [sizes, probs, strides.astype(np.int32),
-                     num_stages.astype(np.int32)],
-                    ob,
-                )
-                es, ea = K.sojourn_enum(
-                    jnp.asarray(sz_p, fdt),
-                    jnp.asarray(pr_p, fdt),
-                    jnp.asarray(st_p),
-                    jnp.asarray(rx_p),
-                    k_total,
-                    interpret=interpret,
-                )
-            e_succ_parts.append(np.asarray(es))
-            e_all_parts.append(np.asarray(ea))
+        else:
+            shared = []
+
+            def per_batch(ob):
+                return _permuted([sizes, probs, strides.astype(np.int32), radix], ob)
+
+            def call(sz, pr, st, rx):
+                return K.sojourn_enum(sz, pr, st, rx, k_total, interpret=interpret)
     else:
         if weights is None:
             raise ValueError("explicit outcomes need weights")
         outcomes = np.asarray(outcomes, dtype=np.int32)
-        if impl != "xla":
-            oc_t, wt_t = _tile_outcomes(outcomes, weights)
-            oc_j, wt_j = jnp.asarray(oc_t), jnp.asarray(wt_t, fdt)
-        else:
-            oc_j = jnp.asarray(outcomes)
-            wt_j = jnp.asarray(weights, fdt)
         pb = _order_batch(orders.shape[0], outcomes.shape[0], n)
-        for lo in range(0, orders.shape[0], pb):
-            ob = orders[lo : lo + pb]
-            if impl == "xla":
-                es, ea = _outcomes_xla(
-                    sizes_j,
-                    jnp.asarray(num_stages, jnp.int32),
-                    oc_j,
-                    wt_j,
-                    jnp.asarray(ob),
-                )
-            else:
-                sz_p, rx_p = _permuted(
-                    [sizes, num_stages.astype(np.int32)], ob
-                )
-                es, ea = K.sojourn_outcomes(
-                    jnp.asarray(sz_p, fdt),
-                    jnp.asarray(rx_p),
-                    jnp.asarray(ob),
-                    oc_j,
-                    wt_j,
-                    interpret=interpret,
-                )
-            e_succ_parts.append(np.asarray(es))
-            e_all_parts.append(np.asarray(ea))
-    return np.concatenate(e_succ_parts), np.concatenate(e_all_parts)
+        if impl == "xla":
+            shared = [sizes, radix, outcomes, np.asarray(weights)]
+
+            def per_batch(ob):
+                return [ob]
+
+            def call(sz, rx, oc, wt, ob):
+                return _outcomes_xla(sz, rx, oc, wt, ob)
+        else:
+            shared = list(_tile_outcomes(outcomes, weights))
+
+            def per_batch(ob):
+                return [*_permuted([sizes, radix], ob), ob]
+
+            def call(oc, wt, sz, rx, ob):
+                return K.sojourn_outcomes(sz, rx, ob, oc, wt, interpret=interpret)
+    return run_batches(phase, orders, pb, fdt, shared, per_batch, call)

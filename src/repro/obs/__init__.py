@@ -9,9 +9,10 @@ Three pieces, wired through every scheduling layer:
 * :class:`MetricsRegistry` (:mod:`repro.obs.metrics`) — counters /
   gauges / histograms with a JSON snapshot, replacing ad-hoc result
   dicts; both frontends populate it via ``metrics=``.
-* :mod:`repro.obs.profiling` — opt-in wall-clock spans around the fused
-  ``sojourn_eval`` ops and the workload-cache tiers, surfaced in the
-  same registry snapshot.
+* :mod:`repro.obs.profiling` — opt-in wall-clock spans around the
+  evaluator, the fused ``sojourn_eval`` ops and their phases, and the
+  workload-cache tiers, surfaced in the same registry snapshot and, as
+  profiler annotations, on the device trace's clock.
 
 ``python -m repro.obs.report`` replays a synthetic Philly-trace
 workload and writes the trace + metrics artifacts.

@@ -4,7 +4,7 @@ One :class:`MetricsRegistry` replaces the ad-hoc result dicts the
 frontends used to hand-roll: the DES (:func:`repro.core.simulator.simulate`)
 and the cluster manager (:meth:`repro.cluster.manager.ClusterManager.run`)
 populate a registry passed by the caller, the profiling hooks
-(:mod:`repro.obs.profiling`) and the workload-cache latency probes feed
+(:mod:`repro.obs.profiling`), among them the workload cache's, feed
 the process-wide default registry, and ``python -m repro.obs.report``
 dumps everything as one JSON artifact (metrics catalog in
 ``docs/observability.md``).
@@ -113,7 +113,7 @@ class Histogram:
 class MetricsRegistry:
     """Name-keyed counters/gauges/histograms with get-or-create access.
 
-    Names are dotted strings (``sojourn.successful``, ``cache.mem_hit``,
+    Names are dotted strings (``sojourn.successful``, ``jobs.restarts``,
     ``prof.sojourn_eval.static.enum.xla.seconds``); a name is bound to
     the first type that claims it and re-registering as another type
     raises.
@@ -215,7 +215,7 @@ _DEFAULT = MetricsRegistry()
 
 
 def get_registry() -> MetricsRegistry:
-    """The process-wide default registry (profiling spans, cache probes)."""
+    """The process-wide default registry (profiling spans)."""
     return _DEFAULT
 
 

@@ -1,37 +1,48 @@
-"""Opt-in wall-clock profiling spans for kernels and cache tiers.
+"""Opt-in wall-clock profiling spans on the profiler trace's clock.
 
-Disabled by default: every probe is guarded by one module-level bool,
-so the instrumented hot paths (the fused ``sojourn_eval`` ops, the
-workload-cache tiers in :mod:`repro.core.policies`) pay a single
-attribute check when profiling is off.  Enable with
-:func:`enable` or the ``REPRO_PROFILE=1`` environment variable.
+Disabled by default: :func:`span` and :func:`phases` check one
+module-level bool and return a shared no-op object, so the instrumented
+hot paths (the evaluator, the fused ``sojourn_eval`` ops, the
+workload-cache tiers in :mod:`repro.core.policies`) pay well under a
+microsecond per span when profiling is off.  Enable with :func:`enable`
+or the ``REPRO_PROFILE=1`` environment variable.
 
-Spans record into the process-wide default
-:class:`~repro.obs.metrics.MetricsRegistry` as
-``prof.<name>.seconds`` histograms plus ``prof.<name>.calls``
-counters, so ``python -m repro.obs.report`` (and anything else that
-snapshots the registry) surfaces kernel latency next to scheduler
-metrics and cache hit/miss/eviction latency in one place.
+An enabled span records into the process-wide default
+:class:`~repro.obs.metrics.MetricsRegistry` (or the one passed in):
 
-For JAX results use :func:`block` inside a span to charge async
-dispatch to the span that launched it (``jax.block_until_ready``); the
-``sojourn_eval`` ops convert to numpy inside their spans, which blocks
-implicitly.
+* ``prof.<name>.seconds`` — inclusive wall time, one observation per
+  call (histogram);
+* ``prof.<name>.calls`` — the call count (counter);
+* ``prof.<name>.self_s`` — the inclusive time minus that of the spans
+  opened directly inside it on the same thread (histogram).  Nested
+  spans (a cache lookup whose computation looks up other cached
+  values) double-count in the inclusive sums; the self times do not.
+
+It also opens ``jax.profiler.TraceAnnotation("prof.<name>")``, so under
+a running profiler trace the span lies on the host plane's ``python``
+line, on the same clock as the device's ``XLA Ops``.  Spans are timed
+inside their annotation: the annotation covers the recorded time.
+
+The ``sojourn_eval`` ops convert their answers to NumPy inside their
+spans, which waits for the device, so an op span is end-to-end wall
+time of the call.
 """
 
 from __future__ import annotations
 
 import os
+import threading
 import time
-from contextlib import contextmanager
 
 from repro.obs import metrics
 
-__all__ = ["enabled", "enable", "span", "block", "tick", "tock"]
+__all__ = ["enabled", "enable", "span", "phases"]
 
 _ENABLED = os.environ.get("REPRO_PROFILE", "").strip().lower() not in (
     "", "0", "false", "off",
 )
+_local = threading.local()
+_annotation = None  # jax.profiler.TraceAnnotation, imported on first use
 
 
 def enabled() -> bool:
@@ -44,46 +55,106 @@ def enable(on: bool = True) -> None:
     _ENABLED = bool(on)
 
 
-@contextmanager
+class _Off:
+    """The shared no-op span and phase sequence of disabled profiling."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return None
+
+    def to(self, phase: str) -> None:
+        pass
+
+
+_OFF = _Off()
+
+
+def _open_spans() -> list:
+    stack = getattr(_local, "stack", None)
+    if stack is None:
+        stack = _local.stack = []
+    return stack
+
+
+class _Span:
+    __slots__ = ("name", "registry", "annotation", "child", "t0")
+
+    def __init__(self, name: str, registry: metrics.MetricsRegistry | None):
+        global _annotation
+        if _annotation is None:
+            from jax.profiler import TraceAnnotation
+
+            _annotation = TraceAnnotation
+        self.name = name
+        self.registry = registry or metrics.get_registry()
+        self.annotation = _annotation(f"prof.{name}")
+        self.child = 0.0
+
+    def __enter__(self):
+        self.annotation.__enter__()
+        _open_spans().append(self)
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        seconds = time.perf_counter() - self.t0
+        stack = _open_spans()
+        stack.pop()
+        if stack:
+            stack[-1].child += seconds
+        self.annotation.__exit__(*exc)
+        reg, name = self.registry, self.name
+        reg.histogram(f"prof.{name}.seconds").observe(seconds)
+        reg.histogram(f"prof.{name}.self_s").observe(seconds - self.child)
+        reg.counter(f"prof.{name}.calls").inc()
+        return None
+
+
 def span(name: str, registry: metrics.MetricsRegistry | None = None):
-    """Time a block into ``prof.<name>.seconds`` when profiling is on."""
+    """Time a block as ``prof.<name>`` when profiling is on (see module doc)."""
     if not _ENABLED:
-        yield
-        return
-    reg = registry or metrics.get_registry()
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        reg.histogram(f"prof.{name}.seconds").observe(time.perf_counter() - t0)
-        reg.counter(f"prof.{name}.calls").inc()
+        return _OFF
+    return _Span(name, registry)
 
 
-def block(x):
-    """``jax.block_until_ready`` under profiling; identity otherwise.
+class _Phases:
+    __slots__ = ("prefix", "first", "phase", "open")
 
-    Wrap a span's result so device-async work is charged to the span
-    that launched it instead of the first later host sync.
+    def __init__(self, prefix, first):
+        self.prefix, self.first = prefix, first
+        self.phase = self.open = None
+
+    def __enter__(self):
+        self.to(self.first)
+        return self
+
+    def __exit__(self, *exc):
+        if self.open is not None:
+            self.open.__exit__(*exc)
+            self.phase = self.open = None
+        return None
+
+    def to(self, phase: str) -> None:
+        """Close the open phase and open ``phase``; nothing when it is open."""
+        if phase == self.phase:
+            return
+        if self.open is not None:
+            self.open.__exit__(None, None, None)
+        self.phase = phase
+        self.open = _Span(f"{self.prefix}.{phase}", None).__enter__()
+
+
+def phases(prefix: str, first: str):
+    """Consecutive spans ``<prefix>.<phase>`` that tile a block.
+
+    The block starts in phase ``first``; ``.to(phase)`` ends the open
+    phase and starts the next, so the phases leave no time of the block
+    uncovered.  A no-op object when profiling is off.
     """
-    if _ENABLED:
-        import jax
-
-        jax.block_until_ready(x)
-    return x
-
-
-def tick() -> float:
-    """Start time for a hand-rolled probe; 0.0 when profiling is off.
-
-    ``tick``/``tock`` avoid context-manager overhead on paths probed
-    per cache access.
-    """
-    return time.perf_counter() if _ENABLED else 0.0
-
-
-def tock(name: str, t0: float) -> None:
-    """Close a :func:`tick` probe into ``prof.<name>.seconds``."""
-    if _ENABLED and t0:
-        reg = metrics.get_registry()
-        reg.histogram(f"prof.{name}.seconds").observe(time.perf_counter() - t0)
-        reg.counter(f"prof.{name}.calls").inc()
+    if not _ENABLED:
+        return _OFF
+    return _Phases(prefix, first)

@@ -299,13 +299,19 @@ def test_profiling_spans_gate_on_enable():
         snap = reg.snapshot()
         assert snap["histograms"]["prof.on.case.seconds"]["count"] == 1
         assert snap["counters"]["prof.on.case.calls"] == 1
-        t0 = profiling.tick()
-        assert t0 > 0.0
-        profiling.tock("probe.case", t0)
+        with profiling.span("outer.case"):
+            with profiling.span("probe.case"):
+                pass
         d = get_registry().snapshot()
         assert d["counters"]["prof.probe.case.calls"] >= 1
+        h = d["histograms"]
+        outer = h["prof.outer.case.seconds"]["max"]
+        assert 0.0 < h["prof.probe.case.seconds"]["max"] <= outer
+        assert h["prof.outer.case.self_s"]["max"] < outer
         profiling.enable(False)
-        assert profiling.tick() == 0.0
+        with profiling.span("after.case"):
+            pass
+        assert "prof.after.case.calls" not in get_registry().snapshot()["counters"]
     finally:
         profiling.enable(was)
 
