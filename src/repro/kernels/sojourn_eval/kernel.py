@@ -5,17 +5,24 @@ non-preemptive order by enumerating every per-job outcome combination.
 The seed implementation materialized the full ``(K, N)`` outcome matrix
 in host NumPy (capping K at 2**21); these kernels never materialize it:
 
-* ``sojourn_enum`` — each grid tile owns ``BLOCK_COMBOS`` *combination
-  indices* and decodes them on the fly with the mixed-radix rule
-  ``stage_i(k) = (k // stride_i) % M_i`` (job 0 is the most-significant
-  digit, matching :func:`repro.core.evaluator.enumerate_outcomes`).
-  Realized durations / termination probabilities are gathered from the
-  padded ``(N, M)`` tables by a one-hot select over the (small) stage
-  axis — TPU-friendly: no vector gather, only ``(SUBLANES, LANES)``
-  selects.  The per-order completion-time prefix sum runs in the same
-  position loop, and the probability-weighted successful-job sojourn
-  accumulates, Kahan-compensated, into VMEM scratch tiles that persist
-  across the (sequential, innermost) combination-tile grid dimension.
+* ``sojourn_enum`` — grid ``(order block, combination tile)``.  Each
+  tile owns ``BLOCK_COMBOS`` *combination indices* and decodes them on
+  the fly with the mixed-radix rule ``stage_j(k) = (k // stride_j) %
+  M_j`` (job 0 is the most-significant digit, matching
+  :func:`repro.core.evaluator.enumerate_outcomes`), with one integer
+  division a job, once per job in original job indexing: the realized
+  duration and success mask go to
+  VMEM scratch, and the combination weight (Eq. 8) and success count
+  stay in registers.  Durations / probabilities are gathered from the
+  ``(N, M)`` tables by a one-hot select over the (small) stage axis —
+  TPU-friendly: no vector gather, only ``(SUBLANES, LANES)`` selects.
+  A loop over the block's orders then reads each position's job id
+  from SMEM and the scratch by it, so only the per-order completion-time
+  prefix sum and the two weighted sums remain per order.  Inputs: the
+  job group's tables once, unpermuted (a float ``(2, N, M)`` array and
+  an int32 ``(2, N)`` one), and the orders' flat job ids.  When the
+  combinations span tiles, each order's sum is Kahan-compensated in
+  VMEM scratch across the (sequential, innermost) tile axis.
 
 * ``sojourn_outcomes`` — the same fused gather + prefix sum + weighted
   reduction for an *explicit* outcome matrix (Monte-Carlo samples or a
@@ -33,10 +40,11 @@ in host NumPy (capping K at 2**21); these kernels never materialize it:
   policies) evaluated under one seed sees the identical outcome stream
   (common random numbers).
 
-Both kernels take per-*order* inputs (grid dim 0) whose job axis is
-pre-permuted by the caller (``ops.py``), so position ``pos`` in the
-kernel loop *is* service position: the running sum ``t`` after ``pos``
-steps is the completion time of the job served ``pos``-th.
+``sojourn_outcomes`` and ``sojourn_mc`` take per-*order* inputs (grid
+dim 0) whose job axis is pre-permuted by the caller (``ops.py``).  In
+every kernel, step ``pos`` of the position loop *is* service position:
+the running sum ``t`` after ``pos`` steps is the completion time of the
+job served ``pos``-th.
 
 Accumulation happens in the input dtype.  ``ops.sojourn_eval`` passes
 float32 when the kernels are compiled for a TPU (Mosaic has no 64-bit
@@ -58,6 +66,7 @@ from repro.kernels.sojourn_eval import rng
 
 __all__ = [
     "sojourn_enum",
+    "enum_order_block",
     "sojourn_outcomes",
     "sojourn_mc",
     "BLOCK_COMBOS",
@@ -84,6 +93,14 @@ def reduction_scratch(dtype) -> list:
     return [pltpu.VMEM((SUBLANES, LANES), dtype) for _ in range(4)]
 
 
+def _kahan_add(acc, comp, idx, x):
+    """Kahan-add tile ``x`` into ``acc[idx]`` with compensation ``comp[idx]``."""
+    y = x - comp[idx]
+    t = acc[idx] + y
+    comp[idx] = (t - acc[idx]) - y
+    acc[idx] = t
+
+
 def accumulate(kt, nkt, outs, scratch, terms):
     """Add one tile's per-lane terms into the scratch; flush on the last tile.
 
@@ -100,11 +117,7 @@ def accumulate(kt, nkt, outs, scratch, terms):
             ref[...] = jnp.zeros_like(ref)
 
     for i, x in enumerate(terms):
-        acc, comp = scratch[2 * i], scratch[2 * i + 1]
-        y = x - comp[...]
-        t = acc[...] + y
-        comp[...] = (t - acc[...]) - y
-        acc[...] = t
+        _kahan_add(scratch[2 * i], scratch[2 * i + 1], ..., x)
 
     @pl.when(kt == nkt - 1)
     def _flush():
@@ -137,82 +150,171 @@ def scalar_outputs(rows: int, dtype):
 # ---------------------------------------------------------------------------
 
 
+#: Most orders a grid step scores against one decoded combination tile.
+#: When one tile holds every combination (``K <= BLOCK_COMBOS``: OPTIMAL's
+#: batches of up to 4,096 orders at N <= 10, M = 2) an order's answers
+#: are final after its one tile.  When the combinations span tiles, each
+#: order of the block keeps its four Kahan tiles in VMEM across them,
+#: 16 KiB an order in float32.
+ORDER_BLOCK = 512
+ORDER_BLOCK_TILED = 32
+#: Orders scored in one trip of the order loop when one tile holds K.  With
+#: Kahan sums across tiles a trip scores one order: a trip that ran past
+#: the last order would add that order's terms twice.
+ORDER_UNROLL = 4
+
+
+def enum_order_block(p_orders: int, k_total: int) -> tuple[int, int]:
+    """``(block, unroll)`` of :func:`sojourn_enum` for ``p_orders`` orders
+    over ``k_total`` combinations: orders a grid step, and orders a trip of
+    its order loop.  The fewest blocks the cap allows, split evenly, each
+    whole trips."""
+    if k_total <= BLOCK_COMBOS:
+        unroll, cap = min(ORDER_UNROLL, p_orders), ORDER_BLOCK
+    else:
+        unroll, cap = 1, ORDER_BLOCK_TILED
+    even = pl.cdiv(p_orders, pl.cdiv(p_orders, cap))
+    return pl.cdiv(even, unroll) * unroll, unroll
+
+
 def _enum_kernel(
-    strides_ref,  # (1, 1, N) int32 SMEM, per-order permuted mixed-radix strides
-    radix_ref,  # (1, 1, N) int32 SMEM, per-order permuted stage counts M_i
-    sizes_ref,  # (1, N, M) VMEM, per-order permuted cumulative sizes
-    probs_ref,  # (1, N, M) VMEM, per-order permuted stop probabilities
-    succ_ref,  # (1, 1, 1) SMEM out: E[sojourn | successful jobs]
-    all_ref,  # (1, 1, 1) SMEM out: E[sojourn | all jobs]
-    *scratch,  # reduction_scratch
+    orders_ref,  # (P * N,) int32 SMEM, scalar prefetch: job ids, order-major
+    tables_ref,  # (2, N, M) VMEM: cumulative sizes, stop probabilities
+    ints_ref,  # (2, N) int32 SMEM: mixed-radix strides, stage counts M_j
+    succ_ref,  # (P,) SMEM out: E[sojourn | successful jobs] per order
+    all_ref,  # (P,) SMEM out: E[sojourn | all jobs] per order
+    dur_ref,  # (N, SUBLANES, LANES) VMEM scratch: realized durations
+    won_ref,  # (N, SUBLANES, LANES) VMEM scratch: 1 where the job succeeds
+    *acc,  # nkt > 1: four (B, SUBLANES, LANES) Kahan tiles, as accumulate's
     n: int,
     m: int,
+    block: int,
+    unroll: int,
+    p_orders: int,
     k_total: int,
     nkt: int,
 ):
-    kt = pl.program_id(1)
-    dtype = sizes_ref.dtype
+    b, kt = pl.program_id(0), pl.program_id(1)
+    dtype = tables_ref.dtype
     k = _tile_combo_ids(kt)
-    # Eq. (8): combination probability = prod_i p_{i, stage_i(k)}; the tail
-    # tile is masked by zeroing its weight (k >= K contributes nothing).
+    # Decode the tile once per job, in original job indexing.  Eq. (8):
+    # combination probability = prod_j p_{j, stage_j(k)}; the tail tile is
+    # masked by zeroing its weight (k >= K contributes nothing).
     w = (k < k_total).astype(dtype)
-    t = jnp.zeros((SUBLANES, LANES), dtype)  # completion time at position pos
-    tsum = jnp.zeros((SUBLANES, LANES), dtype)  # sum of completion times
-    tot = jnp.zeros((SUBLANES, LANES), dtype)  # sum over successful jobs
     cnt = jnp.zeros((SUBLANES, LANES), jnp.int32)  # successes l(k)
-    for pos in range(n):
-        stride = strides_ref[0, 0, pos]
-        radix = radix_ref[0, 0, pos]
-        s = (k // stride) % radix  # on-the-fly mixed-radix decode
+    for j in range(n):
+        # On-the-fly mixed-radix decode, one vector integer division a job
+        # (the VPU divides integers in software), independent across jobs:
+        # q_j = k // stride_j, and stage_j(k) = q_j % M_j = q_j - q_{j-1} M_j
+        # since q_{j-1} = q_j // M_j.  For k < K, q_0 < M_0 is the stage.
+        quot = k // ints_ref[0, j]
+        s = quot if j == 0 else quot - prev * ints_ref[1, j]
+        prev = quot
         d = jnp.zeros((SUBLANES, LANES), dtype)
         p = jnp.zeros((SUBLANES, LANES), dtype)
-        for j in range(m):  # one-hot gather over the (small) stage axis
-            hit = s == j
-            d = jnp.where(hit, sizes_ref[0, pos, j], d)
-            p = jnp.where(hit, probs_ref[0, pos, j], p)
+        for i in range(m):  # one-hot gather over the (small) stage axis
+            hit = s == i
+            d = jnp.where(hit, tables_ref[0, j, i], d)
+            p = jnp.where(hit, tables_ref[1, j, i], p)
         w = w * p
-        t = t + d
-        succ = s == radix - 1
-        tot = jnp.where(succ, tot + t, tot)
+        succ = s == ints_ref[1, j] - 1
         cnt = cnt + succ.astype(jnp.int32)
-        tsum = tsum + t
-    # Eq. (7): mean sojourn of the l(k) successful jobs (0 when l = 0);
-    # Eq. (9): the probability-weighted sum, tiled into the scratch.
-    mean = jnp.where(cnt > 0, tot / jnp.maximum(cnt, 1).astype(dtype), 0.0)
-    accumulate(kt, nkt, (succ_ref, all_ref), scratch, (w * mean, w * (tsum / n)))
+        dur_ref[j] = d
+        won_ref[j] = succ.astype(dtype)
+    if nkt > 1:
+
+        @pl.when(kt == 0)
+        def _init():
+            for ref in acc:
+                ref[...] = jnp.zeros_like(ref)
+
+    has_won = cnt > 0
+    n_won = jnp.maximum(cnt, 1).astype(dtype)
+
+    def score(o):
+        # Only the prefix sums depend on the order: position pos serves
+        # job orders[q, pos], and t after pos steps is its completion time.
+        q = jnp.minimum(b * block + o, p_orders - 1)
+        t = jnp.zeros((SUBLANES, LANES), dtype)
+        tsum = jnp.zeros((SUBLANES, LANES), dtype)  # sum of completion times
+        tot = jnp.zeros((SUBLANES, LANES), dtype)  # sum over successful jobs
+        for pos in range(n):
+            job = orders_ref[q * n + pos]
+            t = t + dur_ref[job]
+            tot = tot + won_ref[job] * t  # adds t or exactly 0
+            tsum = tsum + t
+        # Eq. (7): mean sojourn of the l(k) successful jobs (0 when l = 0);
+        # Eq. (9): the probability-weighted sum over the tile.
+        mean = jnp.where(has_won, tot / n_won, 0.0)
+        terms = (w * mean, w * (tsum / n))
+        outs = (succ_ref, all_ref)
+        if nkt == 1:
+            for out, x in zip(outs, terms):
+                out[q] = jnp.sum(x)
+            return
+        for i, x in enumerate(terms):
+            _kahan_add(acc[2 * i], acc[2 * i + 1], o, x)
+
+        @pl.when(kt == nkt - 1)
+        def _flush():
+            for i, out in enumerate(outs):
+                out[q] = jnp.sum(acc[2 * i][o] - acc[2 * i + 1][o])
+
+    # ``unroll`` orders a trip give the scheduler independent work.  The
+    # tail block scores only the orders that exist; a trip that runs past
+    # the last order scores it again and writes the same answers.
+    def trip(i, carry):
+        for u in range(unroll):
+            score(i * unroll + u)
+        return carry
+
+    n_valid = jnp.minimum(block, p_orders - b * block)
+    jax.lax.fori_loop(0, (n_valid + unroll - 1) // unroll, trip, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("k_total", "interpret"))
 def sojourn_enum(
-    sizes_p: jax.Array,  # (P, N, M) per-order permuted cumulative sizes
-    probs_p: jax.Array,  # (P, N, M) per-order permuted probabilities
-    strides_p: jax.Array,  # (P, N) int32 permuted mixed-radix strides
-    radix_p: jax.Array,  # (P, N) int32 permuted stage counts
+    tables: jax.Array,  # (2, N, M) cumulative sizes and stop probabilities
+    ints: jax.Array,  # (2, N) int32 mixed-radix strides and stage counts
+    orders: jax.Array,  # (P * N,) int32 original job ids, order-major
     k_total: int,
     *,
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
-    """Exact (E[sojourn successful], E[sojourn all]) per order, fused."""
-    p_orders, n, m = sizes_p.shape
+    """Exact (E[sojourn successful], E[sojourn all]) per order, fused.
+
+    Grid ``(order block, combination tile)``: each step decodes its tile
+    once for the job group and scores a block of
+    :func:`enum_order_block` orders against that decode.  The orders come
+    flat (a 2-D SMEM array is padded to (8, 128)-word tiles), whole, as
+    scalar prefetch; the answers leave as two whole ``(P,)`` SMEM arrays.
+    """
+    _, n, m = tables.shape
+    p_orders = orders.shape[0] // n
     nkt = max(1, pl.cdiv(k_total, BLOCK_COMBOS))
-    dtype = sizes_p.dtype
-    kernel = functools.partial(_enum_kernel, n=n, m=m, k_total=k_total, nkt=nkt)
-    out_specs, out_shape = scalar_outputs(p_orders, dtype)
-    out_succ, out_all = pl.pallas_call(
+    block, unroll = enum_order_block(p_orders, k_total)
+    dtype = tables.dtype
+    kernel = functools.partial(
+        _enum_kernel, n=n, m=m, block=block, unroll=unroll, p_orders=p_orders,
+        k_total=k_total, nkt=nkt,
+    )
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    scratch = [pltpu.VMEM((n, SUBLANES, LANES), dtype) for _ in range(2)]
+    if nkt > 1:
+        scratch += [pltpu.VMEM((block, SUBLANES, LANES), dtype) for _ in range(4)]
+    out_shape = jax.ShapeDtypeStruct((p_orders,), dtype)
+    return pl.pallas_call(
         kernel,
-        grid=(p_orders, nkt),
-        in_specs=[
-            per_row_smem(n),
-            per_row_smem(n),
-            pl.BlockSpec((1, n, m), lambda p, kt: (p, 0, 0)),
-            pl.BlockSpec((1, n, m), lambda p, kt: (p, 0, 0)),
-        ],
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=reduction_scratch(dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(pl.cdiv(p_orders, block), nkt),
+            in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM), smem],
+            out_specs=[smem, smem],
+            scratch_shapes=scratch,
+        ),
+        out_shape=[out_shape, out_shape],
         interpret=interpret,
-    )(strides_p[:, None], radix_p[:, None], sizes_p, probs_p)
-    return out_succ[:, 0, 0], out_all[:, 0, 0]
+    )(orders, tables, ints)
 
 
 # ---------------------------------------------------------------------------

@@ -376,13 +376,22 @@ def _sojourn_eval(
                     tile=tile,
                 )
         else:
-            shared = []
+            # The job group's tables once a call, in the dtype the kernel
+            # reads; each batch puts only its order ids.
+            shared = [
+                np.stack([sizes, probs]).astype(fdt),
+                np.stack([strides, radix]).astype(np.int32),
+            ]
 
             def per_batch(ob):
-                return _permuted([sizes, probs, strides.astype(np.int32), radix], ob)
+                return [ob.reshape(-1)]
 
-            def call(sz, pr, st, rx):
-                return K.sojourn_enum(sz, pr, st, rx, k_total, interpret=interpret)
+            def call(tables, ints, ob):
+                p_b = ob.shape[0] // n
+                profiling.count("sojourn_enum.orders", p_b)
+                block, _ = K.enum_order_block(p_b, k_total)
+                profiling.count("sojourn_enum.order_blocks", -(-p_b // block))
+                return K.sojourn_enum(tables, ints, ob, k_total, interpret=interpret)
     else:
         if weights is None:
             raise ValueError("explicit outcomes need weights")

@@ -1,11 +1,12 @@
 """Opt-in wall-clock profiling spans on the profiler trace's clock.
 
 Disabled by default: :func:`span` and :func:`phases` check one
-module-level bool and return a shared no-op object, so the instrumented
-hot paths (the evaluator, the fused ``sojourn_eval`` ops, the
-workload-cache tiers in :mod:`repro.core.policies`) pay well under a
-microsecond per span when profiling is off.  Enable with :func:`enable`
-or the ``REPRO_PROFILE=1`` environment variable.
+module-level bool and return a shared no-op object, and :func:`count`
+does nothing, so the instrumented hot paths (the evaluator, the fused
+``sojourn_eval`` ops, the workload-cache tiers in
+:mod:`repro.core.policies`) pay well under a microsecond per span when
+profiling is off.  Enable with :func:`enable` or the ``REPRO_PROFILE=1``
+environment variable.
 
 An enabled span records into the process-wide default
 :class:`~repro.obs.metrics.MetricsRegistry` (or the one passed in):
@@ -18,9 +19,11 @@ An enabled span records into the process-wide default
   spans (a cache lookup whose computation looks up other cached
   values) double-count in the inclusive sums; the self times do not.
 
-It also opens ``jax.profiler.TraceAnnotation("prof.<name>")``, so under
-a running profiler trace the span lies on the host plane's ``python``
-line, on the same clock as the device's ``XLA Ops``.  Spans are timed
+:func:`count` adds to the counter ``prof.<name>`` when profiling is on.
+
+An enabled span also opens ``jax.profiler.TraceAnnotation("prof.<name>")``,
+so under a running profiler trace the span lies on the host plane's
+``python`` line, on the same clock as the device's ``XLA Ops``.  Spans are timed
 inside their annotation: the annotation covers the recorded time.
 
 The ``sojourn_eval`` ops convert their answers to NumPy inside their
@@ -36,7 +39,7 @@ import time
 
 from repro.obs import metrics
 
-__all__ = ["enabled", "enable", "span", "phases"]
+__all__ = ["enabled", "enable", "count", "span", "phases"]
 
 _ENABLED = os.environ.get("REPRO_PROFILE", "").strip().lower() not in (
     "", "0", "false", "off",
@@ -112,6 +115,12 @@ class _Span:
         reg.histogram(f"prof.{name}.self_s").observe(seconds - self.child)
         reg.counter(f"prof.{name}.calls").inc()
         return None
+
+
+def count(name: str, n: int = 1, registry: metrics.MetricsRegistry | None = None) -> None:
+    """Add ``n`` to the counter ``prof.<name>`` when profiling is on."""
+    if _ENABLED:
+        (registry or metrics.get_registry()).counter(f"prof.{name}").inc(n)
 
 
 def span(name: str, registry: metrics.MetricsRegistry | None = None):

@@ -122,6 +122,44 @@ def test_op_phases_tile_each_batch(profiled, monkeypatch, kind, mode, impl):
     assert op_spans == [f"prof.sojourn_eval.{kind}.{mode}.{impl}.seconds"]
 
 
+# (jobs, orders, orders a batch or None for the op's own, order blocks):
+# 24 orders in batches of 7 at K = 16 are four one-block calls; 33 orders
+# at K = 2048 (two combination tiles) are one call of two blocks.
+ENUM_COUNTS = [(4, 24, 7, 4), (11, 33, None, 2)]
+
+
+@pytest.mark.parametrize("n,p,batch,blocks", ENUM_COUNTS)
+def test_enum_counts_orders_and_order_blocks(profiled, monkeypatch, n, p, batch, blocks):
+    sizes, probs, num_stages = policies.padded_arrays(_jobs(n=n))
+    rng = np.random.default_rng(n)
+    orders = np.array([rng.permutation(n) for _ in range(p)], np.int32)
+    if batch is not None:
+        monkeypatch.setattr(ops, "_order_batch", lambda *_: batch)
+    names = ("prof.sojourn_enum.orders", "prof.sojourn_enum.order_blocks")
+    profiling.enable(False)
+    sojourn_eval(sizes, probs, num_stages, orders, impl="interpret")
+    assert not set(names) & set(profiled.snapshot()["counters"])
+    profiling.enable(True)
+    sojourn_eval(sizes, probs, num_stages, orders, impl="interpret")
+    counters = profiled.snapshot()["counters"]
+    assert [counters[k] for k in names] == [p, blocks]
+
+
+def test_count_adds_only_when_enabled():
+    was = profiling.enabled()
+    reg = MetricsRegistry()
+    try:
+        profiling.enable(False)
+        profiling.count("a.case", 5, registry=reg)
+        assert reg.snapshot()["counters"] == {}
+        profiling.enable(True)
+        profiling.count("a.case", 5, registry=reg)
+        profiling.count("a.case", registry=reg)
+    finally:
+        profiling.enable(was)
+    assert reg.snapshot()["counters"] == {"prof.a.case": 6}
+
+
 def test_nested_cache_spans_count_each_moment_once(profiled, monkeypatch, tmp_path):
     monkeypatch.setenv("REPRO_CACHE_DIR", str(tmp_path))
     jobs = _jobs(seed=91)

@@ -212,6 +212,50 @@ def test_enum_parity_zero_probability_row(impl):
     assert _relerr(ea, r_ea) < RTOL
 
 
+def _random_orders(n, p, seed):
+    rng = np.random.default_rng(seed)
+    return np.array([rng.permutation(n) for _ in range(p)], dtype=np.int32)
+
+
+def _ragged_jobs(seed):
+    # Stage counts 2, 4, 1, 3, 5, 3, 2, 4: K = 2880, three combination
+    # tiles (the last partial), stages padded to M = 5.
+    rng = np.random.default_rng(seed)
+    return [generate_workload(rng, 1, num_stages=m)[0] for m in (2, 4, 1, 3, 5, 3, 2, 4)]
+
+
+# The static kernel scores a block of orders per grid step against one
+# decoded combination tile (kernel.enum_order_block).  (jobs, orders) per case.
+ORDER_BLOCK_CASES = {
+    # One tile (K = 128); 1,001 orders in blocks of 504 and 497, so the
+    # tail block ends inside a trip of the order loop.
+    "one_tile_tail_block": lambda: (generate_workload(np.random.default_rng(41), 7),
+                                    _random_orders(7, 1001, 41)),
+    "one_tile_one_order": lambda: (generate_workload(np.random.default_rng(43), 8),
+                                   _random_orders(8, 1, 43)),
+    # K = 2048 spans two tiles: per-order Kahan sums across them.
+    "tiles_one_order": lambda: (generate_workload(np.random.default_rng(47), 11),
+                                _random_orders(11, 1, 47)),
+    "tiles_tail_block": lambda: (generate_workload(np.random.default_rng(53), 11),
+                                 _random_orders(11, 70, 53)),
+    "tiles_ragged_stages": lambda: (_ragged_jobs(59), _random_orders(8, 40, 59)),
+    "all_orders_n5": lambda: (generate_workload(np.random.default_rng(61), 5, num_stages=3),
+                              np.array(list(itertools.permutations(range(5))), np.int32)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(ORDER_BLOCK_CASES))
+def test_enum_order_blocks_parity(case):
+    jobs, orders = ORDER_BLOCK_CASES[case]()
+    es, ea = sojourn_eval_x64(jobs, orders, impl="interpret")
+    r_es, r_ea = _ref(jobs, orders)
+    assert _relerr(es, r_es) < RTOL
+    assert _relerr(ea, r_ea) < RTOL
+    x_es, x_ea = sojourn_eval_x64(jobs, orders, impl="xla")
+    assert _relerr(es, x_es) < RTOL
+    assert _relerr(ea, x_ea) < RTOL
+
+
 # ---------------------------------------------------------------------------
 # Fused op vs the seed materialized path
 # ---------------------------------------------------------------------------
