@@ -17,6 +17,9 @@ by ``generate_workload`` from fixed seeds:
 3. ``streamed_mc``: N = 27 (K = 2^27), so ``evaluate_many`` takes its
    shared-seed Monte Carlo branch with 2^20 samples; RANK is also scored
    over the same samples passed as an explicit outcome table.
+4. ``stage_sweep``: Table XIV at its largest stage count, N = 5 jobs of
+   M = 8 stages from workload set 1 (K = 8^5 = 32,768 over 32
+   combination tiles): OPTIMAL's 120 orders in one static call, and RANK.
 
 The chip computes in float32.  Each phase checks every answer against a
 float64 reference: the dense oracles of ``ref.py`` where their tables
@@ -54,6 +57,8 @@ MC_SAMPLES = 1 << 20
 NUMERICAL_ALGS = ("optimal", "rank", "serpt", "sr", "random")
 MC_ALGS = ("rank", "serpt", "sr", "random")
 DYNAMIC_CASES = (("sr", 1), ("sr", 3), ("rank", 3))  # (index policy, servers)
+N_STAGE_SWEEP, M_STAGE_SWEEP = 5, 8  # Table XIV's largest point
+STAGE_SWEEP_GROUPS = 4
 
 
 class CompileLog:
@@ -252,10 +257,53 @@ def streamed_mc():
     return device, check
 
 
+def stage_sweep():
+    import numpy as np
+
+    from repro.core import evaluator, policies
+    from repro.core.jobs import generate_workload
+
+    n, m = N_STAGE_SWEEP, M_STAGE_SWEEP
+    orders = np.array(list(itertools.permutations(range(n))), dtype=np.int32)
+    groups = [
+        generate_workload(np.random.default_rng([SEED, 7, g]), n, m, 1)
+        for g in range(STAGE_SWEEP_GROUPS)
+    ]
+
+    def device():
+        return [
+            {
+                "many": evaluator.evaluate_many(
+                    jobs, ("optimal", "rank"), np.random.default_rng([SEED, 8, g])
+                ),
+                "all": evaluator.expected_sojourn_static(jobs, orders),
+            }
+            for g, jobs in enumerate(groups)
+        ]
+
+    def check(results):
+        from repro.kernels.sojourn_eval.ref import ref_sojourn
+
+        errs = {}
+        for g, (jobs, res) in enumerate(zip(groups, results)):
+            sizes, probs, num_stages = policies.padded_arrays(jobs)
+            ref_all, _ = ref_sojourn(sizes, probs, num_stages, orders)
+            (ref_rank,), _ = ref_sojourn(
+                sizes, probs, num_stages, policies.rank_order(jobs)[None]
+            )
+            errs[f"group{g}.all_orders"] = ("enum", rel_err(res["all"], ref_all))
+            errs[f"group{g}.optimal"] = ("enum", rel_err(res["many"]["optimal"], ref_all.min()))
+            errs[f"group{g}.rank"] = ("enum", rel_err(res["many"]["rank"], ref_rank))
+        return errs, {}
+
+    return device, check
+
+
 PHASES = (
     ("numerical_study", numerical_study),
     ("exact_cap", exact_cap),
     ("streamed_mc", streamed_mc),
+    ("stage_sweep", stage_sweep),
 )
 
 

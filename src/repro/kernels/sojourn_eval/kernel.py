@@ -67,6 +67,7 @@ from repro.kernels.sojourn_eval import rng
 __all__ = [
     "sojourn_enum",
     "enum_order_block",
+    "enum_grid",
     "sojourn_outcomes",
     "sojourn_mc",
     "BLOCK_COMBOS",
@@ -175,6 +176,12 @@ def enum_order_block(p_orders: int, k_total: int) -> tuple[int, int]:
         unroll, cap = 1, ORDER_BLOCK_TILED
     even = pl.cdiv(p_orders, pl.cdiv(p_orders, cap))
     return pl.cdiv(even, unroll) * unroll, unroll
+
+
+def enum_grid(p_orders: int, k_total: int) -> tuple[int, int]:
+    """Grid of :func:`sojourn_enum`: ``(order blocks, combination tiles)``."""
+    block, _ = enum_order_block(p_orders, k_total)
+    return pl.cdiv(p_orders, block), max(1, pl.cdiv(k_total, BLOCK_COMBOS))
 
 
 def _enum_kernel(
@@ -291,8 +298,9 @@ def sojourn_enum(
     """
     _, n, m = tables.shape
     p_orders = orders.shape[0] // n
-    nkt = max(1, pl.cdiv(k_total, BLOCK_COMBOS))
     block, unroll = enum_order_block(p_orders, k_total)
+    grid = enum_grid(p_orders, k_total)
+    nkt = grid[1]
     dtype = tables.dtype
     kernel = functools.partial(
         _enum_kernel, n=n, m=m, block=block, unroll=unroll, p_orders=p_orders,
@@ -307,7 +315,7 @@ def sojourn_enum(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(pl.cdiv(p_orders, block), nkt),
+            grid=grid,
             in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM), smem],
             out_specs=[smem, smem],
             scratch_shapes=scratch,

@@ -389,8 +389,9 @@ def _sojourn_eval(
             def call(tables, ints, ob):
                 p_b = ob.shape[0] // n
                 profiling.count("sojourn_enum.orders", p_b)
-                block, _ = K.enum_order_block(p_b, k_total)
-                profiling.count("sojourn_enum.order_blocks", -(-p_b // block))
+                blocks, tiles = K.enum_grid(p_b, k_total)
+                profiling.count("sojourn_enum.order_blocks", blocks)
+                profiling.count("sojourn_enum.grid_steps", blocks * tiles)
                 return K.sojourn_enum(tables, ints, ob, k_total, interpret=interpret)
     else:
         if weights is None:

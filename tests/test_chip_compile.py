@@ -5,7 +5,8 @@ that is described, not attached.  This catches what interpret mode
 cannot (block shapes Mosaic refuses, SMEM overflow, unsupported casts,
 64-bit types) at the sizes the evaluator really uses: the op's batch of
 4,096 orders, float32, N = 9 (OPTIMAL's search) and N = 21 (K = 2^21),
-M = 2, and the dynamic lockstep at 1 and 3 servers.
+M = 2, and the dynamic lockstep at 1 and 3 servers; and the static
+kernel at the stage sweep's N = 5, M = 8 (K = 2^15, 120 orders and 1).
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every test worker imports this
@@ -86,6 +87,17 @@ def test_sojourn_enum_compiles(one_chip, n):
         if 'custom_call_target="tpu_custom_call"' in line
     ]
     assert [xplane.op_name(c) for c in calls] == ["sojourn_enum"]
+
+
+@pytest.mark.parametrize("p", (120, 1))
+def test_sojourn_enum_compiles_eight_stages(one_chip, p):
+    """Table XIV's cell: N = 5, M = 8, K = 8**5 over 32 combination tiles;
+    OPTIMAL's 120 orders (four blocks of 30 with Kahan tiles) and RANK's one."""
+    n, m = 5, 8
+    tables = jax.ShapeDtypeStruct((2, n, m), jnp.float32, sharding=one_chip)
+    ints = jax.ShapeDtypeStruct((2, n), jnp.int32, sharding=one_chip)
+    orders = jax.ShapeDtypeStruct((p * n,), jnp.int32, sharding=one_chip)
+    _compile(lambda t, i, o: K.sojourn_enum(t, i, o, m**n), tables, ints, orders)
 
 
 @pytest.mark.parametrize("n", (9, 21))

@@ -145,6 +145,27 @@ def test_enum_counts_orders_and_order_blocks(profiled, monkeypatch, n, p, batch,
     assert [counters[k] for k in names] == [p, blocks]
 
 
+# (jobs, stages, orders, grid steps): 24 orders at K = 8**4 = 4096 (four
+# combination tiles) are one block of four steps; 70 orders at K = 2**11 =
+# 2048 (two tiles) are three blocks of two.
+GRID_STEPS = [(4, 8, 24, 4), (11, 2, 70, 6)]
+
+
+@pytest.mark.parametrize("n,m,p,steps", GRID_STEPS)
+def test_enum_counts_grid_steps(profiled, n, m, p, steps):
+    jobs = generate_workload(np.random.default_rng(n), n, num_stages=m)
+    sizes, probs, num_stages = policies.padded_arrays(jobs)
+    rng = np.random.default_rng(n)
+    orders = np.array([rng.permutation(n) for _ in range(p)], np.int32)
+    name = "prof.sojourn_enum.grid_steps"
+    profiling.enable(False)
+    sojourn_eval(sizes, probs, num_stages, orders, impl="interpret")
+    assert name not in profiled.snapshot()["counters"]
+    profiling.enable(True)
+    sojourn_eval(sizes, probs, num_stages, orders, impl="interpret")
+    assert profiled.snapshot()["counters"][name] == steps
+
+
 def test_count_adds_only_when_enabled():
     was = profiling.enabled()
     reg = MetricsRegistry()

@@ -241,6 +241,10 @@ ORDER_BLOCK_CASES = {
     "tiles_ragged_stages": lambda: (_ragged_jobs(59), _random_orders(8, 40, 59)),
     "all_orders_n5": lambda: (generate_workload(np.random.default_rng(61), 5, num_stages=3),
                               np.array(list(itertools.permutations(range(5))), np.int32)),
+    # Eight stages a job (Table XIV's largest M): K = 8**4 = 4096 spans four
+    # tiles, and all 24 orders are one block carrying Kahan sums across them.
+    "tiles_m8_all_orders": lambda: (generate_workload(np.random.default_rng(67), 4, num_stages=8),
+                                    np.array(list(itertools.permutations(range(4))), np.int32)),
 }
 
 
@@ -310,6 +314,18 @@ def test_exact_beyond_materialization_cap():
     mc_o, mc_w = evaluator.sample_outcomes(jobs, 20_000, rng)
     mc = evaluator.expected_sojourn_static(jobs, order, outcomes=mc_o, weights=mc_w)
     assert abs(mc - val) / val < 0.05
+
+
+def test_evaluate_many_optimal_and_rank_at_eight_stages():
+    """Table XIV's comparison at M = 8: OPTIMAL is the least value over all
+    N! orders, and RANK the value of its own order."""
+    jobs = generate_workload(np.random.default_rng(71), 4, num_stages=8)
+    res = evaluator.evaluate_many(jobs, ("optimal", "rank"), np.random.default_rng(0))
+    all_orders = np.array(list(itertools.permutations(range(4))), np.int32)
+    r_all, _ = _ref(jobs, all_orders)
+    (r_rank,), _ = _ref(jobs, policies.rank_order(jobs)[None])
+    np.testing.assert_allclose(res["optimal"], r_all.min(), rtol=RTOL)
+    np.testing.assert_allclose(res["rank"], r_rank, rtol=RTOL)
 
 
 def test_evaluate_many_tiering():
