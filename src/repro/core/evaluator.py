@@ -363,20 +363,39 @@ def expected_sojourn_dynamic(
 # ---------------------------------------------------------------------------
 
 
+@functools.lru_cache(maxsize=None)
+def _all_orders(n: int) -> np.ndarray:
+    """The read-only ``(n!, n)`` int32 table of every order of ``n`` jobs.
+
+    Rows are in :func:`itertools.permutations`' lexicographic order, so
+    an argmin over the table breaks ties to the first order in it.  Built
+    once per ``n`` and shared by every later call in the process; each
+    build counts as ``prof.optimal.order_builds``.  :func:`optimal_order`
+    bounds ``n`` (9 by default), where the table takes 13.1 MB and all
+    ``n <= 9`` together about 14.5 MB of host memory.
+    """
+    profiling.count("optimal.order_builds", 1)
+    orders = np.array(list(itertools.permutations(range(n))), dtype=np.int32)
+    orders.setflags(write=False)
+    return orders
+
+
 def optimal_order(jobs: Workload, max_n: int = 9) -> tuple[np.ndarray, float]:
     """Exhaustive search over all N! non-preemptive orders (Thm III.1).
 
-    Building the ``(N!, N)`` order array is the ``prof.optimal.orders``
-    span of :mod:`repro.obs.profiling`.
+    Fetching the ``(N!, N)`` order table (:func:`_all_orders`, built on
+    the first call for each N) is the ``prof.optimal.orders`` span of
+    :mod:`repro.obs.profiling`.  The returned order is a copy, never a
+    view into the shared table.
     """
     n = len(jobs)
     if n > max_n:
         raise ValueError(f"exhaustive search with N={n} > {max_n} is too expensive")
     with profiling.span("optimal.orders"):
-        orders = np.array(list(itertools.permutations(range(n))), dtype=np.int32)
+        orders = _all_orders(n)
     vals = expected_sojourn_static(jobs, orders)
     best = int(np.argmin(vals))
-    return orders[best], float(vals[best])
+    return orders[best].copy(), float(vals[best])
 
 
 def evaluate(

@@ -7,6 +7,8 @@ import pytest
 
 from repro.core import evaluator, policies
 from repro.core.jobs import JobSpec, generate_workload
+from repro.kernels.sojourn_eval.ref import ref_sojourn
+from repro.obs import get_registry, profiling
 
 
 def _oracle_static(jobs, order):
@@ -50,6 +52,55 @@ def test_optimal_lower_bounds_all_policies():
         _, e_opt = evaluator.optimal_order(jobs)
         for pol in ("rank", "serpt", "sr"):
             assert evaluator.evaluate(jobs, pol) >= e_opt - 1e-6
+
+
+def _lex_orders(n):
+    return np.array(list(itertools.permutations(range(n))), np.int32)
+
+
+@pytest.mark.parametrize("n", range(1, 9))
+def test_order_table_is_lexicographic_and_read_only(n):
+    table = evaluator._all_orders(n)
+    assert table.dtype == np.int32
+    assert not table.flags.writeable
+    np.testing.assert_array_equal(table, _lex_orders(n))
+    assert evaluator._all_orders(n) is table
+
+
+def test_optimal_order_is_float64_argmin_and_builds_table_once():
+    rng = np.random.default_rng(9)
+    groups = [generate_workload(rng, 6, 2, 2) for _ in range(2)]
+    was = profiling.enabled()
+    reg = get_registry()
+    reg.clear()
+    evaluator._all_orders.cache_clear()
+    profiling.enable(True)
+    try:
+        got = [evaluator.optimal_order(jobs) for jobs in groups]
+        builds = reg.snapshot()["counters"]["prof.optimal.order_builds"]
+    finally:
+        profiling.enable(was)
+        reg.clear()
+    assert builds == 1
+    orders = _lex_orders(6)
+    for jobs, (order, val) in zip(groups, got):
+        want, _ = ref_sojourn(*policies.padded_arrays(jobs), orders)
+        best = int(np.argmin(want))
+        np.testing.assert_array_equal(order, orders[best])
+        assert val == pytest.approx(want[best], rel=1e-9)
+
+
+def test_optimal_order_returns_a_copy():
+    jobs = generate_workload(np.random.default_rng(10), 5, 2, 1)
+    order, val = evaluator.optimal_order(jobs)
+    kept = order.copy()
+    assert order.flags.writeable
+    assert not np.shares_memory(order, evaluator._all_orders(5))
+    order[:] = order[::-1]
+    again, val_again = evaluator.optimal_order(jobs)
+    np.testing.assert_array_equal(again, kept)
+    assert val_again == val
+    np.testing.assert_array_equal(evaluator._all_orders(5), _lex_orders(5))
 
 
 def test_rank_near_optimal_small_n():
