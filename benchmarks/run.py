@@ -27,7 +27,7 @@ import time
 import numpy as np
 
 from repro.configs.paper_workloads import NUMERICAL, TRACE
-from repro.core.evaluator import evaluate_many, exact_combination_count
+from repro.core.evaluator import evaluate_many
 from repro.core.jobs import generate_workload
 from repro.core.simulator import simulate
 from repro.core.trace import synthesize_trace
@@ -249,264 +249,6 @@ def table_faults(full: bool = False):
 
 
 # ---------------------------------------------------------------------------
-# Fused evaluator microbenchmark (BENCH_eval.json)
-# ---------------------------------------------------------------------------
-
-
-def table_eval_perf(full: bool = False):
-    """Seed materialized evaluator vs the fused streaming op.
-
-    The seed path builds the (K, N) outcome/duration/success tables on the
-    host and runs the jitted ``_static_batch`` reduction; the fused path
-    (``repro.kernels.sojourn_eval``) decodes combinations on the fly and
-    never materializes them.  Timed at K = 2**21 (the seed's exact-eval
-    cap); ``--full`` adds a fused-only row at K = 2**26, beyond what the
-    seed could represent in memory.
-    """
-    from repro.core import evaluator, policies
-    from repro.runtime import x64
-
-    def fused_time(jobs, orders, repeats):
-        ts = []
-        for _ in range(repeats + 1):  # first rep warms the jit cache
-            t0 = time.perf_counter()
-            vals = evaluator.expected_sojourn_static(jobs, orders, impl="xla")
-            ts.append(time.perf_counter() - t0)
-        return float(np.median(ts[1:])), np.asarray(vals)
-
-    def seed_time(jobs, orders, repeats):
-        ts = []
-        for _ in range(repeats + 1):
-            t0 = time.perf_counter()
-            # per-call work in the seed design: materialize + gather + jit
-            outcomes, weights = evaluator.enumerate_outcomes(jobs)
-            durations, success = evaluator._realized_arrays(jobs, outcomes)
-            with x64():
-                vals = np.asarray(evaluator._static_batch(
-                    np.asarray(durations, np.float64), success,
-                    np.asarray(weights, np.float64), orders,
-                ))
-            ts.append(time.perf_counter() - t0)
-        return float(np.median(ts[1:])), vals
-
-    rows = []
-    rng = np.random.default_rng(31)
-    repeats = 5 if full else 3
-
-    n = 21  # M=2 -> K = 2**21, the seed cap
-    jobs = generate_workload(rng, n)
-    orders = np.stack([policies.rank_order(jobs),
-                       rng.permutation(n).astype(np.int32)])
-    t_fused, v_fused = fused_time(jobs, orders, repeats)
-    t_seed, v_seed = seed_time(jobs, orders, repeats)
-    relerr = float(np.max(np.abs(v_fused - v_seed) / np.abs(v_seed)))
-    assert relerr <= 1e-9, f"fused/seed divergence: {relerr}"
-    rows.append({
-        "k_combos": 1 << n, "n_jobs": n, "orders": len(orders),
-        "seed_s": t_seed, "fused_s": t_fused,
-        "speedup": t_seed / t_fused, "max_relerr_vs_seed": relerr,
-    })
-
-    if full:  # beyond the seed's representable range: fused only
-        n = 26
-        jobs = generate_workload(rng, n)
-        orders = policies.rank_order(jobs)[None]
-        t_fused, _ = fused_time(jobs, orders, 1)
-        rows.append({
-            "k_combos": 1 << n, "n_jobs": n, "orders": 1,
-            "seed_s": None, "fused_s": t_fused,
-            "speedup": None, "max_relerr_vs_seed": None,
-        })
-
-    _save("BENCH_eval", rows)
-    return rows
-
-
-def table_eval_dynamic(full: bool = False):
-    """Seed materialized lockstep vs the fused dynamic op (BENCH_eval_dynamic).
-
-    The seed design for SR/SERPT (``evaluator._dynamic_batch``) materializes
-    the (K, N) outcome/success tables host-side and simulates every
-    combination in a vmapped ``fori_loop``; the fused op
-    (``repro.kernels.sojourn_eval.dynamic``) decodes combinations on the
-    fly and simulates them inside streaming tiles.  Timed at K = 2**21
-    (the seed's materialization cap); ``--full`` adds SERPT and a
-    fused-only row at K = 2**26, beyond what the seed could represent.
-    """
-    from repro.core import evaluator, policies
-    from repro.runtime import x64
-
-    def fused_time(jobs, policy, repeats):
-        ts = []
-        for _ in range(repeats + 1):  # first rep warms the jit cache
-            t0 = time.perf_counter()
-            val = evaluator.expected_sojourn_dynamic(jobs, policy, impl="xla")
-            ts.append(time.perf_counter() - t0)
-        return float(np.median(ts[1:])), val
-
-    def seed_time(jobs, policy, repeats):
-        idx_table = policies.index_table(jobs, policy)
-        stage_durs = policies.stage_durations(jobs)
-        _, _, num_stages = policies.padded_arrays(jobs)
-        ts = []
-        for _ in range(repeats + 1):
-            t0 = time.perf_counter()
-            # per-call work in the seed design: materialize + gather + jit
-            outcomes, weights = evaluator.enumerate_outcomes(jobs)
-            _, success = evaluator._realized_arrays(jobs, outcomes)
-            with x64():
-                val = float(evaluator._dynamic_batch(
-                    np.asarray(idx_table, np.float64),
-                    np.asarray(stage_durs, np.float64), outcomes, success,
-                    np.asarray(weights, np.float64), int(num_stages.sum()),
-                ))
-            ts.append(time.perf_counter() - t0)
-        return float(np.median(ts[1:])), val
-
-    rows = []
-    rng = np.random.default_rng(37)
-    repeats = 2 if full else 1
-    policies_timed = ("sr", "serpt") if full else ("sr",)
-
-    n = 21  # M=2 -> K = 2**21, the materialization cap
-    jobs = generate_workload(rng, n)
-    for policy in policies_timed:
-        t_fused, v_fused = fused_time(jobs, policy, repeats)
-        t_seed, v_seed = seed_time(jobs, policy, repeats)
-        relerr = abs(v_fused - v_seed) / abs(v_seed)
-        assert relerr <= 1e-9, f"fused/seed divergence: {relerr}"
-        rows.append({
-            "k_combos": 1 << n, "n_jobs": n, "policy": policy,
-            "seed_s": t_seed, "fused_s": t_fused,
-            "speedup": t_seed / t_fused, "max_relerr_vs_seed": relerr,
-        })
-
-    if full:  # beyond the seed's representable range: fused only
-        n = 26
-        jobs = generate_workload(rng, n)
-        t_fused, _ = fused_time(jobs, "sr", 1)
-        rows.append({
-            "k_combos": 1 << n, "n_jobs": n, "policy": "sr",
-            "seed_s": None, "fused_s": t_fused,
-            "speedup": None, "max_relerr_vs_seed": None,
-        })
-
-    _save("BENCH_eval_dynamic", {"rows": rows})
-    return rows
-
-
-def table_eval_mc(full: bool = False, smoke: bool = False):
-    """Streamed Monte Carlo vs the materialized sample-table path
-    (BENCH_eval_mc).
-
-    Beyond ``MAX_EXACT_COMBOS`` the evaluator estimates by Monte Carlo.
-    The materialized design (``sample_outcomes`` + the explicit-outcomes
-    op) builds the (S, N) sample table host-side every call, so the
-    sample count is bounded by host memory and the throughput by table
-    traffic; the streamed design (``samples=(seed, n_samples)``)
-    generates outcomes inside the evaluation tiles from the Threefry
-    counter stream and never materializes them.  Timed on a
-    K = 2**27 > MAX_EXACT_COMBOS workload: streamed at 2**23 samples vs
-    materialized at its practical 2**21 — the streamed path must be
-    >= 2x the throughput at 4x the samples.  A small-K control checks
-    the streamed estimate against the exact fused enumeration within
-    3-sigma CLT bounds (sigma replayed host-side from the same stream).
-
-    ``smoke`` (CI) shrinks sample counts and runs the Pallas kernels in
-    interpret mode instead of the compiled XLA path — a crash/parity
-    canary, not a performance measurement.
-    """
-    from repro.core import evaluator, policies
-    from repro.kernels.sojourn_eval.ref import ref_mc_outcomes
-
-    impl = "interpret" if smoke else "xla"
-    seed = 0x5EED
-    rng = np.random.default_rng(43)
-
-    # --- small-K control: streamed estimate vs exact, CLT bound ----------
-    ctrl_samples = 1 << (12 if smoke else 16)
-    ctrl_jobs = generate_workload(rng, 8)  # K = 256
-    order = policies.rank_order(ctrl_jobs)
-    exact = evaluator.expected_sojourn_static(ctrl_jobs, order, impl=impl)
-    est = evaluator.expected_sojourn_static(
-        ctrl_jobs, order, samples=(seed, ctrl_samples), impl=impl
-    )
-    sizes, probs, num_stages = policies.padded_arrays(ctrl_jobs)
-    outcomes, _ = ref_mc_outcomes(probs, num_stages, seed, ctrl_samples)
-    d = sizes[np.arange(len(ctrl_jobs))[None, :], outcomes]
-    succ = outcomes == num_stages[None, :] - 1
-    t = np.cumsum(d[:, order], axis=1)
-    cnt = succ.sum(axis=1)
-    vals = np.where(
-        cnt > 0, (t * succ[:, order]).sum(axis=1) / np.maximum(cnt, 1), 0.0
-    )
-    sigma = float(vals.std(ddof=1) / np.sqrt(ctrl_samples))
-    z = abs(est - exact) / sigma
-    assert z <= 3.0, f"streamed MC outside 3-sigma CLT bound: z={z}"
-    control = {
-        "k_combos": int(evaluator.exact_combination_count(ctrl_jobs)),
-        "n_samples": ctrl_samples, "exact": float(exact),
-        "streamed_est": float(est), "sigma": sigma, "z_score": float(z),
-    }
-
-    # --- throughput: K > MAX_EXACT_COMBOS, MC is the only option ---------
-    n = 27  # M=2 -> K = 2**27 > MAX_EXACT_COMBOS
-    jobs = generate_workload(rng, n)
-    orders = policies.rank_order(jobs)[None]
-    s_streamed = 1 << (12 if smoke else 23)
-    s_materialized = 1 << (10 if smoke else 21)
-    repeats = 1 if smoke else (3 if full else 2)
-
-    def streamed_time():
-        ts = []
-        for rep in range(repeats + 1):  # first rep warms the jit cache
-            t0 = time.perf_counter()
-            evaluator.expected_sojourn_static(
-                jobs, orders, samples=(seed + rep, s_streamed), impl=impl
-            )
-            ts.append(time.perf_counter() - t0)
-        return float(np.median(ts[1:]))
-
-    def materialized_time():
-        g = np.random.default_rng(seed)
-        ts = []
-        for _ in range(repeats + 1):
-            t0 = time.perf_counter()
-            # per-call work in the materialized design: host sampling of
-            # the (S, N) table, then the explicit-outcomes op
-            mc_o, mc_w = evaluator.sample_outcomes(jobs, s_materialized, g)
-            evaluator.expected_sojourn_static(
-                jobs, orders, outcomes=mc_o, weights=mc_w, impl=impl
-            )
-            ts.append(time.perf_counter() - t0)
-        return float(np.median(ts[1:]))
-
-    t_streamed = streamed_time()
-    t_materialized = materialized_time()
-    tp_streamed = s_streamed / t_streamed
-    tp_materialized = s_materialized / t_materialized
-    row = {
-        "k_combos": 1 << n, "n_jobs": n,
-        "streamed_samples": s_streamed, "streamed_s": t_streamed,
-        "streamed_samples_per_s": tp_streamed,
-        "materialized_samples": s_materialized, "materialized_s": t_materialized,
-        "materialized_samples_per_s": tp_materialized,
-        "throughput_ratio": tp_streamed / tp_materialized,
-    }
-    if not smoke:
-        assert row["throughput_ratio"] >= 2.0, (
-            f"streamed MC below the 2x throughput bar: {row}"
-        )
-    _save("BENCH_eval_mc", {
-        "mode": "smoke" if smoke else ("full" if full else "default"),
-        "impl": impl,
-        "clt_control": control,
-        "rows": [row],
-    })
-    return [{**row, "control_z_score": control["z_score"]}]
-
-
-# ---------------------------------------------------------------------------
 # Roofline aggregation (reads dry-run artifacts)
 # ---------------------------------------------------------------------------
 
@@ -550,9 +292,6 @@ TABLES = {
     "stages": table_stages,
     "trace": table_trace,
     "faults": table_faults,
-    "eval_perf": table_eval_perf,
-    "eval_dynamic": table_eval_dynamic,
-    "eval_mc": table_eval_mc,
     "roofline": lambda full=False: table_roofline(),
 }
 
@@ -561,9 +300,6 @@ def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--table", default="all", choices=["all", *TABLES])
     ap.add_argument("--full", action="store_true", help="paper-scale trials")
-    ap.add_argument("--smoke", action="store_true",
-                    help="tiny sample counts + interpret-mode kernels "
-                         "(eval_mc only; CI crash canary)")
     ap.add_argument("--cache-dir", default=None, metavar="DIR",
                     help="persist the workload-keyed memo tier in DIR "
                          "(overrides REPRO_CACHE_DIR)")
@@ -590,8 +326,6 @@ def main() -> None:
             if shared_study is None:
                 shared_study = _numerical_study(args.full)
             rows = TABLES[name](args.full, study=shared_study)
-        elif name == "eval_mc":
-            rows = table_eval_mc(full=args.full, smoke=args.smoke)
         else:
             rows = TABLES[name](full=args.full)
         dt = time.perf_counter() - t0

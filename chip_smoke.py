@@ -15,8 +15,7 @@ by ``generate_workload`` from fixed seeds:
    SR and RANK index policies on 1 and 3 servers; then N = 26
    (K = 2^26 = ``MAX_EXACT_COMBOS``): RANK.
 3. ``streamed_mc``: N = 27 (K = 2^27), so ``evaluate_many`` takes its
-   shared-seed Monte Carlo branch with 2^20 samples; RANK is also scored
-   over the same samples passed as an explicit outcome table.
+   shared-seed Monte Carlo branch with 2^20 samples.
 4. ``stage_sweep``: Table XIV at its largest stage count, N = 5 jobs of
    M = 8 stages from workload set 1 (K = 8^5 = 32,768 over 32
    combination tiles): OPTIMAL's 120 orders in one static call, and RANK.
@@ -24,14 +23,14 @@ by ``generate_workload`` from fixed seeds:
 The chip computes in float32.  Each phase checks every answer against a
 float64 reference: the dense oracles of ``ref.py`` where their tables
 fit, and otherwise the x64 XLA path run on the host CPU device.  An
-answer passes within ``ops.CHIP_RTOL`` relative error for its entry mode,
+answer passes within ``ops.CHIP_RTOL`` relative error for its source,
 and OPTIMAL's order must be optimal in float64 up to that tolerance.
 
 Each phase runs its device work twice and prints one JSON line: the
 implementation the ops resolved to (from the ``repro.obs.profiling``
 span names, which must end in ``.pallas``), backend compile seconds and
 persistent-cache hits and misses of the first run, the second run's
-wall seconds, and the largest relative error per entry mode.  The last
+wall seconds, and the largest relative error per source.  The last
 line is ``{"ok": true, "device": {...}}``.  The script exits nonzero
 without that line when the first device is not a TPU or a phase fails.
 One process; it starts no other.
@@ -234,8 +233,7 @@ def streamed_mc():
         many = evaluator.evaluate_many(
             jobs, MC_ALGS, np.random.default_rng([SEED, 6]), mc_samples=MC_SAMPLES
         )
-        table = evaluator.expected_sojourn_static(jobs, rank, outcomes, weights)
-        return {"many": many, "rank_table": table}
+        return {"many": many}
 
     def check(res):
         (want_rank, want_random), _ = ref_sojourn(
@@ -244,7 +242,6 @@ def streamed_mc():
         errs = {
             "rank": ("mc", rel_err(res["many"]["rank"], want_rank)),
             "random": ("mc", rel_err(res["many"]["random"], want_random)),
-            "rank_table": ("outcomes", rel_err(res["rank_table"], want_rank)),
         }
         with jax.default_device(jax.devices("cpu")[0]):
             for pol in ("serpt", "sr"):

@@ -3,34 +3,35 @@
 The paper (Section IV-A1) evaluates a schedule *exactly* by enumerating all
 combinations of per-job outcomes (which checkpoint each job stops at),
 weighting each combination by its probability.  We reproduce that scheme,
-fused and vectorized:
+fused and vectorized.  Outcomes reach the fused ops from one of two
+sources, never as a table:
+
+* **Exact enumeration** (``K <= MAX_EXACT_COMBOS = 2**26``): the ops
+  decode every combination on the fly inside their tiles, so no
+  ``(K, N)`` outcome matrix exists anywhere.
+* **Streamed Monte Carlo** (``samples=(seed, n_samples)``, what
+  :func:`evaluate_many` takes past the exact cap): outcomes are generated
+  inside the fused kernels from a counter-based Threefry stream keyed by
+  ``(seed, sample, job)``, so no (S, N) sample table is ever
+  materialized and all policies under one seed share identical outcome
+  streams (common random numbers; see ``docs/streaming_mc.md``).
+
+The entry points:
 
 * :func:`expected_sojourn_static` — a batch of static non-preemptive orders
   (Theorem III.1 justifies restricting to these for RANK/OPTIMAL/RANDOM)
-  evaluated by the fused :mod:`repro.kernels.sojourn_eval` op, which
-  decodes outcome combinations on the fly inside the kernel instead of
-  materializing the ``(K, N)`` outcome matrix host-side.  Exact
-  evaluation scales to ``MAX_EXACT_COMBOS = 2**26`` combinations in
-  bounded memory; explicit outcome tables (Monte-Carlo samples or a
-  shared exact table) ride the same op's streaming path.
+  through :func:`repro.kernels.sojourn_eval.sojourn_eval`.
 * :func:`expected_sojourn_dynamic` — stage-level policies (SR / SERPT /
-  conditional-RANK) evaluated by the fused
-  :mod:`repro.kernels.sojourn_eval.dynamic` op, which decodes outcome
-  combinations on the fly and runs the single-server stage-boundary
-  preemption simulation *inside* each tile, so exact dynamic evaluation
-  also scales to ``MAX_EXACT_COMBOS`` with no outcome table.  Explicit
-  outcome tables (Monte-Carlo samples) ride the legacy
-  :func:`_dynamic_batch` lockstep simulation, which is retained as the
-  ``<= MAX_MATERIALIZED_COMBOS`` reference tier for differential tests.
+  conditional-RANK) through :mod:`repro.kernels.sojourn_eval.dynamic`,
+  which runs the W-server stage-boundary preemption simulation *inside*
+  each tile.
 * :func:`optimal_order` — exhaustive search over permutations (N <= 9).
-* Beyond ``MAX_EXACT_COMBOS``, both ops switch to *streaming* Monte
-  Carlo via ``samples=(seed, n_samples)``: outcomes are generated
-  inside the fused kernels from a counter-based Threefry stream keyed
-  by ``(seed, sample, job)``, so no (S, N) sample table is ever
-  materialized and all policies under one seed share identical outcome
-  streams (common random numbers; see ``docs/streaming_mc.md``).
-  :func:`sample_outcomes` + explicit tables remain as the legacy
-  materialized tier.
+
+One reference tier stays beside them: the seed's materialized path
+(:func:`enumerate_outcomes`, :func:`_realized_arrays`,
+:func:`_static_batch`, :func:`_dynamic_batch`, capped at
+``MAX_MATERIALIZED_COMBOS = 2**21``).  Tests compare the fused ops
+against it; the program does not call it.
 
 Evaluation runs under :func:`repro.runtime.x64`, so host-side tables and
 the XLA path are float64 (<=1e-9 agreement with the seed path).  On a
@@ -64,7 +65,6 @@ from repro.runtime import x64
 
 __all__ = [
     "enumerate_outcomes",
-    "sample_outcomes",
     "expected_sojourn_static",
     "expected_sojourn_dynamic",
     "optimal_order",
@@ -77,8 +77,8 @@ __all__ = [
 #: materializing them) falls back to Monte Carlo.
 MAX_EXACT_COMBOS = 1 << 26
 
-#: Above this many combinations, a (K, N) outcome table is too large to
-#: materialize (dynamic-policy lockstep simulation and shared exact tables).
+#: Above this many combinations, the reference tier's (K, N) outcome
+#: table is too large to materialize.
 MAX_MATERIALIZED_COMBOS = 1 << 21
 
 
@@ -98,8 +98,18 @@ def _enum_meta(jobs: Workload) -> tuple[int, np.ndarray, np.ndarray]:
     return policies.workload_cached("enum_meta", jobs, compute)
 
 
+def _check_exact(jobs: Workload) -> None:
+    """Refuse exact evaluation past ``MAX_EXACT_COMBOS``."""
+    k_total, _, _ = _enum_meta(jobs)
+    if k_total > MAX_EXACT_COMBOS:
+        raise ValueError(
+            f"{k_total} combinations exceed MAX_EXACT_COMBOS; use "
+            "samples=(seed, n_samples)"
+        )
+
+
 def enumerate_outcomes(jobs: Workload) -> tuple[np.ndarray, np.ndarray]:
-    """All outcome combinations, materialized.
+    """All outcome combinations, materialized: the reference tier.
 
     Returns:
       outcomes: (K, N) int32 — for each combination, the stage index at
@@ -107,15 +117,15 @@ def enumerate_outcomes(jobs: Workload) -> tuple[np.ndarray, np.ndarray]:
       weights:  (K,) float64 — probability of each combination.
 
     Only valid up to ``MAX_MATERIALIZED_COMBOS``; the fused evaluator
-    handles larger exact enumerations without materialization.
+    handles larger exact enumerations without materialization.  Tests
+    compare against it; the program does not call it.
     """
     _, probs, _ = policies.padded_arrays(jobs)
     k_total, strides, num_stages = _enum_meta(jobs)
     if k_total > MAX_MATERIALIZED_COMBOS:
         raise ValueError(
-            f"{k_total} combinations exceed MAX_MATERIALIZED_COMBOS; use "
-            "sample_outcomes, or expected_sojourn_static(outcomes=None) "
-            "which enumerates inside the fused kernel"
+            f"{k_total} combinations exceed MAX_MATERIALIZED_COMBOS; "
+            "expected_sojourn_static enumerates inside the fused kernel"
         )
     # Single vectorized mixed-radix decode + gathered weight product (the
     # seed looped over jobs for both the meshgrid and the product).
@@ -129,25 +139,9 @@ def enumerate_outcomes(jobs: Workload) -> tuple[np.ndarray, np.ndarray]:
     return outcomes, weights
 
 
-def sample_outcomes(
-    jobs: Workload, n_samples: int, rng: np.random.Generator
-) -> tuple[np.ndarray, np.ndarray]:
-    """Monte-Carlo outcome sampling; weights are uniform 1/S.
-
-    Vectorized inverse-CDF sampling over the whole (S, N) matrix in one
-    shot (the seed drew per-job ``rng.choice`` columns in a Python loop).
-    """
-    _, probs, num_stages = policies.padded_arrays(jobs)
-    cdf = np.cumsum(probs, axis=1)  # (N, M); padded stages add 0 mass
-    u = rng.random((n_samples, len(jobs)))
-    outcomes = np.sum(u[:, :, None] >= cdf[None, :, :], axis=2)
-    outcomes = np.minimum(outcomes, num_stages[None, :] - 1).astype(np.int32)
-    weights = np.full((n_samples,), 1.0 / n_samples)
-    return outcomes, weights
-
-
 def _realized_arrays(jobs: Workload, outcomes: np.ndarray):
-    """Per-combination realized durations and success masks."""
+    """Per-combination realized durations and success masks (reference
+    tier: tests compare against it; the program does not call it)."""
     sizes, _, num_stages = policies.padded_arrays(jobs)
     durations = sizes[np.arange(len(jobs)), outcomes]  # (K, N) fancy gather
     success = outcomes == (num_stages[None, :] - 1)
@@ -163,8 +157,8 @@ def _realized_arrays(jobs: Workload, outcomes: np.ndarray):
 def _static_batch(durations, success, weights, orders, also_all_jobs=False):
     """Seed reference path: E[sojourn of successful jobs] per order.
 
-    Retained as the parity oracle for the fused op (tests and the
-    ``table_eval_perf`` benchmark); production calls go through
+    Retained as a parity reference for the fused op: tests compare
+    against it; the program does not call it, and goes through
     :func:`repro.kernels.sojourn_eval.sojourn_eval`.
 
     durations: (K, N)  realized total service per job per combination
@@ -192,8 +186,6 @@ def _static_batch(durations, success, weights, orders, also_all_jobs=False):
 def expected_sojourn_static(
     jobs: Workload,
     orders: np.ndarray,
-    outcomes: np.ndarray | None = None,
-    weights: np.ndarray | None = None,
     also_all_jobs: bool = False,
     impl: str = "auto",
     samples: tuple[int, int] | None = None,
@@ -201,11 +193,9 @@ def expected_sojourn_static(
     """Expected sojourn of successful jobs for static order(s), fused.
 
     ``orders`` may be (N,) for a single order or (P, N) for a batch.
-    With ``outcomes=None`` the evaluation is exact: all ``prod(M_i)``
-    combinations are enumerated *inside* the fused kernel (up to
-    ``MAX_EXACT_COMBOS``, never materializing a (K, N) array).  Passing
-    explicit ``outcomes``/``weights`` (Monte-Carlo samples or a shared
-    exact table) streams them through the same op.  Passing
+    By default the evaluation is exact: all ``prod(M_i)`` combinations
+    are enumerated *inside* the fused kernel (up to ``MAX_EXACT_COMBOS``,
+    never materializing a (K, N) array).  Passing
     ``samples=(seed, n_samples)`` instead runs *streaming* Monte Carlo:
     outcomes are generated inside the op from the counter-based RNG
     stream, so no (S, N) sample table is ever materialized and every
@@ -216,23 +206,11 @@ def expected_sojourn_static(
     if single:
         orders = orders[None]
     sizes, probs, num_stages = policies.padded_arrays(jobs)
-    if outcomes is None and samples is None:
-        k_total, _, _ = _enum_meta(jobs)
-        if k_total > MAX_EXACT_COMBOS:
-            raise ValueError(
-                f"{k_total} combinations exceed MAX_EXACT_COMBOS; use "
-                "samples=(seed, n_samples) or sample_outcomes"
-            )
+    if samples is None:
+        _check_exact(jobs)
     with x64():
         e_succ, e_all = sojourn_eval(
-            sizes,
-            probs,
-            num_stages,
-            orders,
-            outcomes=outcomes,
-            weights=weights,
-            samples=samples,
-            impl=impl,
+            sizes, probs, num_stages, orders, samples=samples, impl=impl
         )
     if also_all_jobs:
         return (e_succ[0], e_all[0]) if single else (e_succ, e_all)
@@ -248,10 +226,11 @@ def expected_sojourn_static(
 def _dynamic_batch(idx_table, stage_durs, outcomes, success, weights, total_stages):
     """Simulate a stage-level index policy for every outcome combination.
 
-    Retained as the ``<= MAX_MATERIALIZED_COMBOS`` reference tier (and the
-    Monte-Carlo path) for the fused streaming op in
-    :mod:`repro.kernels.sojourn_eval.dynamic`; the differential suite
-    checks the two against each other and the dense oracle.
+    Retained as the ``<= MAX_MATERIALIZED_COMBOS`` single-server reference
+    tier for the fused streaming op in
+    :mod:`repro.kernels.sojourn_eval.dynamic`: the differential suite
+    checks the two against each other and the dense oracle; the program
+    does not call it.
 
     idx_table:  (N, M)   priority after surviving s checkpoints (+inf pad)
     stage_durs: (N, M)   duration of executing checkpoint segment s
@@ -296,66 +275,32 @@ def _dynamic_batch(idx_table, stage_durs, outcomes, success, weights, total_stag
 def expected_sojourn_dynamic(
     jobs: Workload,
     policy: str,
-    outcomes: np.ndarray | None = None,
-    weights: np.ndarray | None = None,
     impl: str = "auto",
     samples: tuple[int, int] | None = None,
     n_servers: int = 1,
 ) -> float:
     """Exact expected sojourn of successful jobs for a stage-level policy.
 
-    With ``outcomes=None`` the evaluation is exact: all ``prod(M_i)``
-    combinations are decoded and *simulated* inside the fused dynamic
-    kernel (up to ``MAX_EXACT_COMBOS``, no (K, N) outcome table).
-    Passing ``samples=(seed, n_samples)`` runs streaming Monte Carlo
-    through the same fused op — outcomes are generated in-tile from the
+    By default the evaluation is exact: all ``prod(M_i)`` combinations
+    are decoded and *simulated* inside the fused dynamic kernel (up to
+    ``MAX_EXACT_COMBOS``, no (K, N) outcome table).  Passing
+    ``samples=(seed, n_samples)`` runs streaming Monte Carlo through the
+    same fused op — outcomes are generated in-tile from the
     counter-based RNG stream shared with the static op, so no (S, N)
     table exists at any sample count.  ``n_servers=W`` evaluates the
-    paper's online multi-server setting exactly (or by streamed MC);
-    both fused entry modes support it.  Passing explicit
-    ``outcomes``/``weights`` (a materialized table) runs the legacy
-    lockstep simulation, retained as the single-server reference tier.
+    paper's online multi-server setting, exactly or by streamed MC.
     """
     _, probs, num_stages = policies.padded_arrays(jobs)
     idx_table = policies.index_table(jobs, policy)
     stage_durs = policies.stage_durations(jobs)
-    if samples is not None:
-        with x64():
-            e_succ, _ = sojourn_eval_dynamic(
-                probs, stage_durs, num_stages, idx_table,
-                samples=samples, n_servers=n_servers, impl=impl,
-            )
-        return float(e_succ[0])
-    if outcomes is None:
-        k_total, _, _ = _enum_meta(jobs)
-        if k_total > MAX_EXACT_COMBOS:
-            raise ValueError(
-                f"{k_total} combinations exceed MAX_EXACT_COMBOS; use "
-                "samples=(seed, n_samples) or sample_outcomes"
-            )
-        with x64():
-            e_succ, _ = sojourn_eval_dynamic(
-                probs, stage_durs, num_stages, idx_table,
-                n_servers=n_servers, impl=impl,
-            )
-        return float(e_succ[0])
-    if n_servers != 1:
-        raise ValueError(
-            "the materialized outcomes/weights tier is single-server; "
-            "use the fused path (outcomes=None or samples=) for n_servers > 1"
-        )
-    _, success = _realized_arrays(jobs, outcomes)
-    total_stages = int(num_stages.sum())
+    if samples is None:
+        _check_exact(jobs)
     with x64():
-        val = _dynamic_batch(
-            jnp.asarray(np.asarray(idx_table, np.float64)),
-            jnp.asarray(np.asarray(stage_durs, np.float64)),
-            jnp.asarray(outcomes),
-            jnp.asarray(success),
-            jnp.asarray(np.asarray(weights, np.float64)),
-            total_stages,
+        e_succ, _ = sojourn_eval_dynamic(
+            probs, stage_durs, num_stages, idx_table,
+            samples=samples, n_servers=n_servers, impl=impl,
         )
-        return float(val)
+    return float(e_succ[0])
 
 
 # ---------------------------------------------------------------------------
@@ -402,8 +347,6 @@ def evaluate(
     jobs: Workload,
     policy: str,
     rng: np.random.Generator | None = None,
-    outcomes: np.ndarray | None = None,
-    weights: np.ndarray | None = None,
     samples: tuple[int, int] | None = None,
 ) -> float:
     """Expected sojourn time of successful jobs under ``policy``.
@@ -416,22 +359,19 @@ def evaluate(
     """
     if policy == "rank":
         return expected_sojourn_static(
-            jobs, policies.rank_order(jobs), outcomes, weights, samples=samples
+            jobs, policies.rank_order(jobs), samples=samples
         )
     if policy == "random":
         if rng is None:
             raise ValueError("random policy needs an rng")
         return expected_sojourn_static(
-            jobs, policies.random_order(jobs, rng), outcomes, weights,
-            samples=samples,
+            jobs, policies.random_order(jobs, rng), samples=samples
         )
     if policy == "optimal":
         _, val = optimal_order(jobs)
         return val
     if policy in ("serpt", "sr"):
-        return expected_sojourn_dynamic(
-            jobs, policy, outcomes, weights, samples=samples
-        )
+        return expected_sojourn_dynamic(jobs, policy, samples=samples)
     raise ValueError(f"unknown policy {policy!r}")
 
 

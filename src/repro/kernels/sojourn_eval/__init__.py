@@ -35,15 +35,10 @@ combination axis innermost (sequential on TPU).  Each grid tile owns
    scratch accumulator that persists across combination tiles, flushed
    to the per-order output on the last tile.
 
-A second kernel (``sojourn_outcomes``) runs the same fused gather +
-prefix sum + weighted reduction over an *explicit* outcome matrix
-(Monte-Carlo samples or a shared exact table) streamed through VMEM in
-stage-major ``(SUBLANES, LANES)`` tiles.
-
-``ops.sojourn_eval`` fronts both kernels with an ``impl`` dispatch
+``ops.sojourn_eval`` fronts the kernels with an ``impl`` dispatch
 ("pallas" / "interpret" / tiled "xla" streaming fallback for CPU), and
-:mod:`repro.core.evaluator` rides it for ``expected_sojourn_static``,
-Monte-Carlo evaluation, and ``optimal_order``.
+:mod:`repro.core.evaluator` rides it for ``expected_sojourn_static``
+and ``optimal_order``.
 
 Dynamic (stage-level) policies — SR / SERPT / conditional-RANK — stream
 through the same scheme via :mod:`repro.kernels.sojourn_eval.dynamic`:

@@ -482,6 +482,41 @@ def _sim_tile_xla(
     return tot, tsum, cnt
 
 
+def _simulate_tiles(
+    idx_table, stage_durs, radix, total, tile, decode, *, total_stages, n_servers
+):
+    """Eq. (9) for one policy, over ``total`` combinations or samples.
+
+    A ``lax.scan`` over tiles of ``tile`` indices ``k``: ``decode(k)``
+    gives their ``(T, N)`` stop stages and ``(T,)`` weights, indices past
+    ``total`` weigh zero, and every lane is simulated by
+    :func:`_sim_tile_xla`.  ``radix`` is the static tuple of stage counts.
+    """
+    n, m = stage_durs.shape
+    dtype = stage_durs.dtype
+    radix_a = jnp.asarray(radix, jnp.int32)[None, :]
+    job_ids = jnp.arange(n, dtype=jnp.int32)[None, :]
+
+    def tile_fn(carry, t):
+        e_succ, e_all = carry
+        k = t * tile + jnp.arange(tile, dtype=jnp.int32)
+        s, w = decode(k)
+        w = w * (k < total)
+        tot, tsum, cnt = _sim_tile_xla(
+            s, s == radix_a - 1, idx_table, stage_durs, job_ids, m=m,
+            total_stages=total_stages, n_servers=n_servers,
+        )
+        mean = jnp.where(cnt > 0, tot / jnp.maximum(cnt, 1).astype(dtype), 0.0)
+        return (e_succ + jnp.dot(w, mean), e_all + jnp.dot(w, tsum / n)), None
+
+    zero = jnp.zeros((), dtype)
+    n_tiles = max(1, -(-total // tile))
+    (e_succ, e_all), _ = jax.lax.scan(
+        tile_fn, (zero, zero), jnp.arange(n_tiles, dtype=jnp.int32)
+    )
+    return e_succ, e_all
+
+
 @functools.partial(
     jax.jit,
     static_argnames=(
@@ -494,33 +529,18 @@ def _dynamic_enum_xla(
 ):
     """Exact fused dynamic evaluation for one policy; ``strides``/``radix``
     are static tuples so the decode lowers to constant div/mod chains."""
-    n = probs.shape[0]
-    m = probs.shape[1]
-    dtype = probs.dtype
     strides_a = jnp.asarray(strides, jnp.int32)[None, :]
     radix_a = jnp.asarray(radix, jnp.int32)[None, :]
-    job_ids = jnp.arange(n, dtype=jnp.int32)[None, :]
-    n_tiles = max(1, -(-k_total // tile))
+    job_ids = jnp.arange(probs.shape[0], dtype=jnp.int32)[None, :]
 
-    def tile_fn(carry, t):
-        e_succ, e_all = carry
-        k = t * tile + jnp.arange(tile, dtype=jnp.int32)
-        valid = k < k_total
+    def decode(k):
         s = (k[:, None] // strides_a) % radix_a  # (T, N) on-the-fly decode
-        w = jnp.prod(probs[job_ids, s], axis=1) * valid  # Eq. (8)
-        succ = s == radix_a - 1
-        tot, tsum, cnt = _sim_tile_xla(
-            s, succ, idx_table, stage_durs, job_ids, m=m,
-            total_stages=total_stages, n_servers=n_servers,
-        )
-        mean = jnp.where(cnt > 0, tot / jnp.maximum(cnt, 1).astype(dtype), 0.0)
-        return (e_succ + jnp.dot(w, mean), e_all + jnp.dot(w, tsum / n)), None
+        return s, jnp.prod(probs[job_ids, s], axis=1)  # Eq. (8)
 
-    zero = jnp.zeros((), dtype)
-    (e_succ, e_all), _ = jax.lax.scan(
-        tile_fn, (zero, zero), jnp.arange(n_tiles, dtype=jnp.int32)
+    return _simulate_tiles(
+        idx_table, stage_durs, radix, k_total, tile, decode,
+        total_stages=total_stages, n_servers=n_servers,
     )
-    return e_succ, e_all
 
 
 @functools.partial(
@@ -535,37 +555,20 @@ def _dynamic_mc_xla(
     outcome generation (identical counters and compares to the static op
     and the host replay), then the shared lockstep simulation."""
     n = cdf.shape[0]
-    m = cdf.shape[1]
-    dtype = cdf.dtype
     radix_a = jnp.asarray(radix, jnp.int32)[None, :]
-    job_ids = jnp.arange(n, dtype=jnp.int32)[None, :]
-    n_tiles = max(1, -(-n_samples // tile))
-    x1 = jnp.broadcast_to(job_ids, (tile, n)).astype(jnp.uint32)
+    x1 = jnp.broadcast_to(jnp.arange(n, dtype=jnp.uint32)[None, :], (tile, n))
 
-    def tile_fn(carry, t):
-        e_succ, e_all = carry
-        k = t * tile + jnp.arange(tile, dtype=jnp.int32)
+    def decode(k):
         x0 = jnp.broadcast_to(k[:, None], (tile, n)).astype(jnp.uint32)
         bits, _ = rng.threefry2x32(jnp, (key2[0], key2[1]), x0, x1)
-        u = rng.uniform_from_bits(bits, dtype)
-        s = jnp.minimum(
-            jnp.sum(u[:, :, None] >= cdf[None, :, :], axis=2).astype(jnp.int32),
-            radix_a - 1,
-        )
-        w = (k < n_samples).astype(dtype) * (1.0 / n_samples)
-        succ = s == radix_a - 1
-        tot, tsum, cnt = _sim_tile_xla(
-            s, succ, idx_table, stage_durs, job_ids, m=m,
-            total_stages=total_stages, n_servers=n_servers,
-        )
-        mean = jnp.where(cnt > 0, tot / jnp.maximum(cnt, 1).astype(dtype), 0.0)
-        return (e_succ + jnp.dot(w, mean), e_all + jnp.dot(w, tsum / n)), None
+        u = rng.uniform_from_bits(bits, cdf.dtype)
+        s = jnp.sum(u[:, :, None] >= cdf[None, :, :], axis=2).astype(jnp.int32)
+        return jnp.minimum(s, radix_a - 1), jnp.full((tile,), 1.0 / n_samples, cdf.dtype)
 
-    zero = jnp.zeros((), dtype)
-    (e_succ, e_all), _ = jax.lax.scan(
-        tile_fn, (zero, zero), jnp.arange(n_tiles, dtype=jnp.int32)
+    return _simulate_tiles(
+        idx_table, stage_durs, radix, n_samples, tile, decode,
+        total_stages=total_stages, n_servers=n_servers,
     )
-    return e_succ, e_all
 
 
 # ---------------------------------------------------------------------------

@@ -24,12 +24,6 @@ in host NumPy (capping K at 2**21); these kernels never materialize it:
   combinations span tiles, each order's sum is Kahan-compensated in
   VMEM scratch across the (sequential, innermost) tile axis.
 
-* ``sojourn_outcomes`` — the same fused gather + prefix sum + weighted
-  reduction for an *explicit* outcome matrix (Monte-Carlo samples or a
-  shared exact table).  The ``(K, N)`` int32 matrix is streamed through
-  VMEM in ``(SUBLANES, LANES)``-shaped tiles laid out stage-major, so
-  the float duration/success matrices of the seed path are never built.
-
 * ``sojourn_mc`` — streaming Monte-Carlo: each grid tile owns
   ``BLOCK_COMBOS`` *sample indices* and generates the per-job outcome
   in-register from the counter-based Threefry stream
@@ -40,11 +34,10 @@ in host NumPy (capping K at 2**21); these kernels never materialize it:
   policies) evaluated under one seed sees the identical outcome stream
   (common random numbers).
 
-``sojourn_outcomes`` and ``sojourn_mc`` take per-*order* inputs (grid
-dim 0) whose job axis is pre-permuted by the caller (``ops.py``).  In
-every kernel, step ``pos`` of the position loop *is* service position:
-the running sum ``t`` after ``pos`` steps is the completion time of the
-job served ``pos``-th.
+``sojourn_mc`` takes per-*order* inputs (grid dim 0) whose job axis is
+pre-permuted by the caller (``ops.py``).  In both kernels, step ``pos``
+of the position loop *is* service position: the running sum ``t`` after
+``pos`` steps is the completion time of the job served ``pos``-th.
 
 Accumulation happens in the input dtype.  ``ops.sojourn_eval`` passes
 float32 when the kernels are compiled for a TPU (Mosaic has no 64-bit
@@ -68,7 +61,6 @@ __all__ = [
     "sojourn_enum",
     "enum_order_block",
     "enum_grid",
-    "sojourn_outcomes",
     "sojourn_mc",
     "BLOCK_COMBOS",
     "SUBLANES",
@@ -323,81 +315,6 @@ def sojourn_enum(
         out_shape=[out_shape, out_shape],
         interpret=interpret,
     )(orders, tables, ints)
-
-
-# ---------------------------------------------------------------------------
-# Explicit-outcome mode: stream a (K, N) outcome matrix (MC / shared tables)
-# ---------------------------------------------------------------------------
-
-
-def _outcomes_kernel(
-    order_ref,  # (1, 1, N) int32 SMEM: original job id served at each position
-    radix_ref,  # (1, 1, N) int32 SMEM, per-order permuted stage counts
-    sizes_ref,  # (1, N, M) VMEM, per-order permuted cumulative sizes
-    outcomes_ref,  # (N, 1, SUBLANES, LANES) int32 VMEM, original job indexing
-    weights_ref,  # (1, SUBLANES, LANES) VMEM, zero-padded combination weights
-    succ_ref,  # (1, 1, 1) SMEM out
-    all_ref,  # (1, 1, 1) SMEM out
-    *scratch,  # reduction_scratch
-    n: int,
-    m: int,
-    nkt: int,
-):
-    kt = pl.program_id(1)
-    dtype = sizes_ref.dtype
-    w = weights_ref[0]  # tail tiles are weight-padded with zeros
-    t = jnp.zeros((SUBLANES, LANES), dtype)
-    tsum = jnp.zeros((SUBLANES, LANES), dtype)
-    tot = jnp.zeros((SUBLANES, LANES), dtype)
-    cnt = jnp.zeros((SUBLANES, LANES), jnp.int32)
-    for pos in range(n):
-        job = order_ref[0, 0, pos]
-        radix = radix_ref[0, 0, pos]
-        s = outcomes_ref[job, 0]  # (SUBLANES, LANES) realized stop stages
-        d = jnp.zeros((SUBLANES, LANES), dtype)
-        for j in range(m):
-            d = jnp.where(s == j, sizes_ref[0, pos, j], d)
-        t = t + d
-        succ = s == radix - 1
-        tot = jnp.where(succ, tot + t, tot)
-        cnt = cnt + succ.astype(jnp.int32)
-        tsum = tsum + t
-    mean = jnp.where(cnt > 0, tot / jnp.maximum(cnt, 1).astype(dtype), 0.0)
-    accumulate(kt, nkt, (succ_ref, all_ref), scratch, (w * mean, w * (tsum / n)))
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def sojourn_outcomes(
-    sizes_p: jax.Array,  # (P, N, M) per-order permuted cumulative sizes
-    radix_p: jax.Array,  # (P, N) int32 permuted stage counts
-    orders: jax.Array,  # (P, N) int32 original job ids by position
-    outcomes_t: jax.Array,  # (N, KT, SUBLANES, LANES) int32 streamed tiles
-    weights_t: jax.Array,  # (KT, SUBLANES, LANES) zero-padded weights
-    *,
-    interpret: bool = False,
-) -> tuple[jax.Array, jax.Array]:
-    """Fused static-order evaluation over an explicit outcome matrix."""
-    p_orders, n, m = sizes_p.shape
-    nkt = weights_t.shape[0]
-    dtype = sizes_p.dtype
-    kernel = functools.partial(_outcomes_kernel, n=n, m=m, nkt=nkt)
-    out_specs, out_shape = scalar_outputs(p_orders, dtype)
-    out_succ, out_all = pl.pallas_call(
-        kernel,
-        grid=(p_orders, nkt),
-        in_specs=[
-            per_row_smem(n),
-            per_row_smem(n),
-            pl.BlockSpec((1, n, m), lambda p, kt: (p, 0, 0)),
-            pl.BlockSpec((n, 1, SUBLANES, LANES), lambda p, kt: (0, kt, 0, 0)),
-            pl.BlockSpec((1, SUBLANES, LANES), lambda p, kt: (kt, 0, 0)),
-        ],
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=reduction_scratch(dtype),
-        interpret=interpret,
-    )(orders[:, None], radix_p[:, None], sizes_p, outcomes_t, weights_t)
-    return out_succ[:, 0, 0], out_all[:, 0, 0]
 
 
 # ---------------------------------------------------------------------------

@@ -11,15 +11,12 @@
   * "interpret" — the Pallas kernels interpreted on CPU (parity tests).
   * "auto"      — "pallas" on TPU backends, else "xla".
 
-Three entry modes, mirroring :mod:`repro.core.evaluator`'s sources of
-outcome combinations:
+Two sources of outcome combinations, mirroring
+:mod:`repro.core.evaluator`:
 
-* ``sojourn_eval(..., outcomes=None)`` — *exact enumeration*: evaluates
-  all ``K = prod(M_i)`` combinations without ever materializing them
+* ``sojourn_eval(...)`` — *exact enumeration*: evaluates all
+  ``K = prod(M_i)`` combinations without ever materializing them
   (supports K up to ``repro.core.evaluator.MAX_EXACT_COMBOS``).
-* ``sojourn_eval(..., outcomes=, weights=)`` — *explicit outcomes*:
-  Monte-Carlo samples or a shared exact table; the float duration and
-  success matrices of the seed path are never built host-side.
 * ``sojourn_eval(..., samples=(seed, n_samples))`` — *streaming Monte
   Carlo*: outcomes are generated inside the tiles from the counter-based
   Threefry stream (:mod:`repro.kernels.sojourn_eval.rng`) and an
@@ -30,9 +27,12 @@ outcome combinations:
   (common random numbers), and ``ref.ref_mc_outcomes`` replays the
   stream host-side bitwise for parity.
 
+Both sources feed one scoring body: on the XLA path
+:func:`_score_tiles`, on the chip a kernel each (``kernel.py``).
+
 Precision: ``impl="pallas"`` always runs the kernels in float32, since
 Mosaic has no 64-bit types; its error against the float64 oracle is
-bounded per entry mode by :data:`CHIP_RTOL`.  ``"xla"`` and
+bounded per source by :data:`CHIP_RTOL`.  ``"xla"`` and
 ``"interpret"`` follow the ambient JAX x64 mode: the evaluator calls
 this op under :func:`repro.runtime.x64`, so on the CPU everything
 accumulates in float64 (<=1e-9 parity with the dense oracle).  The
@@ -59,13 +59,13 @@ __all__ = ["sojourn_eval", "CHIP_RTOL"]
 Impl = Literal["auto", "xla", "pallas", "interpret"]
 
 #: Largest relative error of the float32 Pallas path against the float64
-#: oracle, per entry mode, at the sizes ``chip_smoke.py`` runs.  Shared by
+#: oracle, per source, at the sizes ``chip_smoke.py`` runs.  Shared by
 #: that script and the float32 parity tests.  On a TPU v5e the largest
-#: errors seen were 4.1e-7 (enum, 9! orders and K = 2**26), 2.3e-8
-#: (outcomes) and 3.8e-8 (mc, 2**20 samples).  "mc" has room for a few
-#: samples whose float32 uniform lands on the other side of a float32 CDF
-#: entry than in float64: each moves the mean by about 1/n_samples.
-CHIP_RTOL = {"enum": 1e-6, "outcomes": 1e-6, "mc": 1e-5}
+#: errors seen were 4.1e-7 (enum, 9! orders and K = 2**26) and 3.8e-8
+#: (mc, 2**20 samples).  "mc" has room for a few samples whose float32
+#: uniform lands on the other side of a float32 CDF entry than in
+#: float64: each moves the mean by about 1/n_samples.
+CHIP_RTOL = {"enum": 1e-6, "mc": 1e-5}
 
 #: Combination indices per XLA scan tile (bounded-memory streaming).
 XLA_TILE = 1 << 15
@@ -109,26 +109,24 @@ def _order_batch(n_orders: int, tile: int, n: int) -> int:
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(
-    jax.jit, static_argnames=("strides", "radix", "k_total", "tile")
-)
-def _enum_xla(sizes, probs, orders, *, strides, radix, k_total, tile):
-    """Exact fused evaluation; ``strides``/``radix`` are static tuples so
-    the mixed-radix decode lowers to constant div/mod chains."""
-    n = orders.shape[1]
-    strides_a = jnp.asarray(strides, jnp.int32)[None, :]
-    radix_a = jnp.asarray(radix, jnp.int32)[None, :]
-    job_ids = jnp.arange(n, dtype=jnp.int32)[None, :]
-    n_tiles = max(1, -(-k_total // tile))
+def _score_tiles(sizes, radix, orders, total, tile, decode):
+    """Eqs. (7)+(9) for every order, over ``total`` combinations or samples.
+
+    A ``lax.scan`` over tiles of ``tile`` indices ``k``: ``decode(k)``
+    gives their ``(T, N)`` stop stages and ``(T,)`` weights, and indices
+    past ``total`` weigh zero.  The gathers and the success count are
+    shared across the orders; only the completion-time prefix sums are
+    per order.
+    """
+    job_ids = jnp.arange(orders.shape[1], dtype=jnp.int32)[None, :]
 
     def tile_fn(carry, t):
         e_succ, e_all = carry
         k = t * tile + jnp.arange(tile, dtype=jnp.int32)
-        valid = k < k_total
-        s = (k[:, None] // strides_a) % radix_a  # (T, N) on-the-fly decode
-        w = jnp.prod(probs[job_ids, s], axis=1) * valid  # Eq. (8)
+        s, w = decode(k)
+        w = w * (k < total)
         d = sizes[job_ids, s]  # (T, N) realized durations
-        succ = s == radix_a - 1
+        succ = s == radix - 1
         cnt = jnp.sum(succ, axis=1)  # order-invariant success count
         inv_cnt = jnp.where(cnt > 0, 1.0 / jnp.maximum(cnt, 1), 0.0)
 
@@ -144,77 +142,47 @@ def _enum_xla(sizes, probs, orders, *, strides, radix, k_total, tile):
         return (e_succ + des, e_all + dea), None
 
     zeros = jnp.zeros((orders.shape[0],), sizes.dtype)
+    n_tiles = max(1, -(-total // tile))
     (e_succ, e_all), _ = jax.lax.scan(
         tile_fn, (zeros, zeros), jnp.arange(n_tiles, dtype=jnp.int32)
     )
     return e_succ, e_all
+
+
+@functools.partial(
+    jax.jit, static_argnames=("strides", "radix", "k_total", "tile")
+)
+def _enum_xla(sizes, probs, orders, *, strides, radix, k_total, tile):
+    """Exact fused evaluation; ``strides``/``radix`` are static tuples so
+    the mixed-radix decode lowers to constant div/mod chains."""
+    strides_a = jnp.asarray(strides, jnp.int32)[None, :]
+    radix_a = jnp.asarray(radix, jnp.int32)[None, :]
+    job_ids = jnp.arange(orders.shape[1], dtype=jnp.int32)[None, :]
+
+    def decode(k):
+        s = (k[:, None] // strides_a) % radix_a  # (T, N) on-the-fly decode
+        return s, jnp.prod(probs[job_ids, s], axis=1)  # Eq. (8)
+
+    return _score_tiles(sizes, radix_a, orders, k_total, tile, decode)
 
 
 @functools.partial(jax.jit, static_argnames=("n_samples", "tile"))
 def _mc_xla(sizes, cdf, num_stages, orders, key2, *, n_samples, tile):
     """Streamed-MC fused evaluation: per-tile Threefry outcome generation
-    with the same inverse-CDF count as the host replay, then the shared
-    prefix-sum reduction.  ``key2`` is a (2,) uint32 array (traced, so
-    sweeps over seeds do not recompile)."""
+    with the same inverse-CDF count as the host replay.  ``key2`` is a
+    (2,) uint32 array (traced, so sweeps over seeds do not recompile)."""
     n = orders.shape[1]
-    job_ids = jnp.arange(n, dtype=jnp.int32)[None, :]
-    n_tiles = max(1, -(-n_samples // tile))
-    x1 = jnp.broadcast_to(job_ids, (tile, n)).astype(jnp.uint32)
+    radix = num_stages[None, :]
+    x1 = jnp.broadcast_to(jnp.arange(n, dtype=jnp.uint32)[None, :], (tile, n))
 
-    def tile_fn(carry, t):
-        e_succ, e_all = carry
-        k = t * tile + jnp.arange(tile, dtype=jnp.int32)
+    def decode(k):
         x0 = jnp.broadcast_to(k[:, None], (tile, n)).astype(jnp.uint32)
         bits, _ = rng.threefry2x32(jnp, (key2[0], key2[1]), x0, x1)
         u = rng.uniform_from_bits(bits, sizes.dtype)
-        s = jnp.minimum(
-            jnp.sum(u[:, :, None] >= cdf[None, :, :], axis=2).astype(jnp.int32),
-            num_stages[None, :] - 1,
-        )
-        w = (k < n_samples).astype(sizes.dtype) * (1.0 / n_samples)
-        d = sizes[job_ids, s]  # (T, N) realized durations
-        succ = s == num_stages[None, :] - 1
-        cnt = jnp.sum(succ, axis=1)
-        inv_cnt = jnp.where(cnt > 0, 1.0 / jnp.maximum(cnt, 1), 0.0)
+        s = jnp.sum(u[:, :, None] >= cdf[None, :, :], axis=2).astype(jnp.int32)
+        return jnp.minimum(s, radix - 1), jnp.full((tile,), 1.0 / n_samples, sizes.dtype)
 
-        def per_order(order):
-            tcum = jnp.cumsum(jnp.take(d, order, axis=1), axis=1)
-            tot = jnp.sum(tcum * jnp.take(succ, order, axis=1), axis=1)
-            return (
-                jnp.dot(w, tot * inv_cnt),
-                jnp.dot(w, jnp.mean(tcum, axis=1)),
-            )
-
-        des, dea = jax.vmap(per_order)(orders)
-        return (e_succ + des, e_all + dea), None
-
-    zeros = jnp.zeros((orders.shape[0],), sizes.dtype)
-    (e_succ, e_all), _ = jax.lax.scan(
-        tile_fn, (zeros, zeros), jnp.arange(n_tiles, dtype=jnp.int32)
-    )
-    return e_succ, e_all
-
-
-@jax.jit
-def _outcomes_xla(sizes, num_stages, outcomes, weights, orders):
-    """Fused evaluation over an explicit outcome matrix: the duration and
-    success gathers happen on-device instead of as host fancy-indexing."""
-    n = orders.shape[1]
-    job_ids = jnp.arange(n, dtype=jnp.int32)[None, :]
-    d = sizes[job_ids, outcomes]  # (K, N)
-    succ = outcomes == num_stages[None, :] - 1
-    cnt = jnp.sum(succ, axis=1)
-    inv_cnt = jnp.where(cnt > 0, 1.0 / jnp.maximum(cnt, 1), 0.0)
-
-    def per_order(order):
-        tcum = jnp.cumsum(jnp.take(d, order, axis=1), axis=1)
-        tot = jnp.sum(tcum * jnp.take(succ, order, axis=1), axis=1)
-        return (
-            jnp.dot(weights, tot * inv_cnt),
-            jnp.dot(weights, jnp.mean(tcum, axis=1)),
-        )
-
-    return jax.vmap(per_order)(orders)
+    return _score_tiles(sizes, radix, orders, n_samples, tile, decode)
 
 
 # ---------------------------------------------------------------------------
@@ -225,19 +193,6 @@ def _outcomes_xla(sizes, num_stages, outcomes, weights, orders):
 def _permuted(arrs, orders_b):
     """Take the job axis of each array along every order in the batch."""
     return [np.take(a, orders_b, axis=0) for a in arrs]
-
-
-def _tile_outcomes(outcomes, weights):
-    """(K, N) -> (N, KT, SUBLANES, LANES) stage tiles + zero-padded weights."""
-    k_total, n = outcomes.shape
-    bk = K.BLOCK_COMBOS
-    nkt = max(1, -(-k_total // bk))
-    pad = nkt * bk - k_total
-    oc = np.pad(outcomes.astype(np.int32), ((0, pad), (0, 0)))
-    wt = np.pad(np.asarray(weights), (0, pad))
-    oc_t = oc.T.reshape(n, nkt, K.SUBLANES, K.LANES)
-    wt_t = wt.reshape(nkt, K.SUBLANES, K.LANES)
-    return oc_t, wt_t
 
 
 # ---------------------------------------------------------------------------
@@ -251,8 +206,6 @@ def sojourn_eval(
     num_stages: np.ndarray,  # (N,) stage counts
     orders: np.ndarray,  # (P, N) static orders
     *,
-    outcomes: np.ndarray | None = None,  # optional (K, N) explicit outcomes
-    weights: np.ndarray | None = None,  # (K,) weights (required with outcomes)
     samples: tuple[int, int] | None = None,  # (seed, n_samples) streamed MC
     impl: Impl = "auto",
 ) -> tuple[np.ndarray, np.ndarray]:
@@ -266,9 +219,7 @@ def sojourn_eval(
     :func:`run_batches`.
     """
     impl = resolve_impl(impl)
-    mode = "mc" if samples is not None else (
-        "enum" if outcomes is None else "outcomes"
-    )
+    mode = "mc" if samples is not None else "enum"
     with (
         profiling.span(f"sojourn_eval.static.{mode}.{impl}"),
         profiling.phases(f"op_phase.static.{impl}", "prep") as phase,
@@ -276,8 +227,7 @@ def sojourn_eval(
     ):
         return _sojourn_eval(
             sizes, probs, num_stages, orders,
-            outcomes=outcomes, weights=weights, samples=samples, impl=impl,
-            phase=phase,
+            samples=samples, impl=impl, phase=phase,
         )
 
 
@@ -316,10 +266,8 @@ def run_batches(phase, items, batch, fdt, shared, per_batch, call):
 
 def _sojourn_eval(
     sizes, probs, num_stages, orders, *,
-    outcomes=None, weights=None, samples=None, impl="xla", phase,
+    samples=None, impl="xla", phase,
 ) -> tuple[np.ndarray, np.ndarray]:
-    if samples is not None and outcomes is not None:
-        raise ValueError("samples= and outcomes= are mutually exclusive")
     sizes = np.asarray(sizes)
     probs = np.asarray(probs)
     num_stages = np.asarray(num_stages, dtype=np.int64)
@@ -357,7 +305,7 @@ def _sojourn_eval(
 
             def call(sz, cd, rx, ob, k):
                 return K.sojourn_mc(sz, cd, rx, ob, k, n_samples, interpret=interpret)
-    elif outcomes is None:
+    else:
         k_total = int(np.prod(num_stages, dtype=np.int64))
         tile = min(XLA_TILE, max(K.BLOCK_COMBOS, 1 << (k_total - 1).bit_length()))
         pb = _order_batch(orders.shape[0], tile, n)
@@ -393,25 +341,4 @@ def _sojourn_eval(
                 profiling.count("sojourn_enum.order_blocks", blocks)
                 profiling.count("sojourn_enum.grid_steps", blocks * tiles)
                 return K.sojourn_enum(tables, ints, ob, k_total, interpret=interpret)
-    else:
-        if weights is None:
-            raise ValueError("explicit outcomes need weights")
-        outcomes = np.asarray(outcomes, dtype=np.int32)
-        pb = _order_batch(orders.shape[0], outcomes.shape[0], n)
-        if impl == "xla":
-            shared = [sizes, radix, outcomes, np.asarray(weights)]
-
-            def per_batch(ob):
-                return [ob]
-
-            def call(sz, rx, oc, wt, ob):
-                return _outcomes_xla(sz, rx, oc, wt, ob)
-        else:
-            shared = list(_tile_outcomes(outcomes, weights))
-
-            def per_batch(ob):
-                return [*_permuted([sizes, radix], ob), ob]
-
-            def call(oc, wt, sz, rx, ob):
-                return K.sojourn_outcomes(sz, rx, ob, oc, wt, interpret=interpret)
     return run_batches(phase, orders, pb, fdt, shared, per_batch, call)

@@ -20,7 +20,9 @@ a dense ``(S, N)`` outcome table: the streamed kernels decode the same
 ``(seed, sample, job)`` counters in-tile, so evaluating this table with
 ``ref_sojourn`` / ``ref_sojourn_dynamic`` is the oracle for the
 ``samples=`` mode, and the table itself matches the in-kernel stream
-bitwise.
+bitwise.  The oracles' ``outcomes=`` / ``weights=`` arguments are the
+only place an explicit outcome table is scored: the ops themselves
+take no table, they enumerate or stream.
 """
 
 from __future__ import annotations

@@ -4,8 +4,8 @@ One Threefry-2x32 block (Salmon et al., Random123; the same generator
 family JAX's default PRNG uses) written against a generic array
 namespace ``xp`` so the *identical* integer arithmetic runs
 
-* host-side in NumPy (the ``ref.py`` oracle replay and
-  ``evaluator.sample_outcomes``-style parity tests),
+* host-side in NumPy (the ``ref.py`` oracle replay the parity tests
+  check the streamed mode against),
 * in the jitted XLA fallbacks (``jnp`` under ``lax.scan``), and
 * inside the Pallas tiles (``jnp`` on ``(SUBLANES, LANES)`` registers —
   only elementwise uint32 add/xor/shift, all Mosaic-supported).
@@ -22,8 +22,8 @@ Counter layout: ``x0 = sample_index``, ``x1 = job_index`` (each a full
 two 31-bit halves of a user seed (31 bits so the words round-trip
 through int32 SMEM scalars on TPU).  The first output word, scaled by
 ``2**-32``, is the per-(sample, job) uniform; an inverse-CDF count over
-the padded per-job CDF turns it into a stop-stage outcome exactly as
-:func:`repro.core.evaluator.sample_outcomes` does.
+the padded per-job CDF turns it into a stop-stage outcome
+(:func:`host_outcomes`).
 """
 
 from __future__ import annotations

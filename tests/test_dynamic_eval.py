@@ -28,12 +28,15 @@ import pytest
 from repro.core import evaluator, policies, simulator
 from repro.core.jobs import JobSpec, generate_workload
 from repro.kernels.sojourn_eval import sojourn_eval_dynamic
-from repro.kernels.sojourn_eval.ref import ref_sojourn_dynamic
+from repro.kernels.sojourn_eval.ref import ref_mc_outcomes, ref_sojourn_dynamic
 from repro.runtime import x64
 
 RTOL = 1e-9
 IMPLS = ("xla", "interpret")
 POLICIES = ("sr", "serpt")
+#: The op's two sources of outcomes: exact enumeration and streamed draws.
+SOURCES = ("enum", "mc")
+MC_SEED = 0x5EED_CAFE
 
 
 def _relerr(a, b):
@@ -47,11 +50,12 @@ def _tables(jobs, policy):
     return probs, durs, num_stages, idx
 
 
-def fused(jobs, policy, impl, n_servers=1):
+def fused(jobs, policy, impl, n_servers=1, samples=None):
     probs, durs, num_stages, idx = _tables(jobs, policy)
     with x64():
         es, ea = sojourn_eval_dynamic(
-            probs, durs, num_stages, idx, n_servers=n_servers, impl=impl
+            probs, durs, num_stages, idx, samples=samples, n_servers=n_servers,
+            impl=impl,
         )
     return float(es[0]), float(ea[0])
 
@@ -74,9 +78,12 @@ def seed_batch(jobs, policy):
         )
 
 
-def oracle(jobs, policy, n_servers=1):
+def oracle(jobs, policy, n_servers=1, samples=None):
+    """The dense oracle over every combination, or over the host replay of
+    the streamed draws ``samples=(seed, n_samples)``."""
     probs, durs, num_stages, idx = _tables(jobs, policy)
-    return ref_sojourn_dynamic(probs, durs, num_stages, idx, n_servers=n_servers)
+    table = () if samples is None else ref_mc_outcomes(probs, num_stages, *samples)
+    return ref_sojourn_dynamic(probs, durs, num_stages, idx, *table, n_servers=n_servers)
 
 
 def des_exhaustive(jobs, policy, n_servers=1):
@@ -179,8 +186,6 @@ def test_servers_exceed_jobs_matches_parallel_service(policy):
 def test_multi_server_streamed_mc_matches_host_replay(n_servers):
     """samples= mode at W>1: the streamed outcomes evaluated in-kernel
     must match the host Threefry replay fed to the W-server oracle."""
-    from repro.kernels.sojourn_eval.ref import ref_mc_outcomes
-
     rng = np.random.default_rng(23)
     jobs = generate_workload(rng, 5, num_stages=2)
     probs, durs, num_stages, idx = _tables(jobs, "sr")
@@ -198,16 +203,6 @@ def test_multi_server_streamed_mc_matches_host_replay(n_servers):
             )
             assert _relerr(float(es[0]), want_es) < RTOL, impl
             assert _relerr(float(ea[0]), want_ea) < RTOL, impl
-
-
-def test_materialized_tier_rejects_multi_server():
-    rng = np.random.default_rng(31)
-    jobs = generate_workload(rng, 4)
-    outcomes, weights = evaluator.enumerate_outcomes(jobs)
-    with pytest.raises(ValueError, match="single-server"):
-        evaluator.expected_sojourn_dynamic(
-            jobs, "sr", outcomes=outcomes, weights=weights, n_servers=2
-        )
 
 
 # ---------------------------------------------------------------------------
@@ -253,26 +248,32 @@ def test_fixed_priority_table_matches_static_order(impl):
     np.testing.assert_allclose(float(ea[0]), float(want[1]), rtol=RTOL)
 
 
-def test_multi_tile_grid_and_tail_masking():
-    """K = 3^7 = 2187 spans 3 combination tiles with a ragged tail."""
+@pytest.mark.parametrize("source", SOURCES)
+def test_multi_tile_grid_and_tail_masking(source):
+    """K = 3^7 = 2187 spans 3 combination tiles with a ragged tail; the
+    streamed case draws as many samples, over 3 sample tiles."""
     rng = np.random.default_rng(11)
     jobs = generate_workload(rng, 7, num_stages=3)
-    ref_es, ref_ea = oracle(jobs, "serpt")
+    samples = (MC_SEED, 3**7) if source == "mc" else None
+    ref_es, ref_ea = oracle(jobs, "serpt", samples=samples)
     for impl in IMPLS:
-        es, ea = fused(jobs, "serpt", impl)
+        es, ea = fused(jobs, "serpt", impl, samples=samples)
         assert _relerr(es, ref_es) < RTOL, impl
         assert _relerr(ea, ref_ea) < RTOL, impl
 
 
-def test_n1_single_job():
+@pytest.mark.parametrize("source", SOURCES)
+def test_n1_single_job(source):
     jobs = [JobSpec(sizes=np.array([1.0, 3.0]), probs=np.array([0.4, 0.6]))]
-    ref_es, ref_ea = oracle(jobs, "sr")
+    samples = (MC_SEED, 1000) if source == "mc" else None
+    ref_es, ref_ea = oracle(jobs, "sr", samples=samples)
     for impl in IMPLS:
-        es, ea = fused(jobs, "sr", impl)
+        es, ea = fused(jobs, "sr", impl, samples=samples)
         assert _relerr(es, ref_es) < RTOL
         assert _relerr(ea, ref_ea) < RTOL
-    # single job: E[sojourn | success] is its full size
-    np.testing.assert_allclose(ref_es, 0.6 * 3.0, rtol=RTOL)
+    if source == "enum":
+        # single job: E[sojourn | success] is its full size
+        np.testing.assert_allclose(ref_es, 0.6 * 3.0, rtol=RTOL)
 
 
 # ---------------------------------------------------------------------------
@@ -292,8 +293,7 @@ def test_dynamic_exact_beyond_materialization_cap():
     val = evaluator.expected_sojourn_dynamic(jobs, "sr")
     assert np.isfinite(val) and val > 0
     # cross-check against an independent MC estimate (loose tolerance)
-    mc_o, mc_w = evaluator.sample_outcomes(jobs, 20_000, rng)
-    mc = evaluator.expected_sojourn_dynamic(jobs, "sr", outcomes=mc_o, weights=mc_w)
+    mc = evaluator.expected_sojourn_dynamic(jobs, "sr", samples=(MC_SEED, 20_000))
     assert abs(mc - val) / val < 0.05
 
 

@@ -7,6 +7,7 @@ import pytest
 
 from repro.core import evaluator, policies
 from repro.core.jobs import JobSpec, generate_workload
+from repro.kernels.sojourn_eval import rng as kernel_rng
 from repro.kernels.sojourn_eval.ref import ref_sojourn
 from repro.obs import get_registry, profiling
 
@@ -167,9 +168,9 @@ def test_monte_carlo_approaches_exact():
     rng = np.random.default_rng(8)
     jobs = generate_workload(rng, 6, 2, 1)
     exact = evaluator.evaluate(jobs, "rank")
-    outcomes, weights = evaluator.sample_outcomes(jobs, 30_000, rng)
+    seed = int(rng.integers(0, kernel_rng.MAX_SEED))
     mc = evaluator.expected_sojourn_static(
-        jobs, policies.rank_order(jobs), outcomes, weights
+        jobs, policies.rank_order(jobs), samples=(seed, 30_000)
     )
     assert mc == pytest.approx(exact, rel=0.05)
 

@@ -8,7 +8,7 @@ import itertools
 import numpy as np
 import pytest
 
-from repro.core import evaluator, policies
+from repro.core import policies
 from repro.core.jobs import generate_workload
 from repro.kernels.sojourn_eval import ops, sojourn_eval, sojourn_eval_dynamic
 from repro.obs import MetricsRegistry, get_registry, profiling
@@ -73,11 +73,7 @@ def _static_case(mode):
     jobs = _jobs()
     sizes, probs, num_stages = policies.padded_arrays(jobs)
     orders = np.array(list(itertools.permutations(range(len(jobs)))), np.int32)
-    kwargs = {}
-    if mode == "outcomes":
-        kwargs["outcomes"], kwargs["weights"] = evaluator.enumerate_outcomes(jobs)
-    elif mode == "mc":
-        kwargs["samples"] = (2**40 + 3, 300)
+    kwargs = {"samples": (2**40 + 3, 300)} if mode == "mc" else {}
     return (sizes, probs, num_stages, orders), kwargs
 
 
@@ -89,7 +85,7 @@ def _dynamic_case(mode):
     return (probs, policies.stage_durations(jobs), num_stages, tables), kwargs
 
 
-CASES = [("static", m, i) for m in ("enum", "outcomes", "mc") for i in ("xla", "interpret")]
+CASES = [("static", m, i) for m in ("enum", "mc") for i in ("xla", "interpret")]
 CASES += [("dynamic", m, i) for m in ("enum", "mc") for i in ("xla", "interpret")]
 
 

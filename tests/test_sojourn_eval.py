@@ -27,6 +27,10 @@ from repro.runtime import x64
 
 RTOL = 1e-9
 IMPLS = ("xla", "interpret")
+#: The op's two sources of outcomes: exact enumeration and streamed draws.
+SOURCES = ("enum", "mc")
+MC_SEED = 0x5EED_CAFE
+MC_SAMPLES = 1000  # the 1,024-sample Pallas tile, partly masked
 
 
 def _orders(n, rng, p=6):
@@ -42,6 +46,21 @@ def _ref(jobs, orders, outcomes=None, weights=None):
 
 def _relerr(a, b):
     return np.max(np.abs(a - b) / np.maximum(np.abs(b), 1e-300))
+
+
+def _source_parity(jobs, orders, source, impl, n_samples=MC_SAMPLES):
+    """The fused op on ``source`` against the dense oracle on the same
+    outcomes: every combination, or the host replay of the streamed draws."""
+    samples = (MC_SEED, n_samples) if source == "mc" else None
+    es, ea = sojourn_eval_x64(jobs, orders, samples=samples, impl=impl)
+    table = ()
+    if samples is not None:
+        _, probs, num_stages = policies.padded_arrays(jobs)
+        table = ref_mc_outcomes(probs, num_stages, *samples)
+    r_es, r_ea = _ref(jobs, orders, *table)
+    assert _relerr(es, r_es) < RTOL
+    assert _relerr(ea, r_ea) < RTOL
+    return es, ea
 
 
 # ---------------------------------------------------------------------------
@@ -72,18 +91,6 @@ def test_enumerate_outcomes_vectorized_weights_sum_to_one():
     np.testing.assert_allclose(weights[k], expect, rtol=1e-12)
 
 
-def test_sample_outcomes_vectorized_matches_distribution():
-    rng = np.random.default_rng(1)
-    jobs = generate_workload(rng, 4, num_stages=3)
-    outcomes, weights = evaluator.sample_outcomes(jobs, 200_000, rng)
-    assert outcomes.max() < 3 and outcomes.min() >= 0
-    np.testing.assert_allclose(weights.sum(), 1.0, rtol=1e-12)
-    _, probs, _ = policies.padded_arrays(jobs)
-    for i in range(4):
-        freq = np.bincount(outcomes[:, i], minlength=3) / len(outcomes)
-        np.testing.assert_allclose(freq, probs[i, :3], atol=5e-3)
-
-
 # ---------------------------------------------------------------------------
 # Fused op vs dense oracle
 # ---------------------------------------------------------------------------
@@ -101,8 +108,9 @@ def test_enum_parity_vs_ref(impl, n):
     assert _relerr(ea, r_ea) < RTOL
 
 
+@pytest.mark.parametrize("source", SOURCES)
 @pytest.mark.parametrize("impl", IMPLS)
-def test_enum_parity_ragged_stages(impl):
+def test_enum_parity_ragged_stages(impl, source):
     """Jobs with different checkpoint counts (padded M axis exercised)."""
     rng = np.random.default_rng(7)
     jobs = [
@@ -117,10 +125,7 @@ def test_enum_parity_ragged_stages(impl):
         ),
     ]
     orders = _orders(4, rng)
-    es, ea = sojourn_eval_x64(jobs, orders, impl=impl)
-    r_es, r_ea = _ref(jobs, orders)
-    assert _relerr(es, r_es) < RTOL
-    assert _relerr(ea, r_ea) < RTOL
+    _source_parity(jobs, orders, source, impl)
 
 
 @pytest.mark.parametrize("impl", IMPLS)
@@ -135,49 +140,36 @@ def test_single_order_matches_batched(impl):
         np.testing.assert_allclose(single, batched[i], rtol=RTOL)
 
 
-@pytest.mark.parametrize("impl", IMPLS)
-def test_outcomes_mode_parity(impl):
-    """Explicit outcome tables (MC samples) through the fused op."""
-    rng = np.random.default_rng(5)
-    jobs = generate_workload(rng, 6, num_stages=3)
-    orders = _orders(6, rng)
-    outcomes, weights = evaluator.sample_outcomes(jobs, 3000, rng)
-    es, ea = sojourn_eval_x64(jobs, orders, outcomes=outcomes, weights=weights, impl=impl)
-    r_es, r_ea = _ref(jobs, orders, outcomes, weights)
-    assert _relerr(es, r_es) < RTOL
-    assert _relerr(ea, r_ea) < RTOL
-
-
 # ---------------------------------------------------------------------------
 # Edge cases (interpret mode so the Pallas kernels run in CI)
 # ---------------------------------------------------------------------------
 
 
-def test_enum_parity_partial_tail_tile():
+@pytest.mark.parametrize("source", SOURCES)
+def test_enum_parity_partial_tail_tile(source):
     """K = 3^7 = 2187: two full (8x128) combination tiles plus a ragged
-    tail that must be weight-masked, not evaluated."""
+    tail that must be weight-masked, not evaluated.  The streamed case
+    draws as many samples: two full sample tiles and a masked tail."""
     rng = np.random.default_rng(23)
     jobs = generate_workload(rng, 7, num_stages=3)
     orders = _orders(7, rng, p=3)
-    es, ea = sojourn_eval_x64(jobs, orders, impl="interpret")
-    r_es, r_ea = _ref(jobs, orders)
-    assert _relerr(es, r_es) < RTOL
-    assert _relerr(ea, r_ea) < RTOL
+    _source_parity(jobs, orders, source, "interpret", n_samples=3**7)
 
 
-def test_enum_parity_n1():
+@pytest.mark.parametrize("source", SOURCES)
+def test_enum_parity_n1(source):
     """A single job: the only 'order' is the identity."""
     jobs = [JobSpec(sizes=np.array([1.0, 3.0]), probs=np.array([0.4, 0.6]))]
     orders = np.zeros((1, 1), dtype=np.int32)
-    es, ea = sojourn_eval_x64(jobs, orders, impl="interpret")
-    r_es, r_ea = _ref(jobs, orders)
-    assert _relerr(es, r_es) < RTOL
-    # E[sojourn | success] = p_succ * full size
-    np.testing.assert_allclose(es[0], 0.6 * 3.0, rtol=RTOL)
-    np.testing.assert_allclose(ea[0], 0.4 * 1.0 + 0.6 * 3.0, rtol=RTOL)
+    es, ea = _source_parity(jobs, orders, source, "interpret")
+    if source == "enum":
+        # E[sojourn | success] = p_succ * full size
+        np.testing.assert_allclose(es[0], 0.6 * 3.0, rtol=RTOL)
+        np.testing.assert_allclose(ea[0], 0.4 * 1.0 + 0.6 * 3.0, rtol=RTOL)
 
 
-def test_enum_parity_single_stage_jobs():
+@pytest.mark.parametrize("source", SOURCES)
+def test_enum_parity_single_stage_jobs(source):
     """Always-successful single-checkpoint jobs: K = 1 combination, every
     job succeeds, and the padded stage axis degenerates to M = 1."""
     jobs = [
@@ -186,19 +178,18 @@ def test_enum_parity_single_stage_jobs():
         JobSpec(sizes=np.array([1.25]), probs=np.array([1.0])),
     ]
     orders = np.array([[0, 1, 2], [2, 1, 0]], dtype=np.int32)
-    es, ea = sojourn_eval_x64(jobs, orders, impl="interpret")
-    r_es, r_ea = _ref(jobs, orders)
-    assert _relerr(es, r_es) < RTOL
-    assert _relerr(ea, r_ea) < RTOL
-    # deterministic: mean of the prefix sums
+    es, _ = _source_parity(jobs, orders, source, "interpret")
+    # deterministic, whatever the source: mean of the prefix sums
     np.testing.assert_allclose(es[0], np.mean([2.0, 2.5, 3.75]), rtol=RTOL)
 
 
+@pytest.mark.parametrize("source", SOURCES)
 @pytest.mark.parametrize("impl", IMPLS)
-def test_enum_parity_zero_probability_row(impl):
+def test_enum_parity_zero_probability_row(impl, source):
     """A job that can never stop early (p = 0 at an interior checkpoint):
     combinations selecting that row carry zero weight and must not
-    contribute, even though their durations are still decoded."""
+    contribute, even though their durations are still decoded; the
+    streamed draws never select it."""
     rng = np.random.default_rng(29)
     jobs = [
         JobSpec(sizes=np.array([1.0, 2.0]), probs=np.array([0.0, 1.0])),
@@ -206,10 +197,7 @@ def test_enum_parity_zero_probability_row(impl):
         JobSpec(sizes=np.array([1.0, 4.0]), probs=np.array([0.3, 0.7])),
     ]
     orders = _orders(3, rng)
-    es, ea = sojourn_eval_x64(jobs, orders, impl=impl)
-    r_es, r_ea = _ref(jobs, orders)
-    assert _relerr(es, r_es) < RTOL
-    assert _relerr(ea, r_ea) < RTOL
+    _source_parity(jobs, orders, source, impl)
 
 
 def _random_orders(n, p, seed):
@@ -311,8 +299,7 @@ def test_exact_beyond_materialization_cap():
     val = evaluator.expected_sojourn_static(jobs, order)
     assert np.isfinite(val) and val > 0
     # cross-check against an independent MC estimate (loose tolerance)
-    mc_o, mc_w = evaluator.sample_outcomes(jobs, 20_000, rng)
-    mc = evaluator.expected_sojourn_static(jobs, order, outcomes=mc_o, weights=mc_w)
+    mc = evaluator.expected_sojourn_static(jobs, order, samples=(MC_SEED, 20_000))
     assert abs(mc - val) / val < 0.05
 
 
@@ -362,12 +349,12 @@ def test_workload_cache_hits_and_readonly():
     assert policies.index_table(other, "sr") is not a
 
 
-def sojourn_eval_x64(jobs, orders, outcomes=None, weights=None, impl="xla"):
+def sojourn_eval_x64(jobs, orders, samples=None, impl="xla"):
     sizes, probs, num_stages = policies.padded_arrays(jobs)
     with x64():
         es, ea = sojourn_eval(
             sizes, probs, num_stages, np.asarray(orders, np.int32),
-            outcomes=outcomes, weights=weights, impl=impl,
+            samples=samples, impl=impl,
         )
     return np.asarray(es), np.asarray(ea)
 
@@ -388,18 +375,16 @@ def _f32_workload():
     return jobs, sizes, probs, num_stages
 
 
-@pytest.mark.parametrize("mode", ("enum", "outcomes", "mc"))
+@pytest.mark.parametrize("mode", SOURCES)
 def test_static_float32_kernels_within_chip_tolerance(mode):
     jobs, sizes, probs, num_stages = _f32_workload()
     orders = _orders(8, np.random.default_rng(29))
     outcomes = weights = None
-    if mode != "enum":
+    if mode == "mc":
         outcomes, weights = ref_mc_outcomes(probs, num_stages, F32_SEED, F32_SAMPLES)
     # Outside the x64 scope the interpreted kernels compute in float32.
     es, ea = sojourn_eval(
         sizes, probs, num_stages, orders,
-        outcomes=outcomes if mode == "outcomes" else None,
-        weights=weights if mode == "outcomes" else None,
         samples=(F32_SEED, F32_SAMPLES) if mode == "mc" else None,
         impl="interpret",
     )

@@ -28,7 +28,11 @@ import pytest
 from repro.core import evaluator, policies
 from repro.core.jobs import JobSpec, generate_workload
 from repro.kernels.sojourn_eval import rng, sojourn_eval
-from repro.kernels.sojourn_eval.ref import ref_mc_outcomes
+from repro.kernels.sojourn_eval.ref import (
+    ref_mc_outcomes,
+    ref_sojourn,
+    ref_sojourn_dynamic,
+)
 from repro.runtime import x64
 
 IMPLS = ("xla", "interpret")
@@ -38,6 +42,16 @@ RTOL = 1e-9
 
 def _padded(jobs):
     return policies.padded_arrays(jobs)
+
+
+def _replayed_dynamic(jobs, policy, outcomes, weights):
+    """The dense dynamic oracle's E[sojourn successful] on a replayed table."""
+    _, probs, num_stages = _padded(jobs)
+    es, _ = ref_sojourn_dynamic(
+        probs, policies.stage_durations(jobs), num_stages,
+        policies.index_table(jobs, policy), outcomes, weights,
+    )
+    return es
 
 
 # ---------------------------------------------------------------------------
@@ -108,11 +122,8 @@ def test_streamed_static_matches_host_replay(impl):
     orders = np.stack([np.arange(5), np.argsort(-np.arange(5))]).astype(np.int32)
     n_samples = 2048
     outcomes, weights = ref_mc_outcomes(probs, num_stages, SEED, n_samples)
+    want = ref_sojourn(sizes, probs, num_stages, orders, outcomes, weights)
     with x64():
-        want = sojourn_eval(
-            sizes, probs, num_stages, orders,
-            outcomes=outcomes, weights=weights, impl="xla",
-        )
         got = sojourn_eval(
             sizes, probs, num_stages, orders,
             samples=(SEED, n_samples), impl=impl,
@@ -130,9 +141,7 @@ def test_streamed_dynamic_matches_host_replay(impl):
     n_samples = 1024
     outcomes, weights = ref_mc_outcomes(probs, num_stages, SEED, n_samples)
     for policy in ("sr", "serpt"):
-        want = evaluator.expected_sojourn_dynamic(
-            jobs, policy, outcomes=outcomes, weights=weights
-        )
+        want = _replayed_dynamic(jobs, policy, outcomes, weights)
         got = evaluator.expected_sojourn_dynamic(
             jobs, policy, samples=(SEED, n_samples), impl=impl
         )
@@ -147,11 +156,8 @@ def test_streamed_non_pow2_sample_count_tail_masked():
     order = np.arange(4, dtype=np.int32)[None]
     n_samples = 1000  # not a multiple of any tile shape
     outcomes, weights = ref_mc_outcomes(probs, num_stages, SEED, n_samples)
+    want = ref_sojourn(sizes, probs, num_stages, order, outcomes, weights)
     with x64():
-        want = sojourn_eval(
-            sizes, probs, num_stages, order,
-            outcomes=outcomes, weights=weights, impl="xla",
-        )
         for impl in IMPLS:
             got = sojourn_eval(
                 sizes, probs, num_stages, order,
@@ -199,9 +205,7 @@ def test_streamed_dynamic_within_clt_of_exact():
         )
         # conservative sigma bound: per-sample values live in [0, sum durs]
         outcomes, weights = ref_mc_outcomes(probs, num_stages, SEED, n_samples)
-        mc_table = evaluator.expected_sojourn_dynamic(
-            jobs, policy, outcomes=outcomes, weights=weights
-        )
+        mc_table = _replayed_dynamic(jobs, policy, outcomes, weights)
         # the streamed estimate IS the table estimate (parity), and the
         # table estimate is an unbiased S-sample MC mean of the exact value
         np.testing.assert_allclose(est, mc_table, rtol=RTOL)
@@ -225,18 +229,14 @@ def test_common_random_numbers_across_orders_and_policies():
     outcomes, weights = ref_mc_outcomes(probs, num_stages, SEED, n_samples)
     # two different static orders against the shared replayed table
     for order in (np.arange(5), np.array([4, 2, 0, 3, 1])):
-        want = evaluator.expected_sojourn_static(
-            jobs, order, outcomes=outcomes, weights=weights
-        )
+        (want,), _ = ref_sojourn(sizes, probs, num_stages, order[None], outcomes, weights)
         got = evaluator.expected_sojourn_static(
             jobs, order, samples=(SEED, n_samples)
         )
         np.testing.assert_allclose(got, want, rtol=RTOL)
     # dynamic policies against the same table under the same seed
     for policy in ("sr", "serpt"):
-        want = evaluator.expected_sojourn_dynamic(
-            jobs, policy, outcomes=outcomes, weights=weights
-        )
+        want = _replayed_dynamic(jobs, policy, outcomes, weights)
         got = evaluator.expected_sojourn_dynamic(
             jobs, policy, samples=(SEED, n_samples)
         )
