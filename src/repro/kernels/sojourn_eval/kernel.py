@@ -20,9 +20,13 @@ in host NumPy (capping K at 2**21); these kernels never materialize it:
   from SMEM and the scratch by it, so only the per-order completion-time
   prefix sum and the two weighted sums remain per order.  Inputs: the
   job group's tables once, unpermuted (a float ``(2, N, M)`` array and
-  an int32 ``(2, N)`` one), and the orders' flat job ids.  When the
-  combinations span tiles, each order's sum is Kahan-compensated in
-  VMEM scratch across the (sequential, innermost) tile axis.
+  an int32 ``(2, N)`` one), and the orders' job ids laid out by
+  :func:`enum_blocked_orders`: each grid step sees its own block's ids
+  in SMEM and writes its block's answers to SMEM blocks, so SMEM holds
+  one block whatever the number of orders, and one call scores them
+  all.  When the combinations span tiles, each order's sum is
+  Kahan-compensated in VMEM scratch across the (sequential, innermost)
+  tile axis.
 
 * ``sojourn_mc`` — streaming Monte-Carlo: each grid tile owns
   ``BLOCK_COMBOS`` *sample indices* and generates the per-job outcome
@@ -52,6 +56,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -61,6 +66,7 @@ __all__ = [
     "sojourn_enum",
     "enum_order_block",
     "enum_grid",
+    "enum_blocked_orders",
     "sojourn_mc",
     "BLOCK_COMBOS",
     "SUBLANES",
@@ -120,10 +126,11 @@ def accumulate(kt, nkt, outs, scratch, terms):
 
 # TPU block layout.  Mosaic accepts a block only if its last two dims are
 # (8, 128)-aligned or span the whole array, and SMEM holds 1 MiB, so a
-# whole (P, N) table of per-order scalars does not fit at P = 4096.  Each
-# grid row p therefore gets its own (1, 1, N) slice of a (P, 1, N) table,
-# and writes its two scalars through a (1, 1, 1) SMEM block of a (P, 1, 1)
-# output.
+# whole (P, N) table of per-order scalars does not fit at P = 4096 (nor a
+# flat one at 8! orders).  Each grid row p therefore gets its own
+# (1, 1, n) slice of a (P, 1, n) table and writes its answers through
+# (1, 1, w) SMEM blocks of (P, 1, w) outputs: a row is one order of
+# sojourn_mc (w = 1), or one block of orders of sojourn_enum.
 
 
 def per_row_smem(n: int) -> pl.BlockSpec:
@@ -145,10 +152,11 @@ def scalar_outputs(rows: int, dtype):
 
 #: Most orders a grid step scores against one decoded combination tile.
 #: When one tile holds every combination (``K <= BLOCK_COMBOS``: OPTIMAL's
-#: batches of up to 4,096 orders at N <= 10, M = 2) an order's answers
-#: are final after its one tile.  When the combinations span tiles, each
-#: order of the block keeps its four Kahan tiles in VMEM across them,
-#: 16 KiB an order in float32.
+#: N! orders at N <= 10, M = 2) an order's answers are final after its
+#: one tile.  When the combinations span tiles, each order of the block
+#: keeps its four Kahan tiles in VMEM across them, 16 KiB an order in
+#: float32.  A block's job ids and answers are its SMEM: at N = 8, 16 KiB
+#: of ids.
 ORDER_BLOCK = 512
 ORDER_BLOCK_TILED = 32
 #: Orders scored in one trip of the order loop when one tile holds K.  With
@@ -176,12 +184,27 @@ def enum_grid(p_orders: int, k_total: int) -> tuple[int, int]:
     return pl.cdiv(p_orders, block), max(1, pl.cdiv(k_total, BLOCK_COMBOS))
 
 
+def enum_blocked_orders(orders: np.ndarray, k_total: int) -> np.ndarray:
+    """The ``(P, N)`` orders as :func:`sojourn_enum` reads them: an int32
+    ``(order blocks, 1, block * N)`` array, one row of job ids a block,
+    order-major.  The tail block is filled with copies of the last order;
+    the kernel's answers there are not the orders' and are dropped."""
+    p_orders, n = orders.shape
+    block, _ = enum_order_block(p_orders, k_total)
+    blocks, _ = enum_grid(p_orders, k_total)
+    ids = orders.astype(np.int32, copy=False)
+    if blocks * block > p_orders:  # np.pad costs tens of microseconds even when it pads nothing
+        tail = np.broadcast_to(ids[-1], (blocks * block - p_orders, n))
+        ids = np.concatenate([ids, tail])
+    return ids.reshape(blocks, 1, block * n)
+
+
 def _enum_kernel(
-    orders_ref,  # (P * N,) int32 SMEM, scalar prefetch: job ids, order-major
     tables_ref,  # (2, N, M) VMEM: cumulative sizes, stop probabilities
     ints_ref,  # (2, N) int32 SMEM: mixed-radix strides, stage counts M_j
-    succ_ref,  # (P,) SMEM out: E[sojourn | successful jobs] per order
-    all_ref,  # (P,) SMEM out: E[sojourn | all jobs] per order
+    orders_ref,  # (1, 1, B * N) int32 SMEM: this block's job ids, order-major
+    succ_ref,  # (1, 1, B) SMEM out: E[sojourn | successful jobs] per order
+    all_ref,  # (1, 1, B) SMEM out: E[sojourn | all jobs] per order
     dur_ref,  # (N, SUBLANES, LANES) VMEM scratch: realized durations
     won_ref,  # (N, SUBLANES, LANES) VMEM scratch: 1 where the job succeeds
     *acc,  # nkt > 1: four (B, SUBLANES, LANES) Kahan tiles, as accumulate's
@@ -232,13 +255,12 @@ def _enum_kernel(
 
     def score(o):
         # Only the prefix sums depend on the order: position pos serves
-        # job orders[q, pos], and t after pos steps is its completion time.
-        q = jnp.minimum(b * block + o, p_orders - 1)
+        # job orders[o, pos], and t after pos steps is its completion time.
         t = jnp.zeros((SUBLANES, LANES), dtype)
         tsum = jnp.zeros((SUBLANES, LANES), dtype)  # sum of completion times
         tot = jnp.zeros((SUBLANES, LANES), dtype)  # sum over successful jobs
         for pos in range(n):
-            job = orders_ref[q * n + pos]
+            job = orders_ref[0, 0, o * n + pos]
             t = t + dur_ref[job]
             tot = tot + won_ref[job] * t  # adds t or exactly 0
             tsum = tsum + t
@@ -249,7 +271,7 @@ def _enum_kernel(
         outs = (succ_ref, all_ref)
         if nkt == 1:
             for out, x in zip(outs, terms):
-                out[q] = jnp.sum(x)
+                out[0, 0, o] = jnp.sum(x)
             return
         for i, x in enumerate(terms):
             _kahan_add(acc[2 * i], acc[2 * i + 1], o, x)
@@ -257,11 +279,12 @@ def _enum_kernel(
         @pl.when(kt == nkt - 1)
         def _flush():
             for i, out in enumerate(outs):
-                out[q] = jnp.sum(acc[2 * i][o] - acc[2 * i + 1][o])
+                out[0, 0, o] = jnp.sum(acc[2 * i][o] - acc[2 * i + 1][o])
 
     # ``unroll`` orders a trip give the scheduler independent work.  The
     # tail block scores only the orders that exist; a trip that runs past
-    # the last order scores it again and writes the same answers.
+    # the last order scores its copies (enum_blocked_orders), whose
+    # answers are dropped.
     def trip(i, carry):
         for u in range(unroll):
             score(i * unroll + u)
@@ -271,50 +294,58 @@ def _enum_kernel(
     jax.lax.fori_loop(0, (n_valid + unroll - 1) // unroll, trip, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("k_total", "interpret"))
+@functools.partial(jax.jit, static_argnames=("k_total", "p_orders", "interpret"))
 def sojourn_enum(
     tables: jax.Array,  # (2, N, M) cumulative sizes and stop probabilities
     ints: jax.Array,  # (2, N) int32 mixed-radix strides and stage counts
-    orders: jax.Array,  # (P * N,) int32 original job ids, order-major
+    orders: jax.Array,  # (blocks, 1, block * N) int32, enum_blocked_orders
     k_total: int,
+    p_orders: int,
     *,
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
-    """Exact (E[sojourn successful], E[sojourn all]) per order, fused.
+    """Exact (E[sojourn successful], E[sojourn all]) of ``p_orders`` orders,
+    fused, as two ``(blocks, 1, block)`` arrays: order ``q``'s answers are
+    at flat index ``q``, and those past ``p_orders`` are padding.
 
     Grid ``(order block, combination tile)``: each step decodes its tile
     once for the job group and scores a block of
-    :func:`enum_order_block` orders against that decode.  The orders come
-    flat (a 2-D SMEM array is padded to (8, 128)-word tiles), whole, as
-    scalar prefetch; the answers leave as two whole ``(P,)`` SMEM arrays.
+    :func:`enum_order_block` orders against that decode.  A step's job
+    ids and answers are SMEM blocks indexed by the order block alone, so
+    the tiles of one block read its ids once, and SMEM holds one block
+    of them whatever ``p_orders`` is.
     """
     _, n, m = tables.shape
-    p_orders = orders.shape[0] // n
     block, unroll = enum_order_block(p_orders, k_total)
     grid = enum_grid(p_orders, k_total)
+    if orders.shape != (grid[0], 1, block * n):
+        raise ValueError(
+            f"orders must be laid out by enum_blocked_orders as {(grid[0], 1, block * n)};"
+            f" got {orders.shape}"
+        )
     nkt = grid[1]
     dtype = tables.dtype
     kernel = functools.partial(
         _enum_kernel, n=n, m=m, block=block, unroll=unroll, p_orders=p_orders,
         k_total=k_total, nkt=nkt,
     )
-    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     scratch = [pltpu.VMEM((n, SUBLANES, LANES), dtype) for _ in range(2)]
     if nkt > 1:
         scratch += [pltpu.VMEM((block, SUBLANES, LANES), dtype) for _ in range(4)]
-    out_shape = jax.ShapeDtypeStruct((p_orders,), dtype)
+    out_shape = jax.ShapeDtypeStruct((grid[0], 1, block), dtype)
     return pl.pallas_call(
         kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM), smem],
-            out_specs=[smem, smem],
-            scratch_shapes=scratch,
-        ),
+        grid=grid,
+        in_specs=[
+            pl.BlockSpec(memory_space=pltpu.VMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            per_row_smem(block * n),
+        ],
+        out_specs=[per_row_smem(block), per_row_smem(block)],
         out_shape=[out_shape, out_shape],
+        scratch_shapes=scratch,
         interpret=interpret,
-    )(orders, tables, ints)
+    )(tables, ints, orders)
 
 
 # ---------------------------------------------------------------------------

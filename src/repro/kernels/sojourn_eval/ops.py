@@ -99,7 +99,12 @@ def precision_scope(impl: str):
 
 
 def _order_batch(n_orders: int, tile: int, n: int) -> int:
-    """Orders per jit call so (P_b, tile, N) intermediates stay bounded."""
+    """Orders per jit call so (P_b, tile, N) intermediates stay bounded.
+
+    Governs the XLA paths and ``sojourn_mc``, whose calls hold per-order
+    inputs.  The Pallas enumeration kernel streams its orders through
+    SMEM a block at a time and scores every order in one call.
+    """
     per_order = tile * n * 8  # float64 worst case
     return max(1, min(n_orders, 4096, _TILE_BYTES_BUDGET // max(per_order, 1)))
 
@@ -239,8 +244,10 @@ def run_batches(phase, items, batch, fdt, shared, per_batch, call):
     batch's host arrays; ``put``, their copies to the device (with the
     ``shared`` host arrays every batch uses, in the first batch); ``call``,
     the dispatch of ``call(*shared, *batch arrays)``; ``sync``, the wait
-    for its two answers and their copy back.  Float arrays go to the
-    device as ``fdt``, the others keep their dtype.
+    for its two answers and their copy back.  Answers past a batch's
+    number of items (the padding of the static kernel's last order block)
+    are dropped.  Float arrays go to the device as ``fdt``, the others
+    keep their dtype.
     """
 
     def put(a):
@@ -250,7 +257,8 @@ def run_batches(phase, items, batch, fdt, shared, per_batch, call):
     e_succ, e_all = [], []
     for lo in range(0, len(items), batch):
         phase.to("prep")
-        host = per_batch(items[lo : lo + batch])
+        items_b = items[lo : lo + batch]
+        host = per_batch(items_b)
         phase.to("put")
         if shared_j is None:
             shared_j = [put(a) for a in shared]
@@ -259,8 +267,8 @@ def run_batches(phase, items, batch, fdt, shared, per_batch, call):
         es, ea = call(*shared_j, *args)
         del args  # free this batch's inputs before the next batch is put
         phase.to("sync")
-        e_succ.append(np.asarray(es).reshape(-1))
-        e_all.append(np.asarray(ea).reshape(-1))
+        e_succ.append(np.asarray(es).reshape(-1)[: len(items_b)])
+        e_all.append(np.asarray(ea).reshape(-1)[: len(items_b)])
     return np.concatenate(e_succ), np.concatenate(e_all)
 
 
@@ -307,9 +315,9 @@ def _sojourn_eval(
                 return K.sojourn_mc(sz, cd, rx, ob, k, n_samples, interpret=interpret)
     else:
         k_total = int(np.prod(num_stages, dtype=np.int64))
-        tile = min(XLA_TILE, max(K.BLOCK_COMBOS, 1 << (k_total - 1).bit_length()))
-        pb = _order_batch(orders.shape[0], tile, n)
         if impl == "xla":
+            tile = min(XLA_TILE, max(K.BLOCK_COMBOS, 1 << (k_total - 1).bit_length()))
+            pb = _order_batch(orders.shape[0], tile, n)
             shared = [sizes, probs]
 
             def per_batch(ob):
@@ -324,21 +332,23 @@ def _sojourn_eval(
                     tile=tile,
                 )
         else:
-            # The job group's tables once a call, in the dtype the kernel
-            # reads; each batch puts only its order ids.
+            # One call scores every order: the kernel streams them through
+            # SMEM a block at a time.  The job group's tables go once, in
+            # the dtype the kernel reads.
+            pb = len(orders)
             shared = [
                 np.stack([sizes, probs]).astype(fdt),
                 np.stack([strides, radix]).astype(np.int32),
             ]
 
             def per_batch(ob):
-                return [ob.reshape(-1)]
+                return [K.enum_blocked_orders(ob, k_total)]
 
-            def call(tables, ints, ob):
-                p_b = ob.shape[0] // n
-                profiling.count("sojourn_enum.orders", p_b)
-                blocks, tiles = K.enum_grid(p_b, k_total)
+            def call(tables, ints, blocked):
+                profiling.count("sojourn_enum.calls")
+                profiling.count("sojourn_enum.orders", pb)
+                blocks, tiles = K.enum_grid(pb, k_total)
                 profiling.count("sojourn_enum.order_blocks", blocks)
                 profiling.count("sojourn_enum.grid_steps", blocks * tiles)
-                return K.sojourn_enum(tables, ints, ob, k_total, interpret=interpret)
+                return K.sojourn_enum(tables, ints, blocked, k_total, pb, interpret=interpret)
     return run_batches(phase, orders, pb, fdt, shared, per_batch, call)

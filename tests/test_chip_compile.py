@@ -3,9 +3,11 @@
 Nothing runs: the TPU compiler installed with JAX compiles for a chip
 that is described, not attached.  This catches what interpret mode
 cannot (block shapes Mosaic refuses, SMEM overflow, unsupported casts,
-64-bit types) at the sizes the evaluator really uses: the op's batch of
-4,096 orders, float32, N = 9 (OPTIMAL's search) and N = 21 (K = 2^21),
-M = 2, and the dynamic lockstep at 1 and 3 servers; and the static
+64-bit types) at the sizes the evaluator really uses, in float32, M = 2:
+the static enumeration kernel's one call of all 8! and 9! orders (N = 8
+and N = 9, OPTIMAL's searches) and of 4,096 orders at N = 21
+(K = 2^21); the streamed-MC kernel's batch of 4,096 orders and the
+dynamic lockstep at 1 and 3 servers at N = 9 and N = 21; and the static
 kernel at the stage sweep's N = 5, M = 8 (K = 2^15, 120 orders and 1).
 
 The topology is described inside a fixture, never at import: only one
@@ -14,6 +16,7 @@ file.
 """
 
 import importlib.util
+import math
 import os
 from pathlib import Path
 
@@ -33,7 +36,9 @@ _spec = importlib.util.spec_from_file_location("chip_xplane", _XPLANE)
 xplane = importlib.util.module_from_spec(_spec)
 _spec.loader.exec_module(xplane)
 
-ORDERS = 4096  # ops._order_batch's cap: the op's real batch
+ORDERS = 4096  # ops._order_batch's cap: sojourn_mc's real batch
+#: Orders of one static enumeration call: OPTIMAL's N! at N = 8 and 9.
+ENUM_ORDERS = {8: math.factorial(8), 9: math.factorial(9), 21: ORDERS}
 POLICIES = 2
 M = 2
 SAMPLES = 1 << 20
@@ -73,12 +78,22 @@ def _static_shapes(chip, n):
     return f32, i32
 
 
-@pytest.mark.parametrize("n", (9, 21))
+def _enum_shapes(chip, n, m, p):
+    """Shapes of :func:`K.sojourn_enum`'s arguments for ``p`` orders."""
+    blocks, _ = K.enum_grid(p, m**n)
+    block, _ = K.enum_order_block(p, m**n)
+    return (
+        jax.ShapeDtypeStruct((2, n, m), jnp.float32, sharding=chip),
+        jax.ShapeDtypeStruct((2, n), jnp.int32, sharding=chip),
+        jax.ShapeDtypeStruct((blocks, 1, block * n), jnp.int32, sharding=chip),
+    )
+
+
+@pytest.mark.parametrize("n", sorted(ENUM_ORDERS))
 def test_sojourn_enum_compiles(one_chip, n):
-    tables = jax.ShapeDtypeStruct((2, n, M), jnp.float32, sharding=one_chip)
-    ints = jax.ShapeDtypeStruct((2, n), jnp.int32, sharding=one_chip)
-    orders = jax.ShapeDtypeStruct((ORDERS * n,), jnp.int32, sharding=one_chip)
-    hlo = _compile(lambda t, i, o: K.sojourn_enum(t, i, o, M**n), tables, ints, orders)
+    p = ENUM_ORDERS[n]
+    shapes = _enum_shapes(one_chip, n, M, p)
+    hlo = _compile(lambda t, i, o: K.sojourn_enum(t, i, o, M**n, p), *shapes)
     # The chip benchmark finds the kernel in the profiler trace by this
     # instruction's name (kernel_ms_per_trial.static_enum, static_enum_roofline).
     calls = [
@@ -94,10 +109,7 @@ def test_sojourn_enum_compiles_eight_stages(one_chip, p):
     """Table XIV's cell: N = 5, M = 8, K = 8**5 over 32 combination tiles;
     OPTIMAL's 120 orders (four blocks of 30 with Kahan tiles) and RANK's one."""
     n, m = 5, 8
-    tables = jax.ShapeDtypeStruct((2, n, m), jnp.float32, sharding=one_chip)
-    ints = jax.ShapeDtypeStruct((2, n), jnp.int32, sharding=one_chip)
-    orders = jax.ShapeDtypeStruct((p * n,), jnp.int32, sharding=one_chip)
-    _compile(lambda t, i, o: K.sojourn_enum(t, i, o, m**n), tables, ints, orders)
+    _compile(lambda t, i, o: K.sojourn_enum(t, i, o, m**n, p), *_enum_shapes(one_chip, n, m, p))
 
 
 @pytest.mark.parametrize("n", (9, 21))

@@ -92,10 +92,11 @@ CASES += [("dynamic", m, i) for m in ("enum", "mc") for i in ("xla", "interpret"
 @pytest.mark.parametrize("kind,mode,impl", CASES)
 def test_op_phases_tile_each_batch(profiled, monkeypatch, kind, mode, impl):
     if kind == "static":
-        # 24 orders in batches of 7: four batches.
+        # 24 orders in batches of 7: four batches, but one call of the
+        # enumeration kernel, which _order_batch does not govern.
         monkeypatch.setattr(ops, "_order_batch", lambda *_: 7)
         args, kwargs = _static_case(mode)
-        op, batches = sojourn_eval, 4
+        op, batches = sojourn_eval, 1 if (mode, impl) == ("enum", "interpret") else 4
     else:
         args, kwargs = _dynamic_case(mode)
         op, batches = sojourn_eval_dynamic, 3 if impl == "xla" else 1
@@ -119,9 +120,11 @@ def test_op_phases_tile_each_batch(profiled, monkeypatch, kind, mode, impl):
 
 
 # (jobs, orders, orders a batch or None for the op's own, order blocks):
-# 24 orders in batches of 7 at K = 16 are four one-block calls; 33 orders
-# at K = 2048 (two combination tiles) are one call of two blocks.
-ENUM_COUNTS = [(4, 24, 7, 4), (11, 33, None, 2)]
+# every case is one kernel call.  24 orders at K = 16 are one block even
+# when _order_batch says 7; 33 orders at K = 2048 (two combination tiles)
+# are two blocks; all 7! = 5,040 orders at K = 128, more than
+# _order_batch's 4,096, are ten blocks of 504.
+ENUM_COUNTS = [(4, 24, 7, 1), (11, 33, None, 2), (7, 5040, None, 10)]
 
 
 @pytest.mark.parametrize("n,p,batch,blocks", ENUM_COUNTS)
@@ -131,14 +134,15 @@ def test_enum_counts_orders_and_order_blocks(profiled, monkeypatch, n, p, batch,
     orders = np.array([rng.permutation(n) for _ in range(p)], np.int32)
     if batch is not None:
         monkeypatch.setattr(ops, "_order_batch", lambda *_: batch)
-    names = ("prof.sojourn_enum.orders", "prof.sojourn_enum.order_blocks")
+    names = ("prof.sojourn_enum.orders", "prof.sojourn_enum.order_blocks",
+             "prof.sojourn_enum.calls")
     profiling.enable(False)
     sojourn_eval(sizes, probs, num_stages, orders, impl="interpret")
     assert not set(names) & set(profiled.snapshot()["counters"])
     profiling.enable(True)
     sojourn_eval(sizes, probs, num_stages, orders, impl="interpret")
     counters = profiled.snapshot()["counters"]
-    assert [counters[k] for k in names] == [p, blocks]
+    assert [counters[k] for k in names] == [p, blocks, 1]
 
 
 # (jobs, stages, orders, grid steps): 24 orders at K = 8**4 = 4096 (four

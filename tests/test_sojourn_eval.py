@@ -229,6 +229,11 @@ ORDER_BLOCK_CASES = {
     "tiles_ragged_stages": lambda: (_ragged_jobs(59), _random_orders(8, 40, 59)),
     "all_orders_n5": lambda: (generate_workload(np.random.default_rng(61), 5, num_stages=3),
                               np.array(list(itertools.permutations(range(5))), np.int32)),
+    # All 7! = 5,040 orders, more than the 4,096 an XLA call takes: one
+    # kernel call of ten blocks of 504 over one tile (K = 128).
+    "one_tile_all_orders_n7": lambda: (generate_workload(np.random.default_rng(71), 7),
+                                       np.array(list(itertools.permutations(range(7))),
+                                                np.int32)),
     # Eight stages a job (Table XIV's largest M): K = 8**4 = 4096 spans four
     # tiles, and all 24 orders are one block carrying Kahan sums across them.
     "tiles_m8_all_orders": lambda: (generate_workload(np.random.default_rng(67), 4, num_stages=8),
