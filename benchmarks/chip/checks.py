@@ -11,6 +11,15 @@ one number per kind of answer:
 * ``static_rel_err``: RANK and RANDOM, through the static enumeration;
 * ``dynamic_rel_err``: SR and SERPT, through the dynamic lockstep.
 
+That is the mapping on one server.  A configuration whose file names
+``n_servers`` = W > 1 is a cluster of W identical servers (the paper's
+Section V): there RANK is the online RANK, a stage-level index policy
+like SR and SERPT, and all three are scored by the reference's W-server
+simulation and gathered into ``dynamic_rel_err``.  OPTIMAL and RANDOM are
+static orders, whose optimality the paper proves for one server only; the
+reference has no W-server answer for them, and :func:`numbers_of` refuses
+them.
+
 Each number is the largest over the sample and is compared with its limit
 in ``limits.json``.  The control (:func:`control_numbers`) puts the same
 reference, computed in bfloat16, in the program's place.
@@ -24,6 +33,7 @@ import numpy as np
 import reference
 import workgen
 
+#: The number each algorithm's error is gathered into, on one server.
 NUMBER_OF = {
     "optimal": "optimal_rel_err",
     "rank": "static_rel_err",
@@ -31,6 +41,27 @@ NUMBER_OF = {
     "sr": "dynamic_rel_err",
     "serpt": "dynamic_rel_err",
 }
+#: The same on W > 1 servers, where only index policies have a reference.
+NUMBER_OF_SERVERS = {"rank": "dynamic_rel_err", "sr": "dynamic_rel_err",
+                     "serpt": "dynamic_rel_err"}
+
+
+def servers(config: dict) -> int:
+    """The configuration's server count W: ``n_servers``, 1 when absent."""
+    return config.get("n_servers", 1)
+
+
+def numbers_of(config: dict, algorithms) -> dict[str, str]:
+    """The number each algorithm's error is gathered into; raises
+    ``ValueError`` for an algorithm the reference cannot score."""
+    w = servers(config)
+    table = NUMBER_OF if w == 1 else NUMBER_OF_SERVERS
+    missing = [a for a in algorithms if a not in table]
+    if missing:
+        why = (f" on {w} servers, where only the index policies {sorted(table)} have one: "
+               "static orders are optimal on one server only") if w > 1 else ""
+        raise ValueError(f"no reference for {missing}{why}")
+    return {a: table[a] for a in algorithms}
 
 
 def sample(seed: int, n_done: int, count: int, n_sets: int) -> list[int]:
@@ -50,28 +81,29 @@ def sample(seed: int, n_done: int, count: int, n_sets: int) -> list[int]:
 
 
 def reference_answers(config: dict, seed: int, trial: int, algorithms, dtype=np.float64):
-    """What each algorithm should answer on trial ``trial`` of ``seed``."""
+    """What each algorithm should answer on trial ``trial`` of ``seed``, on
+    the configuration's servers."""
+    number_of = numbers_of(config, algorithms)
     sizes, probs = workgen.trial_group(config, seed, trial)
     n = len(sizes)
     out = {}
     for alg in algorithms:
-        if alg == "optimal":
+        if number_of[alg] == "optimal_rel_err":
             out[alg] = float(np.min(reference.static_values(
                 sizes, probs, reference.all_orders(n), dtype)))
-        elif alg in ("rank", "random"):
+        elif number_of[alg] == "static_rel_err":
             order = (reference.rank_order(sizes, probs) if alg == "rank"
                      else workgen.stream(workgen.RANDOM, seed, trial).permutation(n))
             out[alg] = float(reference.static_values(sizes, probs, order[None], dtype)[0])
-        elif alg in ("sr", "serpt"):
+        else:  # a stage-level index policy
             table = reference.index_table(sizes, probs, alg)
-            out[alg] = reference.dynamic_value(sizes, probs, table, dtype)
-        else:
-            raise ValueError(f"no reference for algorithm {alg!r}")
+            out[alg] = reference.dynamic_value(sizes, probs, table, dtype, servers(config))
     return out
 
 
 def _numbers(config, seed, trials, algorithms, answers_of) -> dict[str, float]:
-    numbers = {NUMBER_OF[a]: 0.0 for a in algorithms}
+    number_of = numbers_of(config, algorithms)
+    numbers = {name: 0.0 for name in number_of.values()}
     for t in trials:
         want = reference_answers(config, seed, t, algorithms)
         got = answers_of(t)  # None: the trial raised, so it answered nothing
@@ -79,7 +111,7 @@ def _numbers(config, seed, trials, algorithms, answers_of) -> dict[str, float]:
             err = float("inf") if got is None else abs(float(got[alg]) - want[alg]) / abs(want[alg])
             if not np.isfinite(err):
                 err = float("inf")
-            name = NUMBER_OF[alg]
+            name = number_of[alg]
             numbers[name] = max(numbers[name], err)
     return numbers
 

@@ -14,6 +14,13 @@ client, as a parameter sweep is: trial t draws a fresh job group from
 ``(seed, t)``, scores it with ``repro.core.evaluator.evaluate_many`` and
 starts the next trial when that returns.
 
+A configuration may name ``n_servers`` = W, the size of the cluster that
+serves the group (paper Section V; 1 when absent).  The harness then
+scores each group with ``evaluate_many(jobs, algorithms, rng,
+n_servers=W)`` and checks RANK, SR and SERPT as index policies on W
+servers; a configuration without the key is scored with
+``evaluate_many(jobs, algorithms, rng)``.
+
 The run loads, warms every shape its window uses (set-up), measures for
 ``--seconds``, checks a sample of the window's answers against the
 float64 reference (``checks.py``) and prints one JSON line last.  With
@@ -69,6 +76,12 @@ def load_cell(root: Path, name: str) -> SimpleNamespace:
         raise BenchError(f"no workload {name!r} in BENCHMARK.json")
     cell = cells[name]
     configs = {c["name"]: c for c in spec["configs"]}
+    config = json.loads((root / configs[cell["config"]]["file"]).read_text())
+    traffic = json.loads((bench_dir / "traffic" / f"{cell['traffic']}.json").read_text())
+    try:
+        checks.numbers_of(config, traffic["algorithms"])
+    except ValueError as e:
+        raise BenchError(f"cell {name!r}: {e}") from None
 
     def applies(metric):
         return name in metric.get("workloads", [name])
@@ -76,8 +89,8 @@ def load_cell(root: Path, name: str) -> SimpleNamespace:
     return SimpleNamespace(
         name=name,
         chips=cell["chips"],
-        config=json.loads((root / configs[cell["config"]]["file"]).read_text()),
-        traffic=json.loads((bench_dir / "traffic" / f"{cell['traffic']}.json").read_text()),
+        config=config,
+        traffic=traffic,
         end_to_end=[m for m in spec["end_to_end"] if applies(m)],
         per_layer=[m for m in spec["per_layer"] if applies(m)],
         bench_dir=bench_dir,
@@ -145,6 +158,9 @@ class Bench:
         self.evaluator, self.JobSpec = evaluator, JobSpec
         self.registry, self.profiling = get_registry(), profiling
         self.algorithms = tuple(cell.traffic["algorithms"])
+        # The server count reaches the program only where the configuration names it.
+        self.servers = ({"n_servers": cell.config["n_servers"]} if "n_servers" in cell.config
+                        else {})
         self.log = CompileLog(jax)
 
     def jobs(self, sizes, probs):
@@ -157,7 +173,8 @@ class Bench:
         for i in range(self.cell.traffic["warmup_trials"]):
             sizes, probs = workgen.job_group(config, workgen.stream(workgen.WARMUP, seed, 2 * i), i)
             rng = workgen.stream(workgen.WARMUP, seed, 2 * i + 1)
-            self.evaluator.evaluate_many(self.jobs(sizes, probs), self.algorithms, rng)
+            self.evaluator.evaluate_many(self.jobs(sizes, probs), self.algorithms, rng,
+                                         **self.servers)
 
     def window(self, seed: int, seconds: float, trace_dir: str | None = None):
         """Score trials back to back until ``seconds`` have passed.
@@ -196,7 +213,8 @@ class Bench:
                             rng = workgen.stream(workgen.RANDOM, seed, t)
                         t1 = time.perf_counter()
                         try:
-                            answers.append(evaluator.evaluate_many(jobs, self.algorithms, rng))
+                            answers.append(evaluator.evaluate_many(jobs, self.algorithms, rng,
+                                                                   **self.servers))
                         except Exception:  # noqa: BLE001 - a failed trial is counted, not fatal
                             if not failed:
                                 traceback.print_exc()
