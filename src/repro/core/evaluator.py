@@ -26,6 +26,9 @@ The entry points:
   which runs the W-server stage-boundary preemption simulation *inside*
   each tile.
 * :func:`optimal_order` — exhaustive search over permutations (N <= 9).
+* :func:`evaluate` / :func:`evaluate_many` — the paper's policies by name,
+  on one server or, with ``n_servers=W``, in Section V's online setting
+  on W servers (RANK, SR and SERPT as index policies).
 
 One reference tier stays beside them: the seed's materialized path
 (:func:`enumerate_outcomes`, :func:`_realized_arrays`,
@@ -343,20 +346,53 @@ def optimal_order(jobs: Workload, max_n: int = 9) -> tuple[np.ndarray, float]:
     return orders[best].copy(), float(vals[best])
 
 
+#: Policies scored on W > 1 servers: the stage-level index policies of
+#: Section V.  OPTIMAL and RANDOM are static orders, optimal on one server only.
+ONLINE_POLICIES = ("rank", "sr", "serpt")
+
+
+def _check_servers(policy: str, n_servers: int) -> None:
+    if n_servers < 1:
+        raise ValueError(f"n_servers must be >= 1; got {n_servers}")
+    if n_servers > 1 and policy not in ONLINE_POLICIES:
+        raise ValueError(
+            f"no {n_servers}-server evaluation of policy {policy!r}: static orders "
+            f"are optimal on one server only (Theorem III.1); on W > 1 servers the "
+            f"index policies {ONLINE_POLICIES} are scored"
+        )
+
+
 def evaluate(
     jobs: Workload,
     policy: str,
     rng: np.random.Generator | None = None,
     samples: tuple[int, int] | None = None,
+    n_servers: int = 1,
 ) -> float:
     """Expected sojourn time of successful jobs under ``policy``.
 
     Policies: 'rank' | 'serpt' | 'sr' | 'random' | 'optimal'.
-    RANK and RANDOM are static orders (Theorem III.1); SERPT and SR are
-    stage-level index policies as in the paper's Section III-A examples.
-    ``samples=(seed, n_samples)`` runs streaming Monte Carlo with a
-    shared counter stream (common random numbers across policies).
+    On one server, RANK and RANDOM are static orders (Theorem III.1);
+    SERPT and SR are stage-level index policies as in the paper's
+    Section III-A examples.  ``samples=(seed, n_samples)`` runs streaming
+    Monte Carlo with a shared counter stream (common random numbers
+    across policies).
+
+    ``n_servers=W > 1`` is the online setting of Section V: W identical
+    servers, each taking the alive job of least index at every
+    checkpoint.  There RANK is the online, conditional RANK
+    (``policies.rank_index_table``: E[remaining size] / P(success |
+    survived)), an index policy like SR and SERPT, and all three go
+    through :func:`expected_sojourn_dynamic` on W servers, each call the
+    ``prof.evaluate.online`` span.  OPTIMAL and RANDOM raise
+    ``ValueError``: static orders are optimal on one server only.
     """
+    _check_servers(policy, n_servers)
+    if n_servers > 1:
+        with profiling.span("evaluate.online"):
+            return expected_sojourn_dynamic(
+                jobs, policy, samples=samples, n_servers=n_servers
+            )
     if policy == "rank":
         return expected_sojourn_static(
             jobs, policies.rank_order(jobs), samples=samples
@@ -384,6 +420,7 @@ def evaluate_many(
     algs: tuple[str, ...],
     rng: np.random.Generator,
     mc_samples: int = 4096,
+    n_servers: int = 1,
 ) -> dict[str, float]:
     """Evaluate several policies on one job group, sharing random numbers.
 
@@ -399,14 +436,21 @@ def evaluate_many(
         policies see the identical outcome sequence without any (S, N)
         sample table ever existing.
 
+    ``n_servers=W > 1`` scores each policy on W servers as :func:`evaluate`
+    does (Section V: RANK, SR and SERPT as index policies), in both
+    regimes, one op call per policy as on one server; OPTIMAL or RANDOM
+    among ``algs`` raises ``ValueError`` before any policy is scored.
+
     The call is the ``prof.evaluate_many`` span of :mod:`repro.obs.profiling`.
     """
+    for alg in algs:
+        _check_servers(alg, n_servers)
     with profiling.span("evaluate_many"):
         k_total = exact_combination_count(jobs)
         if k_total <= MAX_EXACT_COMBOS:
-            return {alg: evaluate(jobs, alg, rng=rng) for alg in algs}
+            return {alg: evaluate(jobs, alg, rng=rng, n_servers=n_servers) for alg in algs}
         seed = int(rng.integers(0, kernel_rng.MAX_SEED))
         return {
-            alg: evaluate(jobs, alg, rng=rng, samples=(seed, mc_samples))
+            alg: evaluate(jobs, alg, rng=rng, samples=(seed, mc_samples), n_servers=n_servers)
             for alg in algs
         }

@@ -606,7 +606,12 @@ def sojourn_eval_dynamic(
     ``prof.sojourn_eval.dynamic.<mode>.<impl>`` span, tiled by the phase
     spans ``prof.op_phase.dynamic.<impl>.{prep,put,call,sync}`` of
     :func:`repro.kernels.sojourn_eval.ops.run_batches` (one batch of all
-    tables on the kernels, one table a batch on the XLA path).
+    tables on the kernels, one table a batch on the XLA path).  Each
+    kernel call (Pallas or interpret) also counts, from the shapes on the
+    host in its ``prep`` phase, ``prof.sojourn_dynamic.calls``,
+    ``.policies`` (index tables scored), ``.grid_steps`` (policies times
+    combination or sample tiles: the tile simulations the kernel runs) and
+    ``.servers`` (W, summed over calls).
     """
     impl = resolve_impl(impl)
     mode = "mc" if samples is not None else "enum"
@@ -619,6 +624,17 @@ def sojourn_eval_dynamic(
             probs, stage_durs, num_stages, idx_tables,
             samples=samples, n_servers=n_servers, impl=impl, phase=phase,
         )
+
+
+def _count_kernel_call(policies: int, items: int, n_servers: int) -> None:
+    """The ``prof.sojourn_dynamic.*`` counters of one kernel call that
+    scores ``policies`` index tables over ``items`` combinations or
+    samples on ``n_servers`` servers."""
+    profiling.count("sojourn_dynamic.calls")
+    profiling.count("sojourn_dynamic.policies", policies)
+    tiles = max(1, pl.cdiv(items, K.BLOCK_COMBOS))
+    profiling.count("sojourn_dynamic.grid_steps", policies * tiles)
+    profiling.count("sojourn_dynamic.servers", n_servers)
 
 
 def _sojourn_eval_dynamic(
@@ -670,6 +686,7 @@ def _sojourn_eval_dynamic(
             key = np.asarray(rng.split_seed(seed), np.int32)
 
             def per_batch(tables):
+                _count_kernel_call(len(tables), n_samples, n_servers)
                 return [tables, radix, key]
 
             def call(cdf, durs, tables, rx, k):
@@ -700,6 +717,7 @@ def _sojourn_eval_dynamic(
         else:
 
             def per_batch(tables):
+                _count_kernel_call(len(tables), k_total, n_servers)
                 return [tables, strides.astype(np.int32), radix]
 
             def call(pr, durs, tables, st, rx):

@@ -313,3 +313,96 @@ def test_evaluate_many_all_exact_within_cap():
     b = evaluator.evaluate_many(jobs, ("rank", "sr", "serpt"), np.random.default_rng(1))
     assert a == b
     assert _relerr(a["sr"], oracle(jobs, "sr")[0]) < RTOL
+
+
+# ---------------------------------------------------------------------------
+# The entry point on W servers (Section V's online setting)
+# ---------------------------------------------------------------------------
+
+ONLINE = ("rank", "sr", "serpt")
+
+
+@pytest.fixture
+def entry_impl(request, monkeypatch):
+    """The impl ``"auto"`` resolves to inside the evaluator's dynamic op."""
+    from repro.kernels.sojourn_eval import dynamic
+
+    impl = request.param
+    resolve = dynamic.resolve_impl
+    monkeypatch.setattr(dynamic, "resolve_impl", lambda i: impl if i == "auto" else resolve(i))
+    return impl
+
+
+@pytest.mark.parametrize("entry_impl", IMPLS, indirect=True)
+@pytest.mark.parametrize("n_servers", (2, 3))
+@pytest.mark.parametrize("seed,n,m", [(0, 4, 2), (1, 5, 3), (2, 6, 2), (3, 4, 3)])
+def test_evaluate_many_on_servers_matches_references(entry_impl, n_servers, seed, n, m):
+    """``evaluate_many(..., n_servers=W)`` scores RANK (the online,
+    conditional RANK), SR and SERPT on W servers: it agrees with the dense
+    oracle and an exhaustive run of the DES on W servers, on the XLA path
+    and in interpret mode (both float64 under the evaluator's x64)."""
+    jobs = generate_workload(np.random.default_rng(seed), n, num_stages=m)
+    got = evaluator.evaluate_many(jobs, ONLINE, np.random.default_rng(seed), n_servers=n_servers)
+    assert list(got) == list(ONLINE)
+    for policy in ONLINE:
+        want, _ = oracle(jobs, policy, n_servers=n_servers)
+        assert _relerr(got[policy], want) < RTOL, (entry_impl, policy, got[policy], want)
+        assert _relerr(des_exhaustive(jobs, policy, n_servers=n_servers), want) < RTOL
+    # On W > 1 servers RANK is an index policy, not the static RANK order.
+    static_rank = evaluator.evaluate(jobs, "rank")
+    assert got["rank"] != static_rank
+
+
+@pytest.mark.parametrize("n_servers", (2, 3))
+def test_evaluate_on_servers_streamed_mc_matches_host_replay(n_servers):
+    """The streamed-MC path of ``evaluate`` on W servers: the same draws
+    replayed on the host and fed to the W-server oracle."""
+    jobs = generate_workload(np.random.default_rng(29), 5, num_stages=2)
+    samples = (MC_SEED, 512)
+    for policy in ONLINE:
+        got = evaluator.evaluate(jobs, policy, samples=samples, n_servers=n_servers)
+        want, _ = oracle(jobs, policy, n_servers=n_servers, samples=samples)
+        assert _relerr(got, want) < RTOL, policy
+
+
+@pytest.mark.parametrize("n_servers", (2, 3))
+@pytest.mark.parametrize("policy", ("optimal", "random"))
+def test_static_orders_refused_on_servers(policy, n_servers):
+    jobs = generate_workload(np.random.default_rng(31), 4)
+    rng = np.random.default_rng(0)
+    with pytest.raises(ValueError, match="one server only"):
+        evaluator.evaluate(jobs, policy, rng=rng, n_servers=n_servers)
+    with pytest.raises(ValueError, match="one server only"):
+        evaluator.evaluate_many(jobs, ("sr", policy), rng, n_servers=n_servers)
+
+
+@pytest.mark.parametrize("n_servers", (0, -1))
+def test_fewer_than_one_server_refused(n_servers):
+    jobs = generate_workload(np.random.default_rng(37), 4)
+    with pytest.raises(ValueError, match="n_servers must be >= 1"):
+        evaluator.evaluate(jobs, "sr", n_servers=n_servers)
+    with pytest.raises(ValueError, match="n_servers must be >= 1"):
+        evaluator.evaluate_many(jobs, ("sr",), np.random.default_rng(0), n_servers=n_servers)
+
+
+@pytest.mark.parametrize("samples", [None, (MC_SEED, 512)], ids=["exact", "mc"])
+@pytest.mark.parametrize("servers", [{}, {"n_servers": 1}], ids=["default", "one"])
+def test_one_server_answers_as_before(samples, servers):
+    """On one server every policy is scored by the calls the evaluator
+    made before it took a server count, bit for bit."""
+    jobs = generate_workload(np.random.default_rng(41), 5, num_stages=3)
+    before = {
+        "rank": evaluator.expected_sojourn_static(
+            jobs, policies.rank_order(jobs), samples=samples),
+        "random": evaluator.expected_sojourn_static(
+            jobs, policies.random_order(jobs, np.random.default_rng(3)), samples=samples),
+        "optimal": evaluator.optimal_order(jobs)[1],
+        "sr": evaluator.expected_sojourn_dynamic(jobs, "sr", samples=samples),
+        "serpt": evaluator.expected_sojourn_dynamic(jobs, "serpt", samples=samples),
+    }
+    rng = np.random.default_rng(3)
+    got = {p: evaluator.evaluate(jobs, p, rng=rng, samples=samples, **servers) for p in before}
+    assert got == before
+    if samples is None:
+        many = evaluator.evaluate_many(jobs, tuple(before), np.random.default_rng(3), **servers)
+        assert many == before

@@ -166,6 +166,52 @@ def test_enum_counts_grid_steps(profiled, n, m, p, steps):
     assert profiled.snapshot()["counters"][name] == steps
 
 
+# (jobs, stages, index tables, servers, samples or None, grid steps): one
+# kernel call each.  K = 2**4 = 16 is one combination tile; K = 2**11 =
+# 2048 is two; 3,000 samples are three tiles of 1,024.
+DYNAMIC_COUNTS = [(4, 2, 2, 3, None, 2), (11, 2, 3, 1, None, 6), (5, 2, 1, 2, 3000, 3)]
+
+
+@pytest.mark.parametrize("n,m,p,w,n_samples,steps", DYNAMIC_COUNTS)
+def test_dynamic_counts_calls_policies_grid_steps_servers(profiled, n, m, p, w, n_samples, steps):
+    jobs = generate_workload(np.random.default_rng(n), n, num_stages=m)
+    _, probs, num_stages = policies.padded_arrays(jobs)
+    tables = np.stack([policies.index_table(jobs, ("sr", "serpt", "rank")[i]) for i in range(p)])
+    args = (probs, policies.stage_durations(jobs), num_stages, tables)
+    kwargs = {"n_servers": w, "samples": None if n_samples is None else (2**40 + 5, n_samples)}
+    names = [f"prof.sojourn_dynamic.{c}" for c in ("calls", "policies", "grid_steps", "servers")]
+    profiling.enable(False)
+    sojourn_eval_dynamic(*args, impl="interpret", **kwargs)
+    profiling.enable(True)
+    sojourn_eval_dynamic(*args, impl="xla", **kwargs)  # the XLA path runs no kernel
+    assert not set(names) & set(profiled.snapshot()["counters"])
+    sojourn_eval_dynamic(*args, impl="interpret", **kwargs)
+    counters = profiled.snapshot()["counters"]
+    assert [counters[k] for k in names] == [1, p, steps, w]
+
+
+def test_online_span_around_each_index_policy_on_servers(profiled):
+    from repro.core import evaluator
+
+    jobs = _jobs(seed=17, n=5)
+    evaluator.evaluate_many(jobs, ("rank", "sr", "serpt"), np.random.default_rng(0))
+    snap = profiled.snapshot()
+    assert "prof.evaluate.online.calls" not in snap["counters"]  # one server
+    profiled.clear()
+    evaluator.evaluate_many(jobs, ("rank", "sr"), np.random.default_rng(0), n_servers=3)
+    snap = profiled.snapshot()
+    h = snap["histograms"]
+    assert snap["counters"]["prof.evaluate.online.calls"] == 2
+    assert snap["counters"]["prof.sojourn_eval.dynamic.enum.xla.calls"] == 2
+    assert "prof.sojourn_eval.static.enum.xla.calls" not in snap["counters"]
+    online = h["prof.evaluate.online.seconds"]["sum"]
+    assert h["prof.sojourn_eval.dynamic.enum.xla.seconds"]["sum"] <= online
+    assert online <= h["prof.evaluate_many.seconds"]["sum"]
+    # The op call is a direct child of the online span, so its time is not
+    # the online span's own.
+    assert h["prof.evaluate.online.self_s"]["sum"] < online
+
+
 def test_count_adds_only_when_enabled():
     was = profiling.enabled()
     reg = MetricsRegistry()
